@@ -4241,6 +4241,20 @@ inline NDArray custom(const NDArray& a, const NDArray& b,
   return detail::Invoke("npx:custom", {a.handle(), b.handle(), c.handle()}, kw);
 }
 
+inline NDArray decode_attention(const NDArray& a,
+                    const std::string& kw = "") {
+  return detail::Invoke("npx:decode_attention", {a.handle()}, kw);
+}
+inline NDArray decode_attention(const NDArray& a, const NDArray& b,
+                    const std::string& kw = "") {
+  return detail::Invoke("npx:decode_attention", {a.handle(), b.handle()}, kw);
+}
+inline NDArray decode_attention(const NDArray& a, const NDArray& b,
+                    const NDArray& c,
+                    const std::string& kw = "") {
+  return detail::Invoke("npx:decode_attention", {a.handle(), b.handle(), c.handle()}, kw);
+}
+
 inline NDArray deconvolution(const NDArray& a,
                     const std::string& kw = "") {
   return detail::Invoke("npx:deconvolution", {a.handle()}, kw);

@@ -1,17 +1,11 @@
-"""shard_map compatibility: one shim for the jax 0.8 API rename.
-
-jax >= 0.8 exposes ``jax.shard_map`` (kwarg ``check_vma``) and
-deprecates ``jax.experimental.shard_map`` (kwarg ``check_rep``).
-Every call site imports this single adapter so the next API change is
-a one-file fix.
-"""
+"""shard_map adapter: every call site imports this one spelling
+(``check_rep`` is this repo's name for ``jax.shard_map``'s
+``check_vma``), so an API change is a one-file fix."""
 from __future__ import annotations
 
-try:
-    from jax import shard_map as _new
+from jax import shard_map as _shard_map
 
-    def shard_map(f, mesh, in_specs, out_specs, check_rep=True):
-        return _new(f, mesh=mesh, in_specs=in_specs,
-                    out_specs=out_specs, check_vma=check_rep)
-except ImportError:  # older jax
-    from jax.experimental.shard_map import shard_map  # noqa: F401
+
+def shard_map(f, mesh, in_specs, out_specs, check_rep=True):
+    return _shard_map(f, mesh=mesh, in_specs=in_specs,
+                      out_specs=out_specs, check_vma=check_rep)

@@ -4,14 +4,19 @@ process restarts.
 JAX ships a persistent compilation cache (executables keyed by HLO
 fingerprint, written to a directory); wiring it up means the second
 process launch replays every XLA compile from disk instead of
-re-running the compiler. This module owns the knobs:
+re-running the compiler. The directory is placed from OUTSIDE:
 
-- ``MXTPU_COMPILE_CACHE_DIR`` — set to a directory to enable (created
-  if missing). `configure()` runs at package import; call it again
-  with an explicit path to (re)point the cache at runtime.
-- ``MXTPU_COMPILE_CACHE_MIN_COMPILE_SECS`` — only persist compiles
-  slower than this (default 0: persist everything, so even the tiny
-  tier-1 graphs exercise the cache).
+- ``JAX_COMPILATION_CACHE_DIR`` (JAX's own variable) — when set, the
+  cache lives there and nothing in this repo points it elsewhere;
+  `configure()` at package import only adopts it.
+- unset — the library runs without a persistent cache; the repo's
+  entry scripts (``chip_smoke.py``, ``bench.py``) pass
+  ``CHECKOUT_DIR``, a fixed git-ignored directory inside the checkout
+  (the path is part of the cache key, so it never moves).
+
+Every compile persists (min compile time 0, no size floor) so the
+hit/miss classification below is sound within one process; processes
+sharing the directory can skew each other's counts.
 
 Telemetry: every instrumented compile site (`CachedOp`,
 `TrainStep.__call__`/`warmup`) wraps its first dispatch in
@@ -30,37 +35,31 @@ import os
 
 from . import telemetry
 
-__all__ = ["configure", "enabled", "cache_dir", "entry_count", "measure"]
+__all__ = ["CHECKOUT_DIR", "configure", "enabled", "cache_dir",
+           "entry_count", "measure"]
+
+#: the entry scripts' cache directory when the environment names none
+CHECKOUT_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    ".jax_compile_cache")
 
 _dir: str | None = None
-# hit/miss classification is only sound when every compile persists
-# (min-compile-secs 0) — a compile below the threshold writes no entry
-# and would be misread as a hit. Concurrent processes sharing the dir
-# can still skew counts; treat them as indicative, not exact.
-_classify = True
 
 
 def configure(path: str | None = None) -> str | None:
-    """Point JAX's persistent compilation cache at ``path`` (default:
-    ``MXTPU_COMPILE_CACHE_DIR``). No-op (returns None) when neither is
-    set. Returns the active cache dir."""
-    global _dir, _classify
-    path = path or os.environ.get("MXTPU_COMPILE_CACHE_DIR")
+    """Turn the persistent compilation cache on. The directory is
+    ``JAX_COMPILATION_CACHE_DIR`` when the environment sets it (it
+    always wins — one resolution, from outside), else ``path``; with
+    neither this is a no-op returning None. Returns the active dir."""
+    global _dir
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR") or path
     if not path:
         return None
     import jax
     os.makedirs(path, exist_ok=True)
-    min_secs = float(os.environ.get(
-        "MXTPU_COMPILE_CACHE_MIN_COMPILE_SECS", "0"))
-    _classify = min_secs == 0
-    for knob, val in (
-            ("jax_compilation_cache_dir", path),
-            ("jax_persistent_cache_min_compile_time_secs", min_secs),
-            ("jax_persistent_cache_min_entry_size_bytes", -1)):
-        try:
-            jax.config.update(knob, val)
-        except Exception:  # noqa: BLE001 — knob missing on this jax
-            pass
+    jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     _dir = path
     telemetry.gauge("compile_cache.entries", entry_count())
     return _dir
@@ -100,6 +99,5 @@ def measure(site: str = "compile"):
         telemetry.duration_since("compile_cache.compile", t0)
         after = entry_count()
         telemetry.gauge("compile_cache.entries", after)
-        if _classify:
-            telemetry.counter("compile_cache.miss" if after > before
-                              else "compile_cache.hit")
+        telemetry.counter("compile_cache.miss" if after > before
+                          else "compile_cache.hit")

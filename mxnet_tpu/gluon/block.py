@@ -549,7 +549,7 @@ class CachedOp:
     def warmup(self, *args, training=False):
         """AOT-compile the forward for these template inputs via
         ``jit.lower(...).compile()``, moving trace + XLA compile off
-        the first real call (and, with ``MXTPU_COMPILE_CACHE_DIR``
+        the first real call (and, with ``JAX_COMPILATION_CACHE_DIR``
         set, replaying the compile from the persistent cache across
         process restarts). Only the inference program (``fwd``) is
         AOT-compiled; a recording-path first dispatch still benefits
@@ -892,7 +892,7 @@ class HybridBlock(Block):
     def warmup(self, *args, training=False):
         """Hybridize + AOT-compile the graph for these template inputs
         ahead of the first real call (see CachedOp.warmup). Pair with
-        ``MXTPU_COMPILE_CACHE_DIR`` to make the compile survive
+        ``JAX_COMPILATION_CACHE_DIR`` to make the compile survive
         process restarts."""
         if not self._active:
             self.hybridize(True)

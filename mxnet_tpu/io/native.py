@@ -4,13 +4,16 @@ The reference's high-throughput IO is C++ (src/io/iter_image_recordio_2.cc
 — mmap'd RecordIO chunks + OMP JPEG decode). This module compiles and
 loads the TPU-native equivalent, `src_native/recordio_native.cc`:
 mmap indexing + threaded libjpeg batch decode into a caller-owned NHWC
-uint8 buffer. Build happens on demand with g++ (cached by mtime); when
-the toolchain or libjpeg is missing, callers fall back to the portable
-Python/PIL path in `mxnet_tpu.image`.
+uint8 buffer. The library is built on demand with g++ into the
+git-ignored `src_native/build/`, named by the hash of its source (a
+fresh checkout has arbitrary mtimes, and a stale binary must never be
+preferred over the source); when the toolchain or libjpeg is missing,
+callers fall back to the portable Python/PIL path in `mxnet_tpu.image`.
 """
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 
@@ -19,22 +22,28 @@ import numpy as onp
 _REPO = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 _SRC = os.path.join(_REPO, "src_native", "recordio_native.cc")
-_SO = os.path.join(_REPO, "src_native", "build", "librecordio_native.so")
+_BUILD = os.path.join(_REPO, "src_native", "build")
 
 _lib = None
 _load_error = None
 
 
 def _build_if_needed():
-    if os.path.exists(_SO) and \
-            os.path.getmtime(_SO) >= os.path.getmtime(_SRC):
-        return
-    os.makedirs(os.path.dirname(_SO), exist_ok=True)
+    """Path of the library built from the source as it is now."""
+    with open(_SRC, "rb") as f:
+        tag = hashlib.sha256(f.read()).hexdigest()[:16]
+    so = os.path.join(_BUILD, f"librecordio_native-{tag}.so")
+    if os.path.exists(so):
+        return so
+    os.makedirs(_BUILD, exist_ok=True)
+    tmp = f"{so}.{os.getpid()}.tmp"   # concurrent builders never share
     cmd = ["g++", "-O3", "-std=c++17", "-shared", "-fPIC", _SRC,
-           "-o", _SO, "-ljpeg", "-pthread"]
+           "-o", tmp, "-ljpeg", "-pthread"]
     proc = subprocess.run(cmd, capture_output=True, text=True, timeout=120)
     if proc.returncode != 0:
         raise RuntimeError(f"native recordio build failed:\n{proc.stderr}")
+    os.replace(tmp, so)
+    return so
 
 
 def get_lib():
@@ -45,15 +54,7 @@ def get_lib():
     if _load_error is not None:
         raise _load_error
     try:
-        try:
-            _build_if_needed()
-        except (RuntimeError, OSError, subprocess.TimeoutExpired):
-            # stale-mtime rebuild failed (no toolchain on this box);
-            # a previously-built .so is still usable — prefer it over
-            # disabling the native path
-            if not os.path.exists(_SO):
-                raise
-        lib = ctypes.CDLL(_SO)
+        lib = ctypes.CDLL(_build_if_needed())
         lib.rio_open.restype = ctypes.c_void_p
         lib.rio_open.argtypes = [ctypes.c_char_p]
         lib.rio_count.restype = ctypes.c_long

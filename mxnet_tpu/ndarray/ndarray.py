@@ -267,6 +267,19 @@ class NDArray:
         self._grad = None
         self._grad_req = "null"
 
+    def release_grad(self):
+        """Free the gradient BUFFER but stay a variable: ``.grad``
+        reads as a scalar zero until the next ``backward`` writes a
+        full gradient over it (``write``) or adds one to it (``add``:
+        zero + g broadcasts to g). For owners that differentiate
+        inside their own compiled program (``parallel.TrainStep``) and
+        never touch the imperative buffer — one dead array the size
+        of every parameter otherwise."""
+        if self._grad is not None:
+            self._grad = NDArray(
+                engine.track(jnp.zeros((), self._data.dtype)),
+                ctx=self._ctx)
+
     def zero_grad(self):
         if self._grad is not None:
             self._grad._install(jnp.zeros_like(self._grad._data))

@@ -127,7 +127,7 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale,
     m, l, acc = lax.fori_loop(0, nk, body, (m0, l0, acc0))
     l_safe = jnp.where(l > 0, l, 1.0)                  # padded q rows
     o_ref[0] = (acc / l_safe[:, None]).astype(o_ref.dtype)
-    lse_ref[0] = m + jnp.log(l_safe)
+    lse_ref[0, 0] = m + jnp.log(l_safe)
 
 
 def _pad_seq(x, block):
@@ -177,11 +177,13 @@ def flash_attention_pallas(q, k, v, causal=False, scale=None,
         ],
         out_specs=[
             pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, block_q), lambda i, j: (i, j)),
+            # lse rides a unit middle axis: a (1, block_q) block of a
+            # 2-D (b*h, sqp) array breaks the TPU (8, 128) tiling rule
+            pl.BlockSpec((1, 1, block_q), lambda i, j: (i, 0, j)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b * h, sqp, d), q.dtype),
-            jax.ShapeDtypeStruct((b * h, sqp), jnp.float32),
+            jax.ShapeDtypeStruct((b * h, 1, sqp), jnp.float32),
         ],
         interpret=interpret,
     )(qr, kr, vr)
@@ -268,10 +270,7 @@ def jnp_only():
 def _use_pallas():
     if getattr(_JNP_ONLY, "on", False):
         return False
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+    return jax.default_backend() == "tpu"
 
 
 def _flash_fwd(q, k, v, causal, scale, kv_len=None):
@@ -409,11 +408,13 @@ def _decode_fwd_kernel(len_ref, q_ref, k_ref, v_ref, *rest, scale,
     persists across the innermost (kv-block) grid axis; the output
     block is written once, on the last grid step.
 
-    ``quant=True`` (int8 KV cache) adds two ``(1, 1)`` scale inputs
-    right after ``v_ref``: the resident int8 block is dequantized
-    IN-REGISTER with its slot's (dense) or page's (paged) per-head
-    scale — the fp32 K/V never exist outside VMEM, so the cache's HBM
-    footprint (and the DMA per step) is the int8 bytes."""
+    ``quant=True`` (int8 KV cache) adds two whole-array SMEM scale
+    inputs right after ``v_ref``, indexed ``[batch * H + head, kb]``
+    (one column for the dense cache, one per page slot for the paged
+    one): the resident int8 block is dequantized IN-REGISTER with its
+    slot's (dense) or page's (paged) per-head scale — the fp32 K/V
+    never exist outside VMEM, so the cache's HBM footprint (and the
+    DMA per step) is the int8 bytes."""
     import jax.experimental.pallas as pl
     if quant:
         ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = rest
@@ -423,6 +424,12 @@ def _decode_fwd_kernel(len_ref, q_ref, k_ref, v_ref, *rest, scale,
     kb = pl.program_id(2)
     length = len_ref[b]
     nblocks = (length + block_k - 1) // block_k   # this slot's valid blocks
+    if quant:
+        # program ids are read here: the interpreter cannot bind them
+        # inside a pl.when body. One scale column means one scale per
+        # slot row (dense cache); else one per page slot.
+        sc_row = b * pl.num_programs(1) + pl.program_id(1)
+        sc_col = kb if ks_ref.shape[1] > 1 else 0
 
     @pl.when(kb == 0)
     def _init():
@@ -437,8 +444,8 @@ def _decode_fwd_kernel(len_ref, q_ref, k_ref, v_ref, *rest, scale,
         k = k_ref[0, 0].astype(jnp.float32)            # (block_k, d)
         v = v_ref[0, 0].astype(jnp.float32)
         if quant:
-            k = k * ks_ref[0, 0]
-            v = v * vs_ref[0, 0]
+            k = k * ks_ref[sc_row, sc_col]
+            v = v * vs_ref[sc_row, sc_col]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)        # (sq, bk)
@@ -505,10 +512,11 @@ def decode_attention_pallas(q, k, v, lengths, scale=None, block_k=128,
     ]
     operands = [q, k, v]
     if quant:
-        in_specs += [pl.BlockSpec((1, 1),
-                                  lambda i, j, kb, lens: (i, j))] * 2
-        operands += [k_scale.astype(jnp.float32),
-                     v_scale.astype(jnp.float32)]
+        # per-(slot, head) scalars: whole in SMEM, read by program id
+        # (a (1, 1) VMEM block breaks the TPU (8, 128) tiling rule)
+        in_specs += [pl.BlockSpec(memory_space=pltpu.SMEM)] * 2
+        operands += [k_scale.astype(jnp.float32).reshape(b * h, 1),
+                     v_scale.astype(jnp.float32).reshape(b * h, 1)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(b, h, nkb),
@@ -577,7 +585,8 @@ def paged_decode_attention_pallas(q, k_pool, v_pool, table, lengths,
     slot. Compute for those steps is skipped in the kernel.
     ``k_scale``/``v_scale`` (n_pages, H) mark an int8 pool: each
     resident page is dequantized in VMEM with ITS OWN per-head scale
-    (the scale rides the same table-indexed BlockSpec as the page)."""
+    (gathered per slot through the same table that indexes the
+    page)."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -594,10 +603,6 @@ def paged_decode_attention_pallas(q, k_pool, v_pool, table, lengths,
         last = jnp.maximum((lens[i] + ps - 1) // ps - 1, 0)
         return (tbl[i, jnp.minimum(kb, last)], j, 0, 0)
 
-    def _sc_index(i, j, kb, lens, tbl):
-        last = jnp.maximum((lens[i] + ps - 1) // ps - 1, 0)
-        return (tbl[i, jnp.minimum(kb, last)], j)
-
     in_specs = [
         pl.BlockSpec((1, 1, sq, d),
                      lambda i, j, kb, lens, tbl: (i, j, 0, 0)),
@@ -606,9 +611,13 @@ def paged_decode_attention_pallas(q, k_pool, v_pool, table, lengths,
     ]
     operands = [q, k_pool, v_pool]
     if quant:
-        in_specs += [pl.BlockSpec((1, 1), _sc_index)] * 2
-        operands += [k_scale.astype(jnp.float32),
-                     v_scale.astype(jnp.float32)]
+        # each slot's page scales, gathered through its table row to
+        # (B * H, P_max) and held whole in SMEM: grid step kb reads
+        # column kb (a (1, 1) VMEM block breaks the (8, 128) tiling)
+        in_specs += [pl.BlockSpec(memory_space=pltpu.SMEM)] * 2
+        operands += [
+            sc.astype(jnp.float32)[table].transpose(0, 2, 1)
+            .reshape(b * h, p_max) for sc in (k_scale, v_scale)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(b, h, p_max),

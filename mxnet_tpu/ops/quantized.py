@@ -6,7 +6,7 @@ FLOPs — raises the ceiling. Weights are stored per-output-channel
 symmetric int8 (``quantize_channelwise``) and the projection matmul
 dequantizes them ON THE FLY, one output-channel block at a time:
 
-    y = x @ (wq.astype(f32) * scale[:, None]).T
+    y = (x @ wq.astype(f32).T) * scale[None, :]
 
 with the converted block living only in VMEM (Pallas TPU kernel) or
 cache (blocked jnp path) — the dequantized weight never materializes
@@ -83,23 +83,18 @@ def kv_quantize(x, scale):
         .astype(jnp.int8)
 
 
-def _block_n(n, block_n):
-    """Output channels per block: the requested width when it divides
-    ``n``, otherwise the whole matrix in one block (model dims here
-    are powers of two; an uneven tail would force a second program
-    shape)."""
-    bn = min(int(block_n), n)
-    return bn if n % bn == 0 else n
-
-
-def _dequant_dot(x2, wq_blk, s_blk):
+def _dequant_dot(x2, wq_blk, s_row):
     """The ONE canonical block computation both paths run: convert the
-    int8 block, scale per output channel, contract x's feature axis
-    against the weight's ``in`` axis in fp32. Kept as a shared helper
-    so the jnp/Pallas pair cannot drift apart numerically."""
-    wf = wq_blk.astype(jnp.float32) * s_blk[:, None]
-    return lax.dot_general(x2, wf, (((1,), (1,)), ((), ())),
-                           preferred_element_type=jnp.float32)
+    int8 block, contract x's feature axis against the weight's ``in``
+    axis in fp32, then scale each output channel (``s_row`` is
+    ``(1, bn)``: the scale multiplies the product, not the weight, so
+    it broadcasts along lanes and costs ``B * bn`` multiplies instead
+    of ``bn * K``). Kept as a shared helper so the jnp/Pallas pair
+    cannot drift apart numerically."""
+    y = lax.dot_general(x2, wq_blk.astype(jnp.float32),
+                        (((1,), (1,)), ((), ())),
+                        preferred_element_type=jnp.float32)
+    return y * s_row
 
 
 def dequant_matmul(x, wq, scales, block_n=_BLOCK_N):
@@ -107,11 +102,12 @@ def dequant_matmul(x, wq, scales, block_n=_BLOCK_N):
 
     ``x`` is ``(..., K)`` fp32, ``wq`` ``(N, K)`` int8 (the Dense
     ``(out, in)`` layout), ``scales`` ``(N,)`` fp32. Returns
-    ``(..., N)`` fp32. The weight is dequantized ``block_n`` output
+    ``(..., N)`` fp32. The weight is converted ``block_n`` output
     channels at a time inside a ``lax.map`` — the converted block is
     consumed by its dot before the next one exists, so peak extra
-    memory is one block, not the whole fp32 weight. On TPU dispatches
-    to the Pallas kernel (same per-block arithmetic)."""
+    memory is one block, not the whole fp32 weight; channels past the
+    last whole block (``N % block_n``) take one more dot. On TPU
+    dispatches to the Pallas kernel (same per-block arithmetic)."""
     wq = jnp.asarray(wq)
     scales = jnp.asarray(scales)
     x = jnp.asarray(x)
@@ -125,53 +121,60 @@ def dequant_matmul(x, wq, scales, block_n=_BLOCK_N):
         return dequant_matmul_pallas(x, wq, scales, block_n=block_n)
     lead = x.shape[:-1]
     x2 = x.reshape(-1, k).astype(jnp.float32)
-    bn = _block_n(n, block_n)
+    bn = min(int(block_n), n)
     nb = n // bn
+    s_row = scales[None, :]
 
     def body(j):
         wq_blk = lax.dynamic_slice(wq, (j * bn, 0), (bn, k))
-        s_blk = lax.dynamic_slice(scales, (j * bn,), (bn,))
+        s_blk = lax.dynamic_slice(s_row, (0, j * bn), (1, bn))
         return _dequant_dot(x2, wq_blk, s_blk)
 
     if nb == 1:
-        out = _dequant_dot(x2, wq, scales)
+        out = _dequant_dot(x2, wq[:bn], s_row[:, :bn])
     else:
         out = lax.map(body, jnp.arange(nb))        # (nb, B, bn)
-        out = out.transpose(1, 0, 2).reshape(x2.shape[0], n)
+        out = out.transpose(1, 0, 2).reshape(x2.shape[0], nb * bn)
+    if nb * bn < n:
+        tail = _dequant_dot(x2, wq[nb * bn:], s_row[:, nb * bn:])
+        out = jnp.concatenate([out, tail], axis=1)
     return out.reshape(*lead, n)
 
 
 def _dequant_matmul_kernel(x_ref, wq_ref, s_ref, o_ref):
     """One output-channel-block grid step: the int8 weight block and
-    its scales stream into VMEM, dequantize in-register, one fp32 dot.
-    The dequantized fp32 weight exists ONLY as this block."""
+    its scales stream into VMEM, convert in-register, one fp32 dot.
+    The fp32 weight exists ONLY as this block."""
     o_ref[...] = _dequant_dot(x_ref[...], wq_ref[...], s_ref[...])
 
 
 def dequant_matmul_pallas(x, wq, scales, block_n=_BLOCK_N,
                           interpret=False):
     """Pallas fused dequant-matmul: grid over output-channel blocks;
-    each step DMAs one ``(block_n, K)`` int8 block + its ``(block_n,)``
-    scales, dequantizes in VMEM, and writes one fp32 output block —
-    per-block arithmetic identical to the jnp path (bitwise-parity
-    tested)."""
+    each step DMAs one ``(block_n, K)`` int8 block + its ``(1,
+    block_n)`` scales row, converts in VMEM, and writes one fp32
+    output block — per-block arithmetic identical to the jnp path
+    (bitwise-parity tested). ``N`` need not divide by ``block_n``:
+    the last grid step overhangs, its out-of-range channels compute
+    on padding and are dropped at write-back. On TPU ``block_n`` must
+    be a multiple of 128 (one lane tile) unless it covers ``N``."""
     import jax.experimental.pallas as pl
 
     n, k = wq.shape
     lead = x.shape[:-1]
     x2 = x.reshape(-1, k).astype(jnp.float32)
     b = x2.shape[0]
-    bn = _block_n(n, block_n)
+    bn = min(int(block_n), n)
     out = pl.pallas_call(
         _dequant_matmul_kernel,
-        grid=(n // bn,),
+        grid=(pl.cdiv(n, bn),),
         in_specs=[
             pl.BlockSpec((b, k), lambda j: (0, 0)),
             pl.BlockSpec((bn, k), lambda j: (j, 0)),
-            pl.BlockSpec((bn,), lambda j: (j,)),
+            pl.BlockSpec((1, bn), lambda j: (0, j)),
         ],
         out_specs=pl.BlockSpec((b, bn), lambda j: (0, j)),
         out_shape=jax.ShapeDtypeStruct((b, n), jnp.float32),
         interpret=interpret,
-    )(x2, wq, scales)
+    )(x2, wq, scales[None, :])
     return out.reshape(*lead, n)

@@ -1,6 +1,6 @@
 """SPMD sharding layer — logical-axis partitioning over the device mesh.
 
-The T5X-style ``Partitioner`` (SNIPPETS.md [1]/[3]): parameters carry
+The T5X-style ``Partitioner``: parameters carry
 NAMED LOGICAL AXES (``"embed"``, ``"mlp"``, ``"heads"``, ``"kv"``,
 ``"vocab"``, ``"batch"``), an ORDERED rule list maps each logical axis
 to a mesh axis (or to ``None`` = replicated), and every parameter

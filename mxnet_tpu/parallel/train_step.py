@@ -35,6 +35,7 @@ from ..ndarray.ndarray import NDArray
 from ..random_state import next_key, trace_rng
 from ..gluon import _deferred
 from ..gluon.block import _flatten_arrays, _rebuild, CachedOp
+from ..ops import attention as _attention
 from . import get_mesh, AXIS_DP
 
 
@@ -224,6 +225,13 @@ class TrainStep:
         diff_nds = [params[i].data() for i in diff_idx]
         frozen_nds = [params[i].data() for i in frozen_idx]
         all_nds = diff_nds + frozen_nds
+        # the step differentiates inside its own program and never
+        # reads or writes the imperative .grad buffers initialize()
+        # allocated — release them: a dead copy of every parameter
+        # (3.4 GB at GPT-2 large, with which the step does not fit a
+        # 16 GB chip beside Adam's state)
+        for nd in diff_nds:
+            nd.release_grad()
 
         opt = self.optimizer
         if self._opt_states is None:
@@ -246,7 +254,15 @@ class TrainStep:
             saved = [nd._data for nd in all_nds]
             scope = _deferred.trace_scope()
             rec = autograd._RecordingScope(False, True)
-            with scope, rec, trace_rng(key):
+            # a step partitioned over several devices traces attention
+            # on its jnp paths, as mesh serving engines do: the TPU
+            # compiler cannot partition a pallas_call ("Mosaic kernels
+            # cannot be automatically partitioned") and the kernels
+            # have no shard_map wrapper yet (ROADMAP D6)
+            att = _attention.jnp_only() \
+                if self.mesh is not None and self.mesh.size > 1 \
+                else _nullcontext()
+            with scope, rec, trace_rng(key), att:
                 for nd, d in zip(diff_nds, diff_datas):
                     nd._data = d
                 for nd, d in zip(frozen_nds, frozen_datas):
@@ -493,6 +509,20 @@ class TrainStep:
                     + [nd._data for nd in frozen_nds] + list(states)))
         else:
             data_sh = label_sh = None
+            # COMMIT params and optimizer state to the device they sit
+            # on: a jitted step returns committed arrays, and jit keeps
+            # one executable per input-commitment signature — fresh
+            # (uncommitted) first inputs cost a second whole compile on
+            # step 2, which no build or trace counter sees
+            def commit(a):
+                if not isinstance(a, jax.Array) or a.committed:
+                    return a
+                return jax.device_put(a, next(iter(a.devices())))
+
+            for nd in all_nds:
+                nd._data = commit(nd._data)
+            for k in range(n_diff):
+                states[k] = jax.tree.map(commit, states[k])
 
         entry = {
             "data_sh": data_sh,
@@ -894,7 +924,7 @@ class TrainStep:
 
         Each signature builds its entry (if missing) and compiles it
         via ``jit.lower(...).compile()`` — moving trace + XLA compile
-        off the first training step. With ``MXTPU_COMPILE_CACHE_DIR``
+        off the first training step. With ``JAX_COMPILATION_CACHE_DIR``
         set the compile replays from the persistent cache, so a
         restarted process warms up at disk-read speed. Telemetry:
         ``parallel.train_step.warmup`` (count),

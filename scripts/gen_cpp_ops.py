@@ -13,6 +13,7 @@ Run:  python scripts/gen_cpp_ops.py            (rewrites ops.hpp)
 """
 from __future__ import annotations
 
+import argparse
 import keyword
 import os
 import sys
@@ -38,6 +39,11 @@ def cpp_name(name):
 
 
 def main():
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--check", action="store_true",
+                    help="compare with ops.hpp, write nothing; exit 1 "
+                         "when it drifted from the op table")
+    args = ap.parse_args()     # any other argument is an error
     sys.path.insert(0, ROOT)
     os.environ.setdefault("XLA_FLAGS",
                           "--xla_force_host_platform_device_count=1")
@@ -131,7 +137,7 @@ def main():
         "",
     ]
     text = "\n".join(lines)
-    if "--check" in sys.argv:
+    if args.check:
         on_disk = open(OUT).read() if os.path.exists(OUT) else ""
         if on_disk != text:
             print("ops.hpp is STALE — rerun scripts/gen_cpp_ops.py")

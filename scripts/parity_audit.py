@@ -6,7 +6,7 @@ functions, and diffs them against the LIVE mxnet_tpu namespaces.
 Writes PARITY.md with per-module coverage and the exact missing
 names, so "check the inventory line by line" is mechanical.
 
-Run:  MXTPU_PLATFORM=cpu python scripts/parity_audit.py
+Run:  JAX_PLATFORMS=cpu python scripts/parity_audit.py
 """
 from __future__ import annotations
 

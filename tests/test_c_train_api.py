@@ -202,7 +202,7 @@ int main(int argc, char** argv) {
         capture_output=True, text=True)
     assert r.returncode == 0, r.stderr[:400]
     env = dict(os.environ)
-    env["MXTPU_PLATFORM"] = "cpu"
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = ROOT
     r = subprocess.run([exe, str(trace_dir / "trace.json")],
                        capture_output=True, text=True, timeout=300,

@@ -1,0 +1,120 @@
+"""The main path's Pallas kernels, compiled for a DESCRIBED v5e at
+GPT-2 large shapes (20 heads x 64, 1280 units, MLP 5120, vocabulary
+50257, 1024 positions, page 16, 8 slots).
+
+Interpret-mode parity tests cannot see what the chip's compiler
+refuses: a block shape off the (8, 128) tiling, a scalar operand in
+VMEM, a block larger than the scoped VMEM limit. These compiles can,
+at no chip time (on-chip-measurement guide, section 2): the installed
+TPU compiler targets a ``v5e:2x2`` that is described, not attached.
+Nothing runs, so nothing here says anything about results or speed.
+
+The topology is described inside a module-scoped fixture — never at
+import, in a skipif or in parametrize: only one process may hold the
+TPU library, and every xdist worker imports this file. All such tests
+stay in this one file for the same reason.
+"""
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from mxnet_tpu.ops import attention as att
+from mxnet_tpu.ops import quantized as qz
+
+B, H, D, S_MAX, PAGE = 8, 20, 64, 1024, 16
+P_MAX = S_MAX // PAGE
+N_PAGES = B * P_MAX + 1
+SPEC_K = 4
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    import os
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure means "skip"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture()
+def no_compile_cache():
+    """A described-device executable can be written to the persistent
+    cache but not read back without a chip; keep it out."""
+    from jax.experimental.compilation_cache import compilation_cache
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+    compilation_cache.reset_cache()
+
+
+def _compile(fn, sharding, *shapes):
+    args = [jax.ShapeDtypeStruct(s, d, sharding=sharding)
+            for s, d in shapes]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+    return text
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "fp32"])
+@pytest.mark.parametrize("seq", [16, 32, 200, 1024])
+def test_flash_forward(one_chip, no_compile_cache, dtype, seq):
+    """The engine's paged prefill buckets (16, 32), an unaligned
+    length (block-padded) and the full training sequence."""
+    qkv = ((2, H, seq, D), dtype)
+    _compile(lambda q, k, v: att.flash_attention_pallas(
+        q, k, v, causal=True), one_chip, qkv, qkv, qkv)
+
+
+_KV = {"bf16": jnp.bfloat16, "fp32": jnp.float32, "int8": jnp.int8}
+
+
+def _scales(kind, shape):
+    return [(shape, jnp.float32)] * 2 if kind == "int8" else []
+
+
+@pytest.mark.parametrize("kind", list(_KV))
+@pytest.mark.parametrize("sq", [1, SPEC_K + 1], ids=["decode", "verify"])
+def test_dense_decode(one_chip, no_compile_cache, kind, sq):
+    qdt = jnp.float32 if kind == "int8" else _KV[kind]
+    kv = ((B, H, S_MAX, D), _KV[kind])
+
+    def fn(q, k, v, lens, *sc):
+        return att.decode_attention_pallas(
+            q, k, v, lens, k_scale=sc[0] if sc else None,
+            v_scale=sc[1] if sc else None)
+
+    _compile(fn, one_chip, ((B, H, sq, D), qdt), kv, kv,
+             ((B,), jnp.int32), *_scales(kind, (B, H)))
+
+
+@pytest.mark.parametrize("kind", list(_KV))
+@pytest.mark.parametrize("sq", [1, SPEC_K + 1], ids=["decode", "verify"])
+def test_paged_decode(one_chip, no_compile_cache, kind, sq):
+    qdt = jnp.float32 if kind == "int8" else _KV[kind]
+    pool = ((N_PAGES, H, PAGE, D), _KV[kind])
+
+    def fn(q, k, v, table, lens, *sc):
+        return att.paged_decode_attention_pallas(
+            q, k, v, table, lens, k_scale=sc[0] if sc else None,
+            v_scale=sc[1] if sc else None)
+
+    _compile(fn, one_chip, ((B, H, sq, D), qdt), pool, pool,
+             ((B, P_MAX), jnp.int32), ((B,), jnp.int32),
+             *_scales(kind, (N_PAGES, H)))
+
+
+@pytest.mark.parametrize("n,k", [(1280, 1280), (5120, 1280),
+                                 (1280, 5120), (50257, 1280)])
+def test_dequant_matmul(one_chip, no_compile_cache, n, k):
+    """Every projection shape of the block, and the vocabulary (not a
+    multiple of the block: the last grid step overhangs)."""
+    _compile(qz.dequant_matmul_pallas, one_chip, ((B, k), jnp.float32),
+             ((n, k), jnp.int8), ((n,), jnp.float32))

@@ -128,3 +128,33 @@ def test_mixed_epoch_without_bucketing_rebuilds():
     for lo in range(0, 45, 16):
         step(np.array(X[lo:lo + 16]), np.array(Y[lo:lo + 16]))
     assert _counters().get("parallel.train_step.build") == 2
+
+
+def test_one_device_train_step_compiles_once():
+    """Without a mesh, fresh parameters and optimizer state are
+    uncommitted arrays while a jitted step returns committed ones; jit
+    keeps one executable per commitment signature, so step 2 used to
+    pay a second whole XLA compile that no build/trace counter saw.
+    Counted at the backend: steps 2 and 3 compile nothing."""
+    import jax
+    rng = onp.random.RandomState(0)
+    X = mx.np.array(rng.randn(16, 8).astype(onp.float32))
+    Y = mx.np.array(rng.randint(0, 4, 16).astype(onp.int32))
+    step = parallel.TrainStep(_mlp(), gluon.loss.SoftmaxCrossEntropyLoss(),
+                              "adam", {"learning_rate": 0.01}, mesh=None)
+    compiles = []
+
+    def listener(event, duration, **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiles.append(event)
+
+    jax.monitoring.register_event_duration_secs_listener(listener)
+    try:
+        float(step(X, Y).asnumpy())
+        assert compiles, "the first step compiles the program"
+        del compiles[:]
+        for _ in range(2):
+            float(step(X, Y).asnumpy())
+        assert compiles == []
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listener)

@@ -71,7 +71,7 @@ print("large-tensor OK")
 def test_large_tensor_int64_smoke():
     env = dict(os.environ)
     env["MXTPU_ENABLE_X64"] = "1"
-    env["MXTPU_PLATFORM"] = "cpu"
+    env["JAX_PLATFORMS"] = "cpu"
     env.pop("XLA_FLAGS", None)  # 1 device; no virtual-mesh splitting
     env["PYTHONPATH"] = ROOT + os.pathsep + env.get("PYTHONPATH", "")
     proc = subprocess.run([sys.executable, "-c", SCRIPT], env=env,

@@ -453,3 +453,33 @@ def test_overlap_gather_barrier_chain(mesh_devices):
     ag_on = partition.hlo_collectives(hlo_on).get("all-gather")
     ag_off = partition.hlo_collectives(hlo_off).get("all-gather")
     assert ag_on == ag_off
+
+
+@pytest.mark.requires_mesh(4)
+def test_mesh_trainstep_traces_attention_without_kernels(
+        mesh_devices, monkeypatch):
+    """The TPU compiler cannot partition a ``pallas_call`` over a mesh
+    ("Mosaic kernels cannot be automatically partitioned"), so a
+    TrainStep over several devices traces attention under
+    ``jnp_only()``, like a mesh serving engine. Seen from the CPU by
+    answering ``_use_pallas()`` as a TPU backend would: a kernel
+    lowered here would be refused (no interpret mode), the jnp path
+    is not — and a one-device step does take the kernel."""
+    from mxnet_tpu import parallel
+    from mxnet_tpu.ops import attention as att
+    nets = [_net(), _net()]
+    for net in nets:
+        net._gen_params()   # the eager shape probe runs unpatched
+    monkeypatch.setattr(
+        att, "_use_pallas",
+        lambda: not getattr(att._JNP_ONLY, "on", False))
+    data, label = _train_batch()
+    mesh = parallel.make_mesh((4,), ("dp",), devices=mesh_devices[:4])
+    step = parallel.TrainStep(nets[0], _LmLoss(), "adam",
+                              {"learning_rate": 0.01}, mesh=mesh,
+                              layout="fsdp")
+    assert onp.isfinite(float(step(data, label)))
+    one = parallel.TrainStep(nets[1], _LmLoss(), "adam",
+                             {"learning_rate": 0.01})
+    with pytest.raises(ValueError, match="interpret mode"):
+        one(data, label)

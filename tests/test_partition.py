@@ -137,7 +137,12 @@ def test_reduce_scatter_plus_all_gather_equals_allreduce():
         b._install(jax.device_put(b._data, NamedSharding(mesh, P("dp"))))
         parallel.allreduce(a, axis_name="dp")
         kv.reduce_scatter(b, axis_name="dp")
-        assert b._data.sharding.spec == P("dp")
+        # compare specs padded with None to the array's rank, as
+        # kvstore.reduce_scatter does: P('dp', None) != P('dp') as
+        # objects, though they place the array identically
+        spec = b._data.sharding.spec
+        assert tuple(spec) + (None,) * (b._data.ndim - len(spec)) \
+            == ("dp", None)
         kv.all_gather(b, axis_name="dp")
         onp.testing.assert_array_equal(a.asnumpy(), b.asnumpy())
         # replicated input (each copy counts once: sum = n * x)

@@ -97,6 +97,29 @@ def test_dequant_matmul_jnp_pallas_bitwise():
         assert (a == b).all()
 
 
+@pytest.mark.requires_pallas
+@pytest.mark.parametrize("n", [96, 100, 257])
+def test_dequant_matmul_tail_block(n):
+    """N need not divide by the block (a vocabulary never does): the
+    jnp path takes the leftover channels in one more dot, the kernel's
+    last grid step overhangs and its out-of-range channels are dropped
+    — both agree with the dequantized reference."""
+    from mxnet_tpu.ops.quantized import (dequant_matmul,
+                                         dequant_matmul_pallas,
+                                         quantize_channelwise)
+    rng = onp.random.RandomState(3)
+    w = rng.randn(n, 40).astype("f4")
+    x = rng.randn(5, 40).astype("f4")
+    wq, s = quantize_channelwise(w)
+    ref = x @ (onp.asarray(wq, "f4") * onp.asarray(s)[:, None]).T
+    a = onp.asarray(dequant_matmul(x, wq, s, block_n=32))
+    b = onp.asarray(dequant_matmul_pallas(x, wq, s, block_n=32,
+                                          interpret=True))
+    assert a.shape == b.shape == (5, n)
+    assert onp.allclose(a, ref, atol=1e-4)
+    assert onp.allclose(b, ref, atol=1e-4)
+
+
 # -- int8-KV decode attention ------------------------------------------
 
 def _quant_kv(kf, vf):

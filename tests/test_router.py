@@ -411,8 +411,10 @@ def test_infer_mode_routing_and_crash_retry(base):
                     fault_injector=injector)
     rng = onp.random.RandomState(12)
     xs = [mx.np.array(rng.randn(1, 4).astype("f4")) for _ in range(6)]
-    futs = [router.submit(x) for x in xs]
+    # before any submit: this thread tracing the block while the
+    # replica's dispatcher traces it too is an UnexpectedTracerError
     expected = [engines[1].block(x).asnumpy() for x in xs]
+    futs = [router.submit(x) for x in xs]
     for f, want in zip(futs, expected):
         onp.testing.assert_allclose(f.result(timeout=120).asnumpy(),
                                     want, rtol=1e-5, atol=1e-6)

@@ -117,6 +117,15 @@ _LORA_PROJECTIONS = ("q_proj", "k_proj", "v_proj", "out_proj")
 _kv_scale = _qz.kv_scale
 _kv_quantize = _qz.kv_quantize
 
+# HLO metadata only: every operation of a generation or training
+# program carries the scope it was traced under in its ``op_name``
+# (``jit(gpt_paged_decode)/attn/...``), so a device trace says which
+# lines of the model emitted it. The scopes are ``attn`` (projections,
+# the attention call and whatever it reshapes), ``kv_write`` (the write
+# into the cache or pool), ``mlp``, ``lm_head`` and ``sample``
+# (docs/OBSERVABILITY.md).
+_scope = jax.named_scope
+
 
 def _cache_insert(cache, new, pos):
     """Write ``new`` (B, H, 1, Dh) into ``cache`` (B, H, S, Dh) at
@@ -140,6 +149,26 @@ def _to_pages(a, page_size, dtype):
     h, c, d = a.shape[1:]
     return a[0].reshape(h, c // page_size, page_size, d) \
         .transpose(1, 0, 2, 3).astype(dtype)
+
+
+def _gathered_attention(q, k_pool, v_pool, k_scale, v_scale, table,
+                        start):
+    """A chunk's or a verify step's queries over each row's gathered
+    view of the pool (``table`` (B, P_max)), causal in global
+    coordinates from ``start``. ``k_scale``/``v_scale`` (n_pages, H)
+    mark an INT8 pool, whose every page is dequantized with the scale
+    it was written under."""
+    kg = _att.gather_pages(k_pool, table)
+    vg = _att.gather_pages(v_pool, table)
+    if k_scale is not None:
+        ps = k_pool.shape[2]
+        kg = kg.astype(jnp.float32) \
+            * _att.expand_page_scales(k_scale, table, ps)[..., None]
+        vg = vg.astype(jnp.float32) \
+            * _att.expand_page_scales(v_scale, table, ps)[..., None]
+    else:
+        kg, vg = kg.astype(q.dtype), vg.astype(q.dtype)
+    return _att.chunked_prefill_attention(q, kg, vg, start)
 
 
 class GPTBlock(HybridBlock):
@@ -216,25 +245,29 @@ class GPTBlock(HybridBlock):
         return out
 
     def _qkv(self, x):
-        h = self.ln1(x)
-        return (self._split(self._proj("q_proj", h)),
-                self._split(self._proj("k_proj", h)),
-                self._split(self._proj("v_proj", h)))
+        with _scope("attn"):
+            h = self.ln1(x)
+            return (self._split(self._proj("q_proj", h)),
+                    self._split(self._proj("k_proj", h)),
+                    self._split(self._proj("v_proj", h)))
 
     def _finish(self, x, attn):
-        y = self._proj("out_proj", self._merge(attn))
-        if self.drop is not None:
-            y = self.drop(y)
-        x = x + y
-        y = self._proj("ffn2", self._proj("ffn1", self.ln2(x)))
-        if self.drop is not None:
-            y = self.drop(y)
-        return x + y
+        with _scope("attn"):
+            y = self._proj("out_proj", self._merge(attn))
+            if self.drop is not None:
+                y = self.drop(y)
+            x = x + y
+        with _scope("mlp"):
+            y = self._proj("ffn2", self._proj("ffn1", self.ln2(x)))
+            if self.drop is not None:
+                y = self.drop(y)
+            return x + y
 
     def forward(self, x):
         q, k, v = self._qkv(x)
         from ... import numpy_extension as npx
-        attn = npx.flash_attention(q, k, v, causal=True)
+        with _scope("attn"):
+            attn = npx.flash_attention(q, k, v, causal=True)
         return self._finish(x, attn)
 
     # -- generation (called inside the model's jitted closures) --------
@@ -242,8 +275,9 @@ class GPTBlock(HybridBlock):
         """Causal attention over the (padded) prompt; returns the block
         output and the raw K/V rows to write into the cache."""
         q, k, v = self._qkv(x)
-        attn = NDArray(_att.flash_attention(q._data, k._data, v._data,
-                                            True, None), ctx=x.ctx)
+        with _scope("attn"):
+            attn = NDArray(_att.flash_attention(
+                q._data, k._data, v._data, True, None), ctx=x.ctx)
         return self._finish(x, attn), (k._data, v._data)
 
     def decode(self, x, k_cache, v_cache, pos, att_len, k_scale=None,
@@ -257,22 +291,22 @@ class GPTBlock(HybridBlock):
         one slot row must share one scale) and attention dequantizes
         in the kernel."""
         q, k, v = self._qkv(x)
-        if k_scale is not None:
-            kc = _cache_insert(
-                k_cache, _kv_quantize(k._data, k_scale[:, :, None, None]),
-                pos)
-            vc = _cache_insert(
-                v_cache, _kv_quantize(v._data, v_scale[:, :, None, None]),
-                pos)
+        with _scope("kv_write"):
+            if k_scale is not None:
+                kc = _cache_insert(k_cache, _kv_quantize(
+                    k._data, k_scale[:, :, None, None]), pos)
+                vc = _cache_insert(v_cache, _kv_quantize(
+                    v._data, v_scale[:, :, None, None]), pos)
+            else:
+                kc = _cache_insert(k_cache,
+                                   k._data.astype(k_cache.dtype), pos)
+                vc = _cache_insert(v_cache,
+                                   v._data.astype(v_cache.dtype), pos)
+        with _scope("attn"):
             attn = NDArray(
                 _att.decode_attention(q._data, kc, vc, att_len,
                                       k_scale=k_scale, v_scale=v_scale),
                 ctx=x.ctx)
-            return self._finish(x, attn), kc, vc
-        kc = _cache_insert(k_cache, k._data.astype(k_cache.dtype), pos)
-        vc = _cache_insert(v_cache, v._data.astype(v_cache.dtype), pos)
-        attn = NDArray(_att.decode_attention(q._data, kc, vc, att_len),
-                       ctx=x.ctx)
         return self._finish(x, attn), kc, vc
 
     def verify(self, x, k_cache, v_cache, pos, start, k_scale=None,
@@ -289,24 +323,26 @@ class GPTBlock(HybridBlock):
         prefill-time scale and the attention view dequantizes with it
         (the decode-path convention — one slot row, one scale)."""
         q, k, v = self._qkv(x)
-        if k_scale is not None:
-            kc = _cache_insert(
-                k_cache, _kv_quantize(k._data, k_scale[:, :, None, None]),
-                pos)
-            vc = _cache_insert(
-                v_cache, _kv_quantize(v._data, v_scale[:, :, None, None]),
-                pos)
-            kf = kc.astype(jnp.float32) * k_scale[:, :, None, None]
-            vf = vc.astype(jnp.float32) * v_scale[:, :, None, None]
-        else:
-            kc = _cache_insert(k_cache, k._data.astype(k_cache.dtype),
-                               pos)
-            vc = _cache_insert(v_cache, v._data.astype(v_cache.dtype),
-                               pos)
-            kf, vf = kc, vc
-        attn = NDArray(_att.chunked_prefill_attention(
-            q._data, kf.astype(q._data.dtype), vf.astype(q._data.dtype),
-            start), ctx=x.ctx)
+        with _scope("kv_write"):
+            if k_scale is not None:
+                kc = _cache_insert(k_cache, _kv_quantize(
+                    k._data, k_scale[:, :, None, None]), pos)
+                vc = _cache_insert(v_cache, _kv_quantize(
+                    v._data, v_scale[:, :, None, None]), pos)
+            else:
+                kc = _cache_insert(k_cache,
+                                   k._data.astype(k_cache.dtype), pos)
+                vc = _cache_insert(v_cache,
+                                   v._data.astype(v_cache.dtype), pos)
+        with _scope("attn"):
+            if k_scale is not None:
+                kf = kc.astype(jnp.float32) * k_scale[:, :, None, None]
+                vf = vc.astype(jnp.float32) * v_scale[:, :, None, None]
+            else:
+                kf, vf = kc, vc
+            attn = NDArray(_att.chunked_prefill_attention(
+                q._data, kf.astype(q._data.dtype),
+                vf.astype(q._data.dtype), start), ctx=x.ctx)
         return self._finish(x, attn), kc, vc
 
     # -- paged-cache generation (serving/generate.py paged mode) --------
@@ -330,30 +366,32 @@ class GPTBlock(HybridBlock):
         scrap-page redirection as the data. Returns the updated scale
         pools alongside the K/V pools."""
         q, k, v = self._qkv(x)
-        if k_scale is not None:
-            fresh = (offset == 0)[:, None]
-            ks_eff = jnp.where(fresh, k_scale[prev_page], k_scale[page])
-            vs_eff = jnp.where(fresh, v_scale[prev_page], v_scale[page])
-            ksp = k_scale.at[page].set(ks_eff)
-            vsp = v_scale.at[page].set(vs_eff)
-            kp = k_pool.at[page, :, offset, :].set(
-                _kv_quantize(k._data[:, :, 0, :], ks_eff[:, :, None]))
-            vp = v_pool.at[page, :, offset, :].set(
-                _kv_quantize(v._data[:, :, 0, :], vs_eff[:, :, None]))
+        ksp = vsp = None
+        with _scope("kv_write"):
+            if k_scale is not None:
+                fresh = (offset == 0)[:, None]
+                ks_eff = jnp.where(fresh, k_scale[prev_page],
+                                   k_scale[page])
+                vs_eff = jnp.where(fresh, v_scale[prev_page],
+                                   v_scale[page])
+                ksp = k_scale.at[page].set(ks_eff)
+                vsp = v_scale.at[page].set(vs_eff)
+                kp = k_pool.at[page, :, offset, :].set(_kv_quantize(
+                    k._data[:, :, 0, :], ks_eff[:, :, None]))
+                vp = v_pool.at[page, :, offset, :].set(_kv_quantize(
+                    v._data[:, :, 0, :], vs_eff[:, :, None]))
+            else:
+                dt = k_pool.dtype
+                kp = k_pool.at[page, :, offset, :].set(
+                    k._data[:, :, 0, :].astype(dt))
+                vp = v_pool.at[page, :, offset, :].set(
+                    v._data[:, :, 0, :].astype(dt))
+        with _scope("attn"):
             attn = NDArray(
                 _att.paged_decode_attention(q._data, kp, vp, table,
                                             att_len, k_scale=ksp,
                                             v_scale=vsp), ctx=x.ctx)
-            return self._finish(x, attn), kp, vp, ksp, vsp
-        dt = k_pool.dtype
-        kp = k_pool.at[page, :, offset, :].set(
-            k._data[:, :, 0, :].astype(dt))
-        vp = v_pool.at[page, :, offset, :].set(
-            v._data[:, :, 0, :].astype(dt))
-        attn = NDArray(_att.paged_decode_attention(q._data, kp, vp,
-                                                   table, att_len),
-                       ctx=x.ctx)
-        return self._finish(x, attn), kp, vp, None, None
+        return self._finish(x, attn), kp, vp, ksp, vsp
 
     def prefill_chunk(self, x, k_pool, v_pool, pages, page_ids, start,
                       k_scale=None, v_scale=None):
@@ -369,33 +407,28 @@ class GPTBlock(HybridBlock):
         included — with the scale that page was written under."""
         q, k, v = self._qkv(x)
         ps = k_pool.shape[2]
-        if k_scale is not None:
-            kpg = _to_pages(k._data, ps, jnp.float32)
-            vpg = _to_pages(v._data, ps, jnp.float32)
-            ks_new = _kv_scale(kpg, (2, 3))          # (C/ps, H)
-            vs_new = _kv_scale(vpg, (2, 3))
-            kp = k_pool.at[page_ids].set(
-                _kv_quantize(kpg, ks_new[:, :, None, None]))
-            vp = v_pool.at[page_ids].set(
-                _kv_quantize(vpg, vs_new[:, :, None, None]))
-            ksp = k_scale.at[page_ids].set(ks_new)
-            vsp = v_scale.at[page_ids].set(vs_new)
-            kg = _att.gather_pages(kp, pages[None]).astype(jnp.float32) \
-                * _att.expand_page_scales(ksp, pages[None], ps)[..., None]
-            vg = _att.gather_pages(vp, pages[None]).astype(jnp.float32) \
-                * _att.expand_page_scales(vsp, pages[None], ps)[..., None]
-            attn = NDArray(_att.chunked_prefill_attention(
-                q._data, kg, vg, start), ctx=x.ctx)
-            return self._finish(x, attn), kp, vp, ksp, vsp
-        dt = k_pool.dtype
-        kp = k_pool.at[page_ids].set(_to_pages(k._data, ps, dt))
-        vp = v_pool.at[page_ids].set(_to_pages(v._data, ps, dt))
-        kg = _att.gather_pages(kp, pages[None])
-        vg = _att.gather_pages(vp, pages[None])
-        attn = NDArray(_att.chunked_prefill_attention(
-            q._data, kg.astype(q._data.dtype),
-            vg.astype(q._data.dtype), start), ctx=x.ctx)
-        return self._finish(x, attn), kp, vp, None, None
+        ksp = vsp = None
+        with _scope("kv_write"):
+            if k_scale is not None:
+                kpg = _to_pages(k._data, ps, jnp.float32)
+                vpg = _to_pages(v._data, ps, jnp.float32)
+                ks_new = _kv_scale(kpg, (2, 3))          # (C/ps, H)
+                vs_new = _kv_scale(vpg, (2, 3))
+                kp = k_pool.at[page_ids].set(
+                    _kv_quantize(kpg, ks_new[:, :, None, None]))
+                vp = v_pool.at[page_ids].set(
+                    _kv_quantize(vpg, vs_new[:, :, None, None]))
+                ksp = k_scale.at[page_ids].set(ks_new)
+                vsp = v_scale.at[page_ids].set(vs_new)
+            else:
+                dt = k_pool.dtype
+                kp = k_pool.at[page_ids].set(_to_pages(k._data, ps, dt))
+                vp = v_pool.at[page_ids].set(_to_pages(v._data, ps, dt))
+        with _scope("attn"):
+            attn = NDArray(_gathered_attention(
+                q._data, kp, vp, ksp, vsp, pages[None], start),
+                ctx=x.ctx)
+        return self._finish(x, attn), kp, vp, ksp, vsp
 
     def verify_paged(self, x, k_pool, v_pool, table, page, offset,
                      start, k_scale=None, v_scale=None, fresh=None,
@@ -416,38 +449,31 @@ class GPTBlock(HybridBlock):
         ``decode_paged``'s predecessor-scale inheritance; positions in
         partially-committed pages reuse that page's scale."""
         q, k, v = self._qkv(x)
-        kt = k._data.transpose(0, 2, 1, 3)            # (B, R, H, Dh)
-        vt = v._data.transpose(0, 2, 1, 3)
-        ps = k_pool.shape[2]
-        if k_scale is not None:
-            ks_eff = jnp.where(fresh[..., None],
-                               k_scale[anchor_page][:, None, :],
-                               k_scale[page])         # (B, R, H)
-            vs_eff = jnp.where(fresh[..., None],
-                               v_scale[anchor_page][:, None, :],
-                               v_scale[page])
-            ksp = k_scale.at[page].set(ks_eff)
-            vsp = v_scale.at[page].set(vs_eff)
-            kp = k_pool.at[page, :, offset, :].set(
-                _kv_quantize(kt, ks_eff[..., None]))
-            vp = v_pool.at[page, :, offset, :].set(
-                _kv_quantize(vt, vs_eff[..., None]))
-            kg = _att.gather_pages(kp, table).astype(jnp.float32) \
-                * _att.expand_page_scales(ksp, table, ps)[..., None]
-            vg = _att.gather_pages(vp, table).astype(jnp.float32) \
-                * _att.expand_page_scales(vsp, table, ps)[..., None]
-            attn = NDArray(_att.chunked_prefill_attention(
-                q._data, kg, vg, start), ctx=x.ctx)
-            return self._finish(x, attn), kp, vp, ksp, vsp
-        dt = k_pool.dtype
-        kp = k_pool.at[page, :, offset, :].set(kt.astype(dt))
-        vp = v_pool.at[page, :, offset, :].set(vt.astype(dt))
-        kg = _att.gather_pages(kp, table)
-        vg = _att.gather_pages(vp, table)
-        attn = NDArray(_att.chunked_prefill_attention(
-            q._data, kg.astype(q._data.dtype),
-            vg.astype(q._data.dtype), start), ctx=x.ctx)
-        return self._finish(x, attn), kp, vp, None, None
+        ksp = vsp = None
+        with _scope("kv_write"):
+            kt = k._data.transpose(0, 2, 1, 3)        # (B, R, H, Dh)
+            vt = v._data.transpose(0, 2, 1, 3)
+            if k_scale is not None:
+                ks_eff = jnp.where(fresh[..., None],
+                                   k_scale[anchor_page][:, None, :],
+                                   k_scale[page])         # (B, R, H)
+                vs_eff = jnp.where(fresh[..., None],
+                                   v_scale[anchor_page][:, None, :],
+                                   v_scale[page])
+                ksp = k_scale.at[page].set(ks_eff)
+                vsp = v_scale.at[page].set(vs_eff)
+                kp = k_pool.at[page, :, offset, :].set(
+                    _kv_quantize(kt, ks_eff[..., None]))
+                vp = v_pool.at[page, :, offset, :].set(
+                    _kv_quantize(vt, vs_eff[..., None]))
+            else:
+                dt = k_pool.dtype
+                kp = k_pool.at[page, :, offset, :].set(kt.astype(dt))
+                vp = v_pool.at[page, :, offset, :].set(vt.astype(dt))
+        with _scope("attn"):
+            attn = NDArray(_gathered_attention(
+                q._data, kp, vp, ksp, vsp, table, start), ctx=x.ctx)
+        return self._finish(x, attn), kp, vp, ksp, vsp
 
     def peek_paged(self, x, k_pool, v_pool, table, att_len,
                    k_scale=None, v_scale=None):
@@ -457,12 +483,10 @@ class GPTBlock(HybridBlock):
         whose entire prompt is cached needs one of these per layer, and
         zero prefill compute."""
         q, _k, _v = self._qkv(x)
-        attn = NDArray(_att.paged_decode_attention(q._data, k_pool,
-                                                   v_pool, table,
-                                                   att_len,
-                                                   k_scale=k_scale,
-                                                   v_scale=v_scale),
-                       ctx=x.ctx)
+        with _scope("attn"):
+            attn = NDArray(_att.paged_decode_attention(
+                q._data, k_pool, v_pool, table, att_len,
+                k_scale=k_scale, v_scale=v_scale), ctx=x.ctx)
         return self._finish(x, attn)
 
 
@@ -589,11 +613,15 @@ class GPTModel(HybridBlock):
             x = self.embed_drop(x)
         return x
 
+    def _head(self, x):
+        with _scope("lm_head"):
+            return self.lm_head(self.ln_f(x))
+
     def forward(self, tokens):
         x = self._embed(tokens)
         for blk in self._blocks():
             x = blk(x)
-        return self.lm_head(self.ln_f(x))
+        return self._head(x)
 
     # -- generation API ------------------------------------------------
     def _clear_cached_op(self):
@@ -1053,8 +1081,13 @@ class GPTModel(HybridBlock):
         closures. ``force_jnp`` (a mesh-sharded serving engine sets
         ``model._force_jnp_attention``) traces the attention ops on
         their jnp paths — a ``pallas_call`` cannot ride inside an
-        SPMD program without its own ``shard_map``."""
-        def _bind(fn):
+        SPMD program without its own ``shard_map``.
+
+        ``_bind(fn, name)`` names the wrapper ``name`` before it is
+        jitted, so each role is its own ``jit_<name>`` program on the
+        profiler's ``XLA Modules`` line (docs/OBSERVABILITY.md lists
+        them; the benchmark's per-program device times match them)."""
+        def _bind(fn, name):
             def wrapper(key, param_datas, quant_tabs, lora_tabs,
                         lora_idx, *args):
                 telemetry.counter("model.gpt.trace")
@@ -1086,6 +1119,7 @@ class GPTModel(HybridBlock):
                             blk._qbind = s
                         for blk, s in zip(blocks, saved_l):
                             blk._lbind = s
+            wrapper.__name__ = wrapper.__qualname__ = name
             return wrapper
         return _bind
 
@@ -1119,7 +1153,7 @@ class GPTModel(HybridBlock):
                 v_scale=cache["v_scale"][li] if quant_kv else None)
             ks.append(kc)
             vs.append(vc)
-        logits = self.lm_head(self.ln_f(x))          # (B, R, V)
+        logits = self._head(x)                       # (B, R, V)
         new_cache = {"k": tuple(ks), "v": tuple(vs), "len": ln}
         if quant_kv:
             new_cache["k_scale"] = cache["k_scale"]
@@ -1174,7 +1208,7 @@ class GPTModel(HybridBlock):
             vs.append(vp)
             kscs.append(ksp)
             vscs.append(vsp)
-        logits = self.lm_head(self.ln_f(x))          # (B, R, V)
+        logits = self._head(x)                       # (B, R, V)
         new_cache = {"k": tuple(ks), "v": tuple(vs),
                      "table": cache["table"], "len": ln}
         if quant_kv:
@@ -1210,7 +1244,7 @@ class GPTModel(HybridBlock):
                 v_scale=cache["v_scale"][li] if quant_kv else None)
             ks.append(kc)
             vs.append(vc)
-        logits = self.lm_head(self.ln_f(x))             # (B, 1, V)
+        logits = self._head(x)                       # (B, 1, V)
         new_len = ln + 1 if live is None \
             else ln + live.astype(jnp.int32)
         new_cache = {"k": tuple(ks), "v": tuple(vs), "len": new_len}
@@ -1264,7 +1298,7 @@ class GPTModel(HybridBlock):
             vs.append(vp)
             kscs.append(ksp)
             vscs.append(vsp)
-        logits = self.lm_head(self.ln_f(x))
+        logits = self._head(x)
         new_cache = {"k": tuple(ks), "v": tuple(vs),
                      "table": cache["table"],
                      "len": ln + live.astype(jnp.int32)}
@@ -1293,39 +1327,40 @@ class GPTModel(HybridBlock):
             # logits of the LAST VALID prompt token (predicts token 1)
             idx = jnp.clip(valid_len - 1, 0, sb - 1)
             last = x._data[jnp.arange(b), idx][:, None, :]   # (b, 1, U)
-            logits = self.lm_head(self.ln_f(NDArray(last)))
-            dt = cache["k"][0].dtype
-            if dt == jnp.int8:
-                # int8 cache: per-head-per-slot scales from the
-                # prompt's amax (the bucket's pad rows contribute —
-                # harmless overestimate); decode reuses them
-                ksc = [_kv_scale(k, (2, 3)) for k in ks]     # (b, H)
-                vsc = [_kv_scale(v, (2, 3)) for v in vs]
-                new_cache = {
-                    "k": tuple(
-                        c.at[slots, :, :sb, :].set(
-                            _kv_quantize(k, s[:, :, None, None]))
-                        for c, k, s in zip(cache["k"], ks, ksc)),
-                    "v": tuple(
-                        c.at[slots, :, :sb, :].set(
-                            _kv_quantize(v, s[:, :, None, None]))
-                        for c, v, s in zip(cache["v"], vs, vsc)),
-                    "k_scale": tuple(
-                        c.at[slots].set(s)
-                        for c, s in zip(cache["k_scale"], ksc)),
-                    "v_scale": tuple(
-                        c.at[slots].set(s)
-                        for c, s in zip(cache["v_scale"], vsc)),
-                    "len": cache["len"].at[slots].set(valid_len),
-                }
-            else:
-                new_cache = {
-                    "k": tuple(c.at[slots, :, :sb, :].set(k.astype(dt))
-                               for c, k in zip(cache["k"], ks)),
-                    "v": tuple(c.at[slots, :, :sb, :].set(v.astype(dt))
-                               for c, v in zip(cache["v"], vs)),
-                    "len": cache["len"].at[slots].set(valid_len),
-                }
+            logits = self._head(NDArray(last))
+            with _scope("kv_write"):
+                dt = cache["k"][0].dtype
+                if dt == jnp.int8:
+                    # int8 cache: per-head-per-slot scales from the
+                    # prompt's amax (the bucket's pad rows contribute —
+                    # harmless overestimate); decode reuses them
+                    ksc = [_kv_scale(k, (2, 3)) for k in ks]     # (b, H)
+                    vsc = [_kv_scale(v, (2, 3)) for v in vs]
+                    new_cache = {
+                        "k": tuple(
+                            c.at[slots, :, :sb, :].set(
+                                _kv_quantize(k, s[:, :, None, None]))
+                            for c, k, s in zip(cache["k"], ks, ksc)),
+                        "v": tuple(
+                            c.at[slots, :, :sb, :].set(
+                                _kv_quantize(v, s[:, :, None, None]))
+                            for c, v, s in zip(cache["v"], vs, vsc)),
+                        "k_scale": tuple(
+                            c.at[slots].set(s)
+                            for c, s in zip(cache["k_scale"], ksc)),
+                        "v_scale": tuple(
+                            c.at[slots].set(s)
+                            for c, s in zip(cache["v_scale"], vsc)),
+                        "len": cache["len"].at[slots].set(valid_len),
+                    }
+                else:
+                    new_cache = {
+                        "k": tuple(c.at[slots, :, :sb, :].set(k.astype(dt))
+                                   for c, k in zip(cache["k"], ks)),
+                        "v": tuple(c.at[slots, :, :sb, :].set(v.astype(dt))
+                                   for c, v in zip(cache["v"], vs)),
+                        "len": cache["len"].at[slots].set(valid_len),
+                    }
             return logits._data[:, 0, :].astype(jnp.float32), new_cache
 
         def decode_raw(tokens, cache):
@@ -1357,10 +1392,14 @@ class GPTModel(HybridBlock):
         # positions below
         self._gen = (
             param_nds,
-            jax.jit(_bind(prefill_raw), donate_argnums=(8,)),
-            jax.jit(_bind(decode_raw), donate_argnums=(6,)),
-            jax.jit(_bind(verify_raw), donate_argnums=(6,)),
-            jax.jit(_bind(advance_raw), donate_argnums=(6,)),
+            jax.jit(_bind(prefill_raw, "gpt_dense_prefill"),
+                    donate_argnums=(8,)),
+            jax.jit(_bind(decode_raw, "gpt_dense_decode"),
+                    donate_argnums=(6,)),
+            jax.jit(_bind(verify_raw, "gpt_dense_verify"),
+                    donate_argnums=(6,)),
+            jax.jit(_bind(advance_raw, "gpt_dense_advance"),
+                    donate_argnums=(6,)),
         )
         return self._gen
 
@@ -1487,6 +1526,8 @@ class GPTModel(HybridBlock):
             param_nds, blocks,
             force_jnp=getattr(self, '_force_jnp_attention', False))
         k = int(k)
+        # a sampled variant is another program, and its name says so
+        tail = "_sampled" if sampled else ""
 
         if kind == "propose":
             if sampled:
@@ -1496,13 +1537,15 @@ class GPTModel(HybridBlock):
                     for _ in range(k):
                         logits, cache = self._decode_body(
                             blocks, cur, cache)
-                        cur, q, keys = _smp.sample_with_probs(
-                            keys, logits, temps, tks, tps)
+                        with _scope("sample"):
+                            cur, q, keys = _smp.sample_with_probs(
+                                keys, logits, temps, tks, tps)
                         dts.append(cur)
                         qs.append(q)
                     return (jnp.stack(dts, axis=1),
                             jnp.stack(qs, axis=1), keys, cache)
-                jitted = jax.jit(_bind(raw), donate_argnums=(10,))
+                jitted = jax.jit(_bind(raw, "gpt_dense_propose" + tail),
+                                 donate_argnums=(10,))
             else:
                 def raw(tokens, cache):
                     cur = tokens
@@ -1510,13 +1553,17 @@ class GPTModel(HybridBlock):
                     for _ in range(k):
                         logits, cache = self._decode_body(
                             blocks, cur, cache)
-                        cur = jnp.argmax(logits, axis=-1) \
-                            .astype(jnp.int32)
+                        with _scope("sample"):
+                            cur = jnp.argmax(logits, axis=-1) \
+                                .astype(jnp.int32)
                         dts.append(cur)
                     return jnp.stack(dts, axis=1), cache
-                jitted = jax.jit(_bind(raw), donate_argnums=(6,))
+                jitted = jax.jit(_bind(raw, "gpt_dense_propose"),
+                                 donate_argnums=(6,))
         elif kind in ("verify_commit", "verify_commit_paged"):
             paged = kind == "verify_commit_paged"
+            name = ("gpt_paged" if paged else "gpt_dense") \
+                + "_verify_commit" + tail
 
             def _verify(vt, active, cache):
                 if paged:
@@ -1530,27 +1577,32 @@ class GPTModel(HybridBlock):
                     vt = jnp.concatenate([last[:, None], d_toks],
                                          axis=1)
                     logits, cache = _verify(vt, active, cache)
-                    commit, n_commit, keys = _smp.speculative_accept(
-                        keys, logits, d_toks, q, temps, tks, tps)
+                    with _scope("sample"):
+                        commit, n_commit, keys = \
+                            _smp.speculative_accept(
+                                keys, logits, d_toks, q, temps, tks, tps)
                     new = dict(cache)
                     new["len"] = cache["len"] \
                         + n_commit * (active > 0)
                     return commit, n_commit, keys, new
-                jitted = jax.jit(_bind(raw), donate_argnums=(13,))
+                jitted = jax.jit(_bind(raw, name), donate_argnums=(13,))
             else:
                 def raw(last, d_toks, active, cache):
                     vt = jnp.concatenate([last[:, None], d_toks],
                                          axis=1)
                     logits, cache = _verify(vt, active, cache)
-                    commit, n_commit = _smp.greedy_accept(logits,
-                                                          d_toks)
+                    with _scope("sample"):
+                        commit, n_commit = _smp.greedy_accept(logits,
+                                                              d_toks)
                     new = dict(cache)
                     new["len"] = cache["len"] \
                         + n_commit * (active > 0)
                     return commit, n_commit, new
-                jitted = jax.jit(_bind(raw), donate_argnums=(8,))
+                jitted = jax.jit(_bind(raw, name), donate_argnums=(8,))
         elif kind in ("decode_multi", "decode_multi_paged"):
             paged = kind == "decode_multi_paged"
+            name = ("gpt_paged" if paged else "gpt_dense") \
+                + "_decode_multi"
 
             def raw(tokens, keys, temps, tks, tps, eos_ids, budgets,
                     cache):
@@ -1581,13 +1633,14 @@ class GPTModel(HybridBlock):
                     # semantics match the k=1 engine exactly — keys
                     # advance per step iff ANY batch row samples
                     # (greedy rows' keys are never consumed).
-                    tok, ks_ = lax.cond(
-                        jnp.any(temps > 0.0),
-                        lambda ks: _smp.sample_tokens(ks, logits,
-                                                      temps, tks, tps),
-                        lambda ks: (jnp.argmax(logits, axis=-1)
-                                    .astype(jnp.int32), ks),
-                        ks_)
+                    with _scope("sample"):
+                        tok, ks_ = lax.cond(
+                            jnp.any(temps > 0.0),
+                            lambda ks: _smp.sample_tokens(
+                                ks, logits, temps, tks, tps),
+                            lambda ks: (jnp.argmax(logits, axis=-1)
+                                        .astype(jnp.int32), ks),
+                            ks_)
                     # a dead row re-feeds its last token: its logits
                     # are garbage and its pick must not leak out
                     tok = jnp.where(live, tok, cur)
@@ -1603,7 +1656,7 @@ class GPTModel(HybridBlock):
                 # per-slot (B, k) blocks
                 return (jnp.transpose(toks), jnp.transpose(emits),
                         keys, cache)
-            jitted = jax.jit(_bind(raw), donate_argnums=(12,))
+            jitted = jax.jit(_bind(raw, name), donate_argnums=(12,))
         else:
             raise ValueError(f"unknown speculative closure {kind!r}")
         entry = (param_nds, jitted)
@@ -1805,41 +1858,42 @@ class GPTModel(HybridBlock):
                 vs.append(v)
             idx = jnp.clip(n_valid - 1, 0, w - 1)
             last = x._data[0, idx][None, None, :]
-            logits = self.lm_head(self.ln_f(NDArray(last)))
-            dt = cache["k"][0].dtype
-            page_ids = pages[:w // ps]          # start == 0: static
-            if dt == jnp.int8:
-                kpgs = [_to_pages(k, ps, jnp.float32) for k in ks]
-                vpgs = [_to_pages(v, ps, jnp.float32) for v in vs]
-                kscs = [_kv_scale(p, (2, 3)) for p in kpgs]
-                vscs = [_kv_scale(p, (2, 3)) for p in vpgs]
-                new_cache = {
-                    "k": tuple(
-                        p.at[page_ids].set(
-                            _kv_quantize(pg, s[:, :, None, None]))
-                        for p, pg, s in zip(cache["k"], kpgs, kscs)),
-                    "v": tuple(
-                        p.at[page_ids].set(
-                            _kv_quantize(pg, s[:, :, None, None]))
-                        for p, pg, s in zip(cache["v"], vpgs, vscs)),
-                    "k_scale": tuple(
-                        p.at[page_ids].set(s)
-                        for p, s in zip(cache["k_scale"], kscs)),
-                    "v_scale": tuple(
-                        p.at[page_ids].set(s)
-                        for p, s in zip(cache["v_scale"], vscs)),
-                    "table": cache["table"].at[slot].set(pages),
-                    "len": cache["len"].at[slot].set(n_valid),
-                }
-            else:
-                new_cache = {
-                    "k": tuple(p.at[page_ids].set(_to_pages(k, ps, dt))
-                               for p, k in zip(cache["k"], ks)),
-                    "v": tuple(p.at[page_ids].set(_to_pages(v, ps, dt))
-                               for p, v in zip(cache["v"], vs)),
-                    "table": cache["table"].at[slot].set(pages),
-                    "len": cache["len"].at[slot].set(n_valid),
-                }
+            logits = self._head(NDArray(last))
+            with _scope("kv_write"):
+                dt = cache["k"][0].dtype
+                page_ids = pages[:w // ps]          # start == 0: static
+                if dt == jnp.int8:
+                    kpgs = [_to_pages(k, ps, jnp.float32) for k in ks]
+                    vpgs = [_to_pages(v, ps, jnp.float32) for v in vs]
+                    kscs = [_kv_scale(p, (2, 3)) for p in kpgs]
+                    vscs = [_kv_scale(p, (2, 3)) for p in vpgs]
+                    new_cache = {
+                        "k": tuple(
+                            p.at[page_ids].set(
+                                _kv_quantize(pg, s[:, :, None, None]))
+                            for p, pg, s in zip(cache["k"], kpgs, kscs)),
+                        "v": tuple(
+                            p.at[page_ids].set(
+                                _kv_quantize(pg, s[:, :, None, None]))
+                            for p, pg, s in zip(cache["v"], vpgs, vscs)),
+                        "k_scale": tuple(
+                            p.at[page_ids].set(s)
+                            for p, s in zip(cache["k_scale"], kscs)),
+                        "v_scale": tuple(
+                            p.at[page_ids].set(s)
+                            for p, s in zip(cache["v_scale"], vscs)),
+                        "table": cache["table"].at[slot].set(pages),
+                        "len": cache["len"].at[slot].set(n_valid),
+                    }
+                else:
+                    new_cache = {
+                        "k": tuple(p.at[page_ids].set(_to_pages(k, ps, dt))
+                                   for p, k in zip(cache["k"], ks)),
+                        "v": tuple(p.at[page_ids].set(_to_pages(v, ps, dt))
+                                   for p, v in zip(cache["v"], vs)),
+                        "table": cache["table"].at[slot].set(pages),
+                        "len": cache["len"].at[slot].set(n_valid),
+                    }
             return logits._data[:, 0, :].astype(jnp.float32), new_cache
 
         def chunk_raw(tokens, start, n_valid, slot, pages, cache):
@@ -1870,7 +1924,7 @@ class GPTModel(HybridBlock):
                 vscs.append(vsp)
             idx = jnp.clip(n_valid - 1, 0, c - 1)
             last = x._data[0, idx][None, None, :]
-            logits = self.lm_head(self.ln_f(NDArray(last)))
+            logits = self._head(NDArray(last))
             new_cache = {
                 "k": tuple(ks), "v": tuple(vs),
                 "table": cache["table"].at[slot].set(pages),
@@ -1919,7 +1973,7 @@ class GPTModel(HybridBlock):
                     x, cache["k"][li], cache["v"][li], table1, ln[None],
                     k_scale=cache["k_scale"][li] if quant_kv else None,
                     v_scale=cache["v_scale"][li] if quant_kv else None)
-            logits = self.lm_head(self.ln_f(x))
+            logits = self._head(x)
             return logits._data[0, 0, :].astype(jnp.float32)
 
         def bind_raw(slot, pages, length, cache):
@@ -1942,18 +1996,15 @@ class GPTModel(HybridBlock):
         # wrapper args: (key, params, quant, lora_tabs, lora_idx,
         # *fn_args) — fn args start at 5, hence the donated cache
         # positions below
-        self._paged = {
-            "params": param_nds,
-            "fresh": jax.jit(_bind(fresh_raw), donate_argnums=(9,)),
-            "chunk": jax.jit(_bind(chunk_raw), donate_argnums=(10,)),
-            "decode": jax.jit(_bind(decode_raw), donate_argnums=(7,)),
-            "peek": jax.jit(_bind(peek_raw)),
-            "bind": jax.jit(_bind(bind_raw), donate_argnums=(8,)),
-            "copy": jax.jit(_bind(copy_raw), donate_argnums=(7,)),
-            "verify": jax.jit(_bind(spec_verify_raw),
-                              donate_argnums=(7,)),
-            "advance": jax.jit(_bind(advance_raw), donate_argnums=(6,)),
-        }
+        self._paged = {"params": param_nds}
+        for role, fn, donate in (
+                ("fresh", fresh_raw, (9,)), ("chunk", chunk_raw, (10,)),
+                ("decode", decode_raw, (7,)), ("peek", peek_raw, ()),
+                ("bind", bind_raw, (8,)), ("copy", copy_raw, (7,)),
+                ("verify", spec_verify_raw, (7,)),
+                ("advance", advance_raw, (6,))):
+            self._paged[role] = jax.jit(_bind(fn, "gpt_paged_" + role),
+                                        donate_argnums=donate)
         return self._paged
 
     def _paged_call(self, name, adapters, batch, *args):

@@ -169,6 +169,7 @@ def flash_attention_pallas(q, k, v, causal=False, scale=None,
         seq_k_padded=skp, kv_len=kv_len, offset=kv_len - sq)
     out, lse = pl.pallas_call(
         kernel,
+        name="flash_attention_fwd",
         grid=(b * h, sqp // block_q),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
@@ -533,6 +534,7 @@ def decode_attention_pallas(q, k, v, lengths, scale=None, block_k=128,
                                block_k=block_k, nkb=nkb, quant=quant)
     return pl.pallas_call(
         kernel,
+        name="decode_attention",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, sq, d), q.dtype),
         interpret=interpret,
@@ -634,6 +636,7 @@ def paged_decode_attention_pallas(q, k_pool, v_pool, table, lengths,
                                block_k=ps, nkb=p_max, quant=quant)
     return pl.pallas_call(
         kernel,
+        name="paged_decode_attention",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, sq, d), q.dtype),
         interpret=interpret,

@@ -167,6 +167,7 @@ def dequant_matmul_pallas(x, wq, scales, block_n=_BLOCK_N,
     bn = min(int(block_n), n)
     out = pl.pallas_call(
         _dequant_matmul_kernel,
+        name="dequant_matmul",
         grid=(pl.cdiv(n, bn),),
         in_specs=[
             pl.BlockSpec((b, k), lambda j: (0, 0)),

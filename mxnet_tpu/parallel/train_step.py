@@ -31,6 +31,7 @@ from .. import bucketing as _bucketing
 from .. import compile_cache
 from .. import engine
 from .. import telemetry
+from .. import tracing
 from ..ndarray.ndarray import NDArray
 from ..random_state import next_key, trace_rng
 from ..gluon import _deferred
@@ -700,6 +701,10 @@ class TrainStep:
         ``n_steps`` sequence) marks trailing padded rows per step;
         their loss contribution is masked out. Returns the per-step
         losses as an NDArray of shape ``(n_steps,)``."""
+        with tracing.phase("train.chain"):
+            return self._run_chain(data, label, pad)
+
+    def _run_chain(self, data, label, pad):
         data_t, label_t = _as_tuple(data), _as_tuple(label)
         data_leaves, data_spec = _flatten_arrays(data_t)
         label_leaves, label_spec = _flatten_arrays(label_t)
@@ -795,6 +800,37 @@ class TrainStep:
         pad marks left on the arrays by the data pipeline apply, and
         an active bucketing policy pads odd batches here so they reuse
         an existing compiled entry."""
+        # the phases of one call, in the profiler's trace while a
+        # session is live (docs/OBSERVABILITY.md); step_num is the
+        # update this call makes
+        with tracing.phase("train.step",
+                           step_num=self.optimizer.num_update + 1):
+            with tracing.phase("train.prepare"):
+                entry, args, pad = self._prepare(data, label, pad)
+            # dispatch is async and entry["jit"] is lazily compiled: its
+            # FIRST dispatch (even when the entry was built by an earlier
+            # run_chain) pays trace + XLA compile — unless warmup() AOT-
+            # compiled the entry, in which case dispatch goes through the
+            # precompiled executable; steady-state 'run' measures enqueue
+            # latency (the host-side cost the reference's engine-push
+            # timing captured)
+            first_dispatch = not entry.get("jit_dispatched")
+            t0 = telemetry.clock()
+            with tracing.phase("train.enqueue"):
+                out, first_dispatch = self._enqueue(entry, args,
+                                                    first_dispatch)
+            with tracing.phase("train.writeback"):
+                entry["jit_dispatched"] = True
+                telemetry.duration_since(
+                    "parallel.train_step.compile" if first_dispatch else
+                    "parallel.train_step.run", t0)
+                return self._writeback(entry, out, pad)
+
+    def _prepare(self, data, label, pad):
+        """Everything of a call before the program is handed its
+        arguments: flatten and bucket the batch, find the entry, count
+        the update, build the hypers, place the batch. Returns
+        ``(entry, args, pad)``."""
         data_leaves, data_spec = _flatten_arrays(_as_tuple(data))
         label_leaves, label_spec = _flatten_arrays(_as_tuple(label))
         data_leaves, label_leaves, pad = self._apply_bucketing(
@@ -825,15 +861,12 @@ class TrainStep:
                 tuple(nd._data for nd in entry["frozen_nds"]),
                 tuple(self._opt_states), hypers,
                 tuple(data_datas), tuple(label_datas), n_valid)
-        # dispatch is async and entry["jit"] is lazily compiled: its
-        # FIRST dispatch (even when the entry was built by an earlier
-        # run_chain) pays trace + XLA compile — unless warmup() AOT-
-        # compiled the entry, in which case dispatch goes through the
-        # precompiled executable; steady-state 'run' measures enqueue
-        # latency (the host-side cost the reference's engine-push
-        # timing captured)
-        first_dispatch = not entry.get("jit_dispatched")
-        t0 = telemetry.clock()
+        return entry, args, pad
+
+    @staticmethod
+    def _enqueue(entry, args, first_dispatch):
+        """The program call and nothing else. Returns its outputs and
+        whether the call paid a trace and compile."""
         out = None
         if entry.get("aot") is not None:
             try:
@@ -851,11 +884,12 @@ class TrainStep:
             with compile_cache.measure() if first_dispatch \
                     else _nullcontext():
                 out = entry["jit"](*args)
+        return out, first_dispatch
+
+    def _writeback(self, entry, out, pad):
+        """Rebind weights and optimizer state to the program's outputs,
+        install the aux updates; returns the loss."""
         new_ws, new_ss, loss, aux = out
-        entry["jit_dispatched"] = True
-        telemetry.duration_since(
-            "parallel.train_step.compile" if first_dispatch else
-            "parallel.train_step.run", t0)
         if self.comm_bytes_per_step and telemetry.enabled():
             telemetry.counter("parallel.train_step.comm_bytes",
                               self.comm_bytes_per_step)

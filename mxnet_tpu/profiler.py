@@ -5,8 +5,8 @@ the equivalent timeline comes from the XLA/PJRT profiler (Xprof): we
 wrap jax.profiler — traces are written as TensorBoard/Xprof protobufs
 AND a chrome-trace .json.gz (viewable at chrome://tracing or Perfetto),
 which covers the reference's `profile_all` surface. Python-side scopes
-map to jax.profiler.TraceAnnotation so custom Task/Frame markers land
-in the same timeline.
+go through tracing.phase, so custom Task/Frame markers land in the
+same timeline as the program's own phases (docs/OBSERVABILITY.md).
 """
 from __future__ import annotations
 
@@ -15,7 +15,7 @@ import threading
 
 import jax
 
-from . import telemetry
+from . import telemetry, tracing
 
 _config = {
     "filename": "profile.json",
@@ -101,7 +101,6 @@ def dumps(reset=False, format="table", sort_by="total", ascending=False,
     out = telemetry.render(format=format, sort_by=sort_by,
                            ascending=ascending, trace_dir=_state["dir"],
                            reset_after=reset)
-    from . import tracing
     traces = tracing.recent_traces()
     if not traces:
         return out
@@ -145,7 +144,7 @@ class Task:
         self._ann = None
 
     def start(self):
-        self._ann = jax.profiler.TraceAnnotation(self.name)
+        self._ann = tracing.phase(self.name)
         self._ann.__enter__()
 
     def stop(self):
@@ -276,7 +275,7 @@ class Marker:
         self.name = name
 
     def mark(self, scope="process"):
-        with jax.profiler.TraceAnnotation(
+        with tracing.phase(
                 f"{getattr(self.domain, 'name', 'domain')}:"
                 f"{self.name}@{scope}"):
             pass
@@ -292,7 +291,7 @@ def scope(name="<unk>:", append_mode=True):
         name = prev + name
     _scope_tls.scope = name
     try:
-        with jax.profiler.TraceAnnotation(name):
+        with tracing.phase(name):
             yield
     finally:
         _scope_tls.scope = prev

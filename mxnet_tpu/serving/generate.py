@@ -403,8 +403,9 @@ class _GenWorker(BoundedQueueWorker):
             # be tracing the jitted closures concurrently, and tracing
             # (parameter rebinding in the _bind wrapper) is not
             # thread-safe against itself
-            with eng._gen_lock:
-                eng._admit(self._queue)
+            with eng._gen_lock, tracing.phase("serve.iter"):
+                with tracing.phase("serve.admit"):
+                    eng._admit(self._queue)
                 active = eng._n_active
                 if active:
                     eng._step()
@@ -419,7 +420,8 @@ class _GenWorker(BoundedQueueWorker):
                 continue
             del eng  # don't pin the engine while blocking on the queue
             try:
-                r = self._queue.get(timeout=0.05)
+                with tracing.phase("serve.idle"):
+                    r = self._queue.get(timeout=0.05)
             except queue.Empty:
                 if self._draining:
                     return
@@ -429,7 +431,8 @@ class _GenWorker(BoundedQueueWorker):
                 r.stream._finish(exc=EngineClosedError(
                     "engine was garbage-collected"))
                 return
-            with eng._gen_lock:
+            with eng._gen_lock, tracing.phase("serve.iter"), \
+                    tracing.phase("serve.admit"):
                 eng._admit_one(r)
 
     def _drained(self, item):
@@ -1254,16 +1257,20 @@ class GenerationEngine:
 
             from ..ops import sampling as _smp
 
-            def counted(fn):
+            def counted(fn, name):
                 def wrapper(*args):
                     telemetry.counter("ops.sampling.trace")
                     tracing.flight.record("compile",
                                           what="ops.sampling")
                     return fn(*args)
+                # the program's name in the profiler's trace
+                # (jit_<name>; docs/OBSERVABILITY.md)
+                wrapper.__name__ = wrapper.__qualname__ = name
                 return wrapper
 
             self._samplers = {
-                "sample": jax.jit(counted(_smp.sample_tokens)),
+                "sample": jax.jit(counted(_smp.sample_tokens,
+                                          "sampling_sample")),
             }
         return self._samplers
 
@@ -1902,25 +1909,29 @@ class GenerationEngine:
         self._arm_sampling(slot, r)
         pt0 = time.perf_counter() if tr is not None else 0.0
         t0 = telemetry.clock()
-        logits, self._cache = self.model.prefill(
-            padded, onp.asarray([n], "i4"), self._cache,
-            slots=onp.asarray([slot], "i4"),
-            **self._akw(self._adapter_idx[slot:slot + 1]))
-        if self._part is not None:
-            self._cache = self._recommit(self._cache)
-        if self.speculative:
-            # the draft mirrors the target's committed prefix from the
-            # moment the slot exists — its own (dense) prefill of the
-            # same padded prompt into the same slot row
-            _, self._draft_cache = self.draft.prefill(
-                padded, onp.asarray([n], "i4"), self._draft_cache,
-                slots=onp.asarray([slot], "i4"))
-            self._draft_cache = self._recommit_draft(self._draft_cache)
+        with tracing.phase("serve.prefill.dispatch", slot=slot, start=0,
+                           tokens=n, fresh=True):
+            logits, self._cache = self.model.prefill(
+                padded, onp.asarray([n], "i4"), self._cache,
+                slots=onp.asarray([slot], "i4"),
+                **self._akw(self._adapter_idx[slot:slot + 1]))
+            if self._part is not None:
+                self._cache = self._recommit(self._cache)
+            if self.speculative:
+                # the draft mirrors the target's committed prefix from
+                # the moment the slot exists — its own (dense) prefill
+                # of the same padded prompt into the same slot row
+                _, self._draft_cache = self.draft.prefill(
+                    padded, onp.asarray([n], "i4"), self._draft_cache,
+                    slots=onp.asarray([slot], "i4"))
+                self._draft_cache = self._recommit_draft(
+                    self._draft_cache)
         telemetry.hist_since("serving.generate.prefill", t0)
         telemetry.counter("serving.generate.prefills")
         if tr is not None:
             tr.add("prefill", pt0, slot=slot, tokens=n)
-        tok = self._pick_first(slot, onp.asarray(logits)[0])
+        with tracing.phase("serve.prefill.sync"):
+            tok = self._pick_first(slot, onp.asarray(logits)[0])
         s = _Slot(r.stream, tok, r.max_new - 1, r.eos_id, r.deadline,
                   n_ctx=n)
         self._slots[slot] = s
@@ -2092,7 +2103,8 @@ class GenerationEngine:
                 tr.add("prefill", pt0, slot=slot, tokens=length,
                        peek=True)
             self._register_prefix(s)
-            self._first_token(slot, s, onp.asarray(logits))
+            with tracing.phase("serve.prefill.sync"):
+                self._first_token(slot, s, onp.asarray(logits))
             return True
         row[first_write:cap_pages] = private
         start0 = first_write * ps
@@ -2207,11 +2219,13 @@ class GenerationEngine:
         tr = s.stream._trace
         pt0 = time.perf_counter() if tr is not None else 0.0
         t0 = telemetry.clock()
-        logits, self._cache = self.model.prefill_paged(
-            toks, n_valid, best, s.row, self._cache, start=start,
-            fresh=fresh,
-            **self._akw(self._adapter_idx[best:best + 1]))
-        self._cache = self._recommit(self._cache)
+        with tracing.phase("serve.prefill.dispatch", slot=best,
+                           start=start, tokens=n_valid, fresh=fresh):
+            logits, self._cache = self.model.prefill_paged(
+                toks, n_valid, best, s.row, self._cache, start=start,
+                fresh=fresh,
+                **self._akw(self._adapter_idx[best:best + 1]))
+            self._cache = self._recommit(self._cache)
         telemetry.hist_since("serving.generate.prefill", t0)
         telemetry.counter("serving.generate.prefill_chunks")
         if tr is not None:
@@ -2221,7 +2235,8 @@ class GenerationEngine:
         if not s.chunks:
             telemetry.counter("serving.generate.prefills")
             self._register_prefix(s)
-            self._first_token(best, s, onp.asarray(logits))
+            with tracing.phase("serve.prefill.sync"):
+                self._first_token(best, s, onp.asarray(logits))
         return 1
 
     def _cow_sweep(self):
@@ -2235,11 +2250,14 @@ class GenerationEngine:
                 src, dst, logical = s.cow_pending
                 tr = s.stream._trace
                 pt0 = time.perf_counter() if tr is not None else 0.0
-                self._cache = self._recommit(self.model.copy_page_paged(
-                    src, dst, self._cache))
-                s.row[logical] = dst
-                self._cache = self._recommit(self.model.bind_slot_paged(
-                    i, s.row, s.n_ctx, self._cache))
+                with tracing.phase("serve.cow", slot=i):
+                    self._cache = self._recommit(
+                        self.model.copy_page_paged(src, dst,
+                                                   self._cache))
+                    s.row[logical] = dst
+                    self._cache = self._recommit(
+                        self.model.bind_slot_paged(i, s.row, s.n_ctx,
+                                                   self._cache))
                 self._pool.release(src)
                 s.page_refs.remove(src)
                 s.cow_pending = None
@@ -2305,31 +2323,34 @@ class GenerationEngine:
         the counters alone would not say so), then deadline (checked
         once per BLOCK — a multi-token tick times out at block
         granularity). Returns the number of tokens emitted."""
-        now = time.monotonic()
-        n_emitted = 0
-        for i in idxs:
-            s = self._slots[i]
-            out = outs[i]
-            span_cb(i, s, out)
-            s.stream._emit_many(out)
-            n_emitted += len(out)
-            if not out:   # can only mean an exhausted slot the evict
-                self._evict(i, "length")     # checks below would have
-                continue                     # caught last tick
-            s.last = out[-1]
-            s.left -= len(out)
-            s.n_ctx += len(out)
-            if s.eos_id is not None and out[-1] == s.eos_id:
-                self._evict(i, "eos")
-            elif s.left <= 0 or s.n_ctx >= self._s_cap \
-                    or (clipped is not None and clipped.get(i)):
-                self._evict(i, "length")
-            elif s.deadline is not None and now > s.deadline:
-                telemetry.counter("serving.generate.timeouts")
-                self._evict(i, "timeout")
-        if n_emitted:  # one delta per tick, not one call per token
-            telemetry.counter("serving.generate.tokens", n_emitted)
-        telemetry.gauge("serving.generate.slots", self._n_active)
+        with tracing.phase("serve.commit"):
+            now = time.monotonic()
+            n_emitted = 0
+            for i in idxs:
+                s = self._slots[i]
+                out = outs[i]
+                span_cb(i, s, out)
+                s.stream._emit_many(out)
+                n_emitted += len(out)
+                if not out:
+                    # can only mean an exhausted slot the evict checks
+                    # below would have caught last tick
+                    self._evict(i, "length")
+                    continue
+                s.last = out[-1]
+                s.left -= len(out)
+                s.n_ctx += len(out)
+                if s.eos_id is not None and out[-1] == s.eos_id:
+                    self._evict(i, "eos")
+                elif s.left <= 0 or s.n_ctx >= self._s_cap \
+                        or (clipped is not None and clipped.get(i)):
+                    self._evict(i, "length")
+                elif s.deadline is not None and now > s.deadline:
+                    telemetry.counter("serving.generate.timeouts")
+                    self._evict(i, "timeout")
+            if n_emitted:  # one delta per tick, not one call per token
+                telemetry.counter("serving.generate.tokens", n_emitted)
+            telemetry.gauge("serving.generate.slots", self._n_active)
         return n_emitted
 
     def _decode_tick(self):
@@ -2348,30 +2369,35 @@ class GenerationEngine:
         if self.decode_ticks > 1:
             self._decode_tick_multi(idxs)
             return
-        toks = onp.zeros((self.max_slots,), "i4")
-        active = onp.zeros((self.max_slots,), "i4")
-        any_trace = False
-        for i in idxs:
-            s = self._slots[i]
-            toks[i] = s.last
-            active[i] = 1
-            if s.stream._trace is not None:
-                any_trace = True
-        tt0 = time.perf_counter() if any_trace else 0.0
-        t0 = telemetry.clock()
-        if self.paged:
-            logits, self._cache = self.model.decode_step_paged(
-                toks, active, self._cache,
-                **self._akw(self._adapter_idx))
-            self._cache = self._recommit(self._cache)
-        else:
-            logits, self._cache = self.model.decode_step(
-                toks, self._cache, **self._akw(self._adapter_idx))
-            if self._part is not None:
+        with tracing.phase("serve.decode.dispatch"):
+            toks = onp.zeros((self.max_slots,), "i4")
+            active = onp.zeros((self.max_slots,), "i4")
+            any_trace = False
+            for i in idxs:
+                s = self._slots[i]
+                toks[i] = s.last
+                active[i] = 1
+                if s.stream._trace is not None:
+                    any_trace = True
+            tt0 = time.perf_counter() if any_trace else 0.0
+            t0 = telemetry.clock()
+            if self.paged:
+                logits, self._cache = self.model.decode_step_paged(
+                    toks, active, self._cache,
+                    **self._akw(self._adapter_idx))
                 self._cache = self._recommit(self._cache)
-        self._emit_collectives()
+            else:
+                logits, self._cache = self.model.decode_step(
+                    toks, self._cache, **self._akw(self._adapter_idx))
+                if self._part is not None:
+                    self._cache = self._recommit(self._cache)
+            self._emit_collectives()
+        with tracing.phase("serve.decode.sync"):
+            step_toks = self._pick_step_tokens(logits)
+        # closed AFTER the tick's host sync, as the multi-tick and
+        # speculative ticks close it: the tick as a caller feels it
+        # (before it, on an asynchronous device, it timed the enqueue)
         telemetry.hist_since("serving.generate.decode", t0)
-        step_toks = self._pick_step_tokens(logits)
         self._tick_counters(1, 1)
         outs = {i: [int(step_toks[i])] for i in idxs}
 
@@ -2396,34 +2422,37 @@ class GenerationEngine:
         steady-state traffic compiles nothing."""
         k = self.decode_ticks
         b = self.max_slots
-        toks = onp.zeros((b,), "i4")
-        budgets = onp.zeros((b,), "i4")
-        eos_ids = onp.full((b,), -1, "i4")
-        any_trace = False
-        for i in idxs:
-            s = self._slots[i]
-            toks[i] = s.last
-            budgets[i] = min(k, s.left, self._s_cap - s.n_ctx)
-            if s.eos_id is not None:
-                eos_ids[i] = s.eos_id
-            if s.stream._trace is not None:
-                any_trace = True
-        tt0 = time.perf_counter() if any_trace else 0.0
-        t0 = telemetry.clock()
-        fn = self.model.decode_multi_paged if self.paged \
-            else self.model.decode_multi
-        tok_blk, emit_blk, keys, self._cache = fn(
-            toks, budgets, self._cache, k, self._keys, self._temps,
-            self._topks, self._topps, eos_ids,
-            **self._akw(self._adapter_idx))
-        if self.paged or self._part is not None:
-            self._cache = self._recommit(self._cache)
-        self._emit_collectives()
-        tok_h = onp.asarray(tok_blk)   # the (B, k) block's ONE sync
-        emit_h = onp.asarray(emit_blk)
-        # onp.array, not asarray: a jax array converts to a READ-ONLY
-        # numpy view, and _arm_sampling assigns into this buffer
-        self._keys = onp.array(keys, dtype="u4")
+        with tracing.phase("serve.decode.dispatch"):
+            toks = onp.zeros((b,), "i4")
+            budgets = onp.zeros((b,), "i4")
+            eos_ids = onp.full((b,), -1, "i4")
+            any_trace = False
+            for i in idxs:
+                s = self._slots[i]
+                toks[i] = s.last
+                budgets[i] = min(k, s.left, self._s_cap - s.n_ctx)
+                if s.eos_id is not None:
+                    eos_ids[i] = s.eos_id
+                if s.stream._trace is not None:
+                    any_trace = True
+            tt0 = time.perf_counter() if any_trace else 0.0
+            t0 = telemetry.clock()
+            fn = self.model.decode_multi_paged if self.paged \
+                else self.model.decode_multi
+            tok_blk, emit_blk, keys, self._cache = fn(
+                toks, budgets, self._cache, k, self._keys, self._temps,
+                self._topks, self._topps, eos_ids,
+                **self._akw(self._adapter_idx))
+            if self.paged or self._part is not None:
+                self._cache = self._recommit(self._cache)
+            self._emit_collectives()
+        with tracing.phase("serve.decode.sync"):
+            tok_h = onp.asarray(tok_blk)  # the (B, k) block's ONE sync
+            emit_h = onp.asarray(emit_blk)
+            # onp.array, not asarray: a jax array converts to a
+            # READ-ONLY numpy view, and _arm_sampling assigns into
+            # this buffer
+            self._keys = onp.array(keys, dtype="u4")
         telemetry.hist_since("serving.generate.decode", t0)
         self._tick_counters(1, k)
         outs = {i: [int(t) for t in tok_h[i, :int(emit_h[i].sum())]]
@@ -2520,51 +2549,53 @@ class GenerationEngine:
             return
         k = self.spec_k
         b = self.max_slots
-        toks = onp.zeros((b,), "i4")
-        active = onp.zeros((b,), "i4")
-        any_trace = False
-        for i in idxs:
-            toks[i] = self._slots[i].last
-            active[i] = 1
-            if self._slots[i].stream._trace is not None:
-                any_trace = True
-        tt0 = time.perf_counter() if any_trace else 0.0
-        sampled = bool(self._n_sampling)
-        t0 = telemetry.clock()
-        # three dispatches + one host sync per iteration: the fused
-        # k-step draft propose, the fused verify+accept+advance, and
-        # the draft rollback — at serving model sizes the per-call
-        # dispatch overhead dominates, so the k draft steps, the k+1
-        # verify, the accept rule and the len bump each run INSIDE
-        # one program instead of as ~3k separate calls
-        if sampled:
-            dt, q, keys, self._draft_cache = self.draft.propose_tokens(
-                toks, self._draft_cache, k, keys=self._keys,
-                temps=self._temps, top_ks=self._topks,
-                top_ps=self._topps)
-            self._draft_cache = self._recommit_draft(self._draft_cache)
-            commit, n_commit, keys, self._cache = (
-                self.model.verify_commit_paged if self.paged
-                else self.model.verify_commit)(
-                toks, dt, active, self._cache, q=q, keys=keys,
-                temps=self._temps, top_ks=self._topks,
-                top_ps=self._topps,
-                **self._akw(self._adapter_idx))
-        else:
-            dt, self._draft_cache = self.draft.propose_tokens(
-                toks, self._draft_cache, k)
-            self._draft_cache = self._recommit_draft(self._draft_cache)
-            commit, n_commit, self._cache = (
-                self.model.verify_commit_paged if self.paged
-                else self.model.verify_commit)(
-                toks, dt, active, self._cache,
-                **self._akw(self._adapter_idx))
-        self._cache = self._recommit(self._cache)
-        self._emit_collectives()
-        commit_h = onp.asarray(commit)    # the tick's one host sync
-        n_h = onp.asarray(n_commit)
-        if sampled:
-            self._keys = onp.array(keys, dtype="u4")  # writable copy
+        with tracing.phase("serve.decode.dispatch"):
+            toks = onp.zeros((b,), "i4")
+            active = onp.zeros((b,), "i4")
+            any_trace = False
+            for i in idxs:
+                toks[i] = self._slots[i].last
+                active[i] = 1
+                if self._slots[i].stream._trace is not None:
+                    any_trace = True
+            tt0 = time.perf_counter() if any_trace else 0.0
+            sampled = bool(self._n_sampling)
+            t0 = telemetry.clock()
+            # three dispatches + one host sync per iteration: the fused
+            # k-step draft propose, the fused verify+accept+advance, and
+            # the draft rollback — at serving model sizes the per-call
+            # dispatch overhead dominates, so the k draft steps, the k+1
+            # verify, the accept rule and the len bump each run INSIDE
+            # one program instead of as ~3k separate calls
+            if sampled:
+                dt, q, keys, self._draft_cache = self.draft.propose_tokens(
+                    toks, self._draft_cache, k, keys=self._keys,
+                    temps=self._temps, top_ks=self._topks,
+                    top_ps=self._topps)
+                self._draft_cache = self._recommit_draft(self._draft_cache)
+                commit, n_commit, keys, self._cache = (
+                    self.model.verify_commit_paged if self.paged
+                    else self.model.verify_commit)(
+                    toks, dt, active, self._cache, q=q, keys=keys,
+                    temps=self._temps, top_ks=self._topks,
+                    top_ps=self._topps,
+                    **self._akw(self._adapter_idx))
+            else:
+                dt, self._draft_cache = self.draft.propose_tokens(
+                    toks, self._draft_cache, k)
+                self._draft_cache = self._recommit_draft(self._draft_cache)
+                commit, n_commit, self._cache = (
+                    self.model.verify_commit_paged if self.paged
+                    else self.model.verify_commit)(
+                    toks, dt, active, self._cache,
+                    **self._akw(self._adapter_idx))
+            self._cache = self._recommit(self._cache)
+            self._emit_collectives()
+        with tracing.phase("serve.decode.sync"):
+            commit_h = onp.asarray(commit)  # the tick's one host sync
+            n_h = onp.asarray(n_commit)
+            if sampled:
+                self._keys = onp.array(keys, dtype="u4")  # writable
         telemetry.hist_since("serving.generate.decode", t0)
         # commit bookkeeping: eos cuts the emission at the stop token,
         # budget/capacity clip it. A clipped slot is EVICTED, so the

@@ -1,8 +1,16 @@
-"""Per-request tracing and the serving-stack flight recorder.
+"""Per-request tracing, the serving-stack flight recorder, and the
+program's phases in the profiler's trace.
 
-Two observability primitives the aggregate telemetry registry
+Three observability primitives the aggregate telemetry registry
 (telemetry.py) cannot provide:
 
+- **Phases** — :func:`phase` is the one span call of the hot paths
+  (the engine worker's iteration, ``TrainStep.__call__``): it writes
+  into the ``jax.profiler`` trace, on the clock of the device
+  operations, whenever a profiler session is live
+  (``profiler.start()``, ``jax.profiler.start_trace``) and costs a
+  constructor and a flag check when none is. The names are listed in
+  docs/OBSERVABILITY.md; the benchmark's span metrics read them.
 - **Per-request traces** — a :class:`Trace` is minted at
   ``GenerationEngine.submit`` / ``Router.submit`` and threaded through
   every lifecycle edge (queue wait, admission, prefill chunks, decode /
@@ -47,12 +55,14 @@ import os
 import threading
 import time
 
+import jax
+
 from . import telemetry
 
 __all__ = [
     "enabled", "set_enabled", "new_trace_id", "Span", "Trace",
     "start_trace", "FlightRecorder", "flight", "recent_traces",
-    "clear_recent", "spans_allocated",
+    "clear_recent", "spans_allocated", "phase",
 ]
 
 _enabled = os.environ.get("MXTPU_TRACING", "0").lower() \
@@ -69,6 +79,19 @@ _allocs = 0
 _RUN = os.urandom(4).hex()
 _mint = itertools.count(1)
 _DEFAULT_MAX_SPANS = 1024
+
+
+def phase(name, **attrs):
+    """Context manager: one span ``name`` in the profiler's trace, on
+    the calling thread's line, covering the enclosed code. ``attrs``
+    travel as the event's statistics, so its *name* stays bare and a
+    reader can match it exactly. ``step_num=`` makes it a step marker
+    (``StepTraceAnnotation``: the profiler groups device work by it).
+    No session live: nothing is recorded. The only place in the
+    package that touches ``jax.profiler.TraceAnnotation``."""
+    if "step_num" in attrs:
+        return jax.profiler.StepTraceAnnotation(name, **attrs)
+    return jax.profiler.TraceAnnotation(name, **attrs)
 
 
 def enabled() -> bool:

@@ -118,3 +118,63 @@ def test_dequant_matmul(one_chip, no_compile_cache, n, k):
     multiple of the block: the last grid step overhangs)."""
     _compile(qz.dequant_matmul_pallas, one_chip, ((B, k), jnp.float32),
              ((n, k), jnp.int8), ((n,), jnp.float32))
+
+
+def _named_kernels():
+    """name -> (function, shapes): each kernel of the main path at its
+    bf16 (dequant: int8) shapes, called under a scope as the model
+    calls it."""
+    qkv = ((2, H, S_MAX, D), jnp.bfloat16)
+    q1 = ((B, H, 1, D), jnp.bfloat16)
+    kv = ((B, H, S_MAX, D), jnp.bfloat16)
+    pool = ((N_PAGES, H, PAGE, D), jnp.bfloat16)
+    return {
+        "flash_attention_fwd": (
+            lambda q, k, v: att.flash_attention_pallas(q, k, v,
+                                                       causal=True),
+            (qkv, qkv, qkv)),
+        "decode_attention": (
+            att.decode_attention_pallas,
+            (q1, kv, kv, ((B,), jnp.int32))),
+        "paged_decode_attention": (
+            att.paged_decode_attention_pallas,
+            (q1, pool, pool, ((B, P_MAX), jnp.int32),
+             ((B,), jnp.int32))),
+        "dequant_matmul": (
+            qz.dequant_matmul_pallas,
+            (((B, 1280), jnp.float32), ((5120, 1280), jnp.int8),
+             ((5120,), jnp.float32))),
+    }
+
+
+@pytest.mark.parametrize("name", ["flash_attention_fwd",
+                                  "decode_attention",
+                                  "paged_decode_attention",
+                                  "dequant_matmul"])
+def test_kernel_is_named_in_the_compiled_program(one_chip,
+                                                 no_compile_cache, name):
+    """What the device trace shows of a kernel is its HLO instruction:
+    the ``name=`` of the ``pallas_call`` is the instruction's name and
+    stands in ``op_name`` under the caller's scope, and
+    ``tpu_custom_call`` still lies inside the 1,200 characters of the
+    instruction that the benchmark's trace reduction keeps
+    (``chipbench/trace_reduce.TEXT_LIMIT``), where the roofline
+    patterns look for it."""
+    fn, shapes = _named_kernels()[name]
+
+    def scoped(*args):
+        with jax.named_scope("attn"):
+            return fn(*args)
+
+    text = _compile(scoped, one_chip, *shapes)
+    calls = [line.strip() for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 1, calls
+    inst = calls[0].removeprefix("ROOT ")
+    assert inst.split(" = ")[0].lstrip("%").split(".")[0] == name, \
+        inst[:120]
+    assert f"/attn/{name}/" in inst.split("op_name=")[1].split('"')[1]
+    # the trace prints each operand with its shape and layout, which
+    # this text gives once more under operand_layout_constraints
+    shapes = inst.split("operand_layout_constraints={")[1].split("}, ")[0]
+    assert inst.index("tpu_custom_call") + len(shapes) < 1200

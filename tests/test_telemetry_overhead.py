@@ -106,6 +106,24 @@ def test_tracing_disabled_engine_run_allocates_no_spans():
         eng.close()
 
 
+def test_phase_without_a_profiler_session_costs_under_5us():
+    """The hot paths call ``tracing.phase`` about ten times a decode
+    tick and four times a training step whether or not anyone is
+    profiling: with no session live it is a constructor and a flag
+    check (about 1 us here), and it allocates no ``Span``."""
+    n = 20000
+    a0 = tracing.spans_allocated()
+    with tracing.phase("warm", slot=0):
+        pass
+    t_start = time.perf_counter()
+    for i in range(n):
+        with tracing.phase("serve.decode.dispatch", slot=3, tokens=i):
+            pass
+    per_phase = (time.perf_counter() - t_start) / n
+    assert tracing.spans_allocated() == a0
+    assert per_phase < 5e-6, f"phase took {per_phase * 1e6:.2f}us"
+
+
 def test_tracing_enabled_span_overhead_under_10us():
     n = 20000
     tr = tracing.Trace(max_spans=n + 16)

@@ -464,3 +464,192 @@ def test_obs_dump_script_pretty_prints(tmp_path, capsys):
     assert mod.main([str(path)]) == 0
     out = capsys.readouterr().out
     assert "engine.fail_all" in out and "gen.admit" in out
+
+
+# -- phases in the profiler's trace (tracing.phase) ---------------------
+
+def _host_lines(trace_dir):
+    """The host threads' lines of the ``.xplane.pb`` under
+    ``trace_dir``: one ``[(name, start_ns, end_ns, stats)]`` per line,
+    as ``jax.profiler.ProfileData`` reads them."""
+    import glob
+
+    from jax.profiler import ProfileData
+    path, = glob.glob(os.path.join(str(trace_dir), "plugins", "profile",
+                                   "*", "*.xplane.pb"))
+    lines = []
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                lines.append([
+                    (ev.name, ev.start_ns, ev.start_ns + ev.duration_ns,
+                     dict(ev.stats)) for ev in line.events])
+    return lines
+
+
+def _profiled(trace_dir, fn):
+    """Run ``fn`` under a profiler session without the Python tracer
+    (the spans are TraceMe events; frames would only slow the test)."""
+    import jax
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(trace_dir), profiler_options=opts)
+    try:
+        fn()
+    finally:
+        jax.profiler.stop_trace()
+    return _host_lines(trace_dir)
+
+
+def _line_with(lines, name):
+    """The one line that holds events called ``name``."""
+    hits = [ln for ln in lines if any(e[0] == name for e in ln)]
+    assert len(hits) == 1, f"{name} on {len(hits)} lines"
+    return hits[0]
+
+
+def _inside(children, parents):
+    """Every child lies, in time, within one of the parents."""
+    return all(any(p[1] <= c[1] and c[2] <= p[2] for p in parents)
+               for c in children)
+
+
+def test_serving_phases_land_in_the_profiler_trace(net, tmp_path):
+    eng = GenerationEngine(net, max_slots=2, max_length=64, paged=True,
+                           page_size=8).warmup()
+    try:
+        def serve():
+            streams = [eng.submit(_prompt(40, 1), max_new_tokens=5),
+                       eng.submit(_prompt(9, 2), max_new_tokens=5)]
+            for s in streams:
+                s.result(timeout=60)
+        lines = _profiled(tmp_path, serve)
+    finally:
+        eng.close()
+    line = _line_with(lines, "serve.iter")
+    by_name = {}
+    for ev in line:
+        by_name.setdefault(ev[0], []).append(ev)
+    iters = by_name["serve.iter"]
+    # every phase of the pass is on the worker's line, under its bare
+    # name (attributes travel as statistics), inside a serve.iter
+    for name in ("serve.admit", "serve.prefill.dispatch",
+                 "serve.prefill.sync", "serve.decode.dispatch",
+                 "serve.decode.sync", "serve.commit"):
+        assert name in by_name, sorted(by_name)
+        assert _inside(by_name[name], iters), name
+    assert not any(ln is not line and any(
+        e[0].startswith("serve.") and e[0] != "serve.idle" for e in ln)
+        for ln in lines)
+    assert not any("#" in n or "=" in n for n in by_name
+                   if n.startswith("serve."))
+    chunk = by_name["serve.prefill.dispatch"][0][3]
+    assert {"slot", "start", "tokens", "fresh"} <= set(chunk)
+    # 40 tokens at page 8: whole chunks of the prompt, then one tick a
+    # token after the first (which the last chunk's logits give)
+    assert sum(e[3]["tokens"] for e in by_name["serve.prefill.dispatch"]) \
+        == 40 + 9
+    assert len(by_name["serve.decode.dispatch"]) \
+        == len(by_name["serve.decode.sync"]) \
+        == len(by_name["serve.commit"]) >= 4
+
+
+def test_training_phases_land_in_the_profiler_trace(tmp_path):
+    from mxnet_tpu import gluon, parallel
+    from mxnet_tpu.gluon import nn
+    rng = onp.random.RandomState(0)
+    x = mx.np.array(rng.randn(16, 8).astype("f4"))
+    y = mx.np.array(rng.randint(0, 4, size=16).astype("i4"))
+    mlp = nn.HybridSequential()
+    mlp.add(nn.Dense(16, activation="relu"), nn.Dense(4))
+    mlp.initialize(mx.init.Xavier())
+    step = parallel.TrainStep(mlp, gluon.loss.SoftmaxCrossEntropyLoss(),
+                              "adam", {"learning_rate": 0.01}, mesh=None)
+    step(x, y)                                   # compiles, untraced
+    lines = _profiled(tmp_path, lambda: [step(x, y), step(x, y)])
+    line = _line_with(lines, "train.step")
+    steps = [e for e in line if e[0] == "train.step"]
+    assert [e[3]["step_num"] for e in steps] == [2, 3]
+    for name in ("train.prepare", "train.enqueue", "train.writeback"):
+        children = [e for e in line if e[0] == name]
+        assert len(children) == 2, name
+        assert _inside(children, steps), name
+    # the children do not overlap, and come in this order
+    order = [e[0] for e in sorted(line, key=lambda e: e[1])
+             if e[0].startswith("train.") and e[0] != "train.step"]
+    assert order == ["train.prepare", "train.enqueue",
+                     "train.writeback"] * 2
+
+
+def test_every_generation_program_has_a_name_of_its_own(net):
+    """Each jitted generation closure is its own ``jit_<name>`` program
+    in a device trace: no two roles share a name, none is ``wrapper``."""
+    dense = list(net._ensure_gen()[1:])
+    paged = [f for role, f in net._ensure_paged().items()
+             if role != "params"]
+    fused = [net._ensure_spec(kind, 3, sampled)[1]
+             for kind in ("propose", "verify_commit",
+                          "verify_commit_paged")
+             for sampled in (False, True)]
+    fused += [net._ensure_spec(kind, 3, True)[1]
+              for kind in ("decode_multi", "decode_multi_paged")]
+    names = [f.__name__ for f in dense + paged + fused]
+    assert len(names) == 4 + 8 + 8
+    assert len(set(names)) == len(names), sorted(names)
+    assert all(n.startswith(("gpt_dense_", "gpt_paged_"))
+               for n in names), sorted(names)
+    assert {"gpt_paged_fresh", "gpt_paged_chunk", "gpt_paged_decode",
+            "gpt_dense_prefill", "gpt_dense_decode",
+            "gpt_paged_decode_multi"} <= set(names)
+    eng = GenerationEngine(net, max_slots=2, max_length=64)
+    try:
+        assert eng._ensure_samplers()["sample"].__name__ \
+            == "sampling_sample"
+    finally:
+        eng.close()
+
+
+class _SlowToFetch:
+    """A tick's result that takes 20 ms to reach the host, as a busy
+    device's does: ``onp.asarray`` of it is the tick's host sync."""
+
+    def __init__(self, arr):
+        self._arr = arr
+
+    def __array__(self, dtype=None, copy=None):
+        time.sleep(0.02)
+        return onp.asarray(self._arr, dtype=dtype)
+
+
+@pytest.mark.parametrize("mode,program,kw", [
+    ("plain", "decode_step", {}),
+    ("multi-tick", "decode_multi", {"decode_ticks": 3}),
+    ("speculative", "verify_commit", {"spec_k": 2}),
+], ids=["plain", "multi-tick", "speculative"])
+def test_decode_histogram_closes_after_the_host_sync(net, monkeypatch,
+                                                     mode, program, kw):
+    """``serving.generate.decode`` (the SLO tracker's time per output
+    token) is the tick as a caller feels it in every engine mode: with
+    the tick's result 20 ms late (in plain mode: ``_pick_step_tokens``
+    delayed by 20 ms) no sample is shorter. Closed before the sync, as
+    the plain tick did, it timed the enqueue."""
+    if mode == "speculative":
+        kw = dict(kw, draft_model=gpt_small(
+            vocab_size=VOCAB, units=16, num_layers=1, num_heads=4,
+            max_length=128))
+        kw["draft_model"].initialize(mx.init.Xavier())
+    eng = GenerationEngine(net, max_slots=2, max_length=64, **kw).warmup()
+    real = getattr(net, program)
+
+    def slow(*args, **kwargs):
+        first, *rest = real(*args, **kwargs)
+        return (_SlowToFetch(first), *rest)
+    monkeypatch.setattr(net, program, slow)
+    telemetry.reset()
+    try:
+        eng.submit(_prompt(6), max_new_tokens=7).result(timeout=60)
+    finally:
+        eng.close()
+    got = telemetry.hist_quantiles("serving.generate.decode")
+    assert got["count"] >= 2
+    assert got["avg"] >= 20.0 and got["min"] >= 20.0, got

@@ -158,15 +158,8 @@ def _gathered_attention(q, k_pool, v_pool, k_scale, v_scale, table,
     coordinates from ``start``. ``k_scale``/``v_scale`` (n_pages, H)
     mark an INT8 pool, whose every page is dequantized with the scale
     it was written under."""
-    kg = _att.gather_pages(k_pool, table)
-    vg = _att.gather_pages(v_pool, table)
-    if k_scale is not None:
-        ps = k_pool.shape[2]
-        kg = kg.astype(jnp.float32) \
-            * _att.expand_page_scales(k_scale, table, ps)[..., None]
-        vg = vg.astype(jnp.float32) \
-            * _att.expand_page_scales(v_scale, table, ps)[..., None]
-    else:
+    kg, vg = _att.gather_kv(k_pool, v_pool, table, k_scale, v_scale)
+    if k_scale is None:
         kg, vg = kg.astype(q.dtype), vg.astype(q.dtype)
     return _att.chunked_prefill_attention(q, kg, vg, start)
 

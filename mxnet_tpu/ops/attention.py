@@ -20,6 +20,10 @@ here because they shape the core design on TPU:
   row's valid length (serving/generate.py slot batches). jnp path
   everywhere; Pallas TPU kernel (scalar-prefetched lengths, KV
   streamed through VMEM) behind the same `_use_pallas()` gate.
+- `paged_decode_attention` — the same against a PAGED cache: a page
+  pool `(n_pages, H, page_size, D)` and a per-slot page table. Each
+  slot's view is gathered from the pool and attended on the jnp path,
+  on every backend: no Pallas kernel (PERF.md §6, PR 25).
 
 All shapes are (batch, heads, seq, head_dim). `kv_len` arguments mean
 "only the first kv_len entries of the key/value buffer are real" —
@@ -410,12 +414,10 @@ def _decode_fwd_kernel(len_ref, q_ref, k_ref, v_ref, *rest, scale,
     block is written once, on the last grid step.
 
     ``quant=True`` (int8 KV cache) adds two whole-array SMEM scale
-    inputs right after ``v_ref``, indexed ``[batch * H + head, kb]``
-    (one column for the dense cache, one per page slot for the paged
-    one): the resident int8 block is dequantized IN-REGISTER with its
-    slot's (dense) or page's (paged) per-head scale — the fp32 K/V
-    never exist outside VMEM, so the cache's HBM footprint (and the
-    DMA per step) is the int8 bytes."""
+    inputs right after ``v_ref``, indexed ``[batch * H + head, 0]``:
+    the resident int8 block is dequantized IN-REGISTER with its slot's
+    per-head scale — the fp32 K/V never exist outside VMEM, so the
+    cache's HBM footprint (and the DMA per step) is the int8 bytes."""
     import jax.experimental.pallas as pl
     if quant:
         ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = rest
@@ -427,10 +429,8 @@ def _decode_fwd_kernel(len_ref, q_ref, k_ref, v_ref, *rest, scale,
     nblocks = (length + block_k - 1) // block_k   # this slot's valid blocks
     if quant:
         # program ids are read here: the interpreter cannot bind them
-        # inside a pl.when body. One scale column means one scale per
-        # slot row (dense cache); else one per page slot.
+        # inside a pl.when body
         sc_row = b * pl.num_programs(1) + pl.program_id(1)
-        sc_col = kb if ks_ref.shape[1] > 1 else 0
 
     @pl.when(kb == 0)
     def _init():
@@ -445,8 +445,8 @@ def _decode_fwd_kernel(len_ref, q_ref, k_ref, v_ref, *rest, scale,
         k = k_ref[0, 0].astype(jnp.float32)            # (block_k, d)
         v = v_ref[0, 0].astype(jnp.float32)
         if quant:
-            k = k * ks_ref[sc_row, sc_col]
-            v = v * vs_ref[sc_row, sc_col]
+            k = k * ks_ref[sc_row, 0]
+            v = v * vs_ref[sc_row, 0]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)        # (sq, bk)
@@ -553,16 +553,6 @@ def gather_pages(pool, table):
     return g.transpose(0, 2, 1, 3, 4).reshape(b, h, pm * ps, d)
 
 
-def _paged_decode_fwd_kernel(len_ref, tbl_ref, q_ref, k_ref, v_ref,
-                             *rest, **kw):
-    """Paged decode grid step: the page table participates only in the
-    BlockSpec index maps (it chooses WHICH pool page each grid step
-    DMAs); once the right (1, 1, page_size, d) pool block is resident
-    the arithmetic is exactly the dense decode kernel's."""
-    del tbl_ref
-    _decode_fwd_kernel(len_ref, q_ref, k_ref, v_ref, *rest, **kw)
-
-
 def expand_page_scales(pool_scale, table, page_size):
     """Broadcast per-head-per-PAGE scales onto token positions:
     ``pool_scale`` (n_pages, H) + ``table`` (B, P_max) ->
@@ -573,74 +563,18 @@ def expand_page_scales(pool_scale, table, page_size):
     return jnp.repeat(g.transpose(0, 2, 1), page_size, axis=2)
 
 
-def paged_decode_attention_pallas(q, k_pool, v_pool, table, lengths,
-                                  scale=None, interpret=False,
-                                  k_scale=None, v_scale=None):
-    """Pallas paged-decode kernel: grid (batch, head, page-slot) with
-    BOTH the per-slot lengths and the page table scalar-prefetched into
-    the KV BlockSpec index maps. Grid step ``kb`` of slot ``i`` DMAs
-    pool page ``table[i, kb]`` — so the data that moves is each slot's
-    OWN pages, wherever they sit in the pool, and (as in the dense
-    decode kernel) steps at or past the slot's valid prefix clamp to
-    its last valid page: a repeated block index lets the TPU pipeline
-    elide the copy, bounding DMA to ceil(len/page_size) pages per
-    slot. Compute for those steps is skipped in the kernel.
-    ``k_scale``/``v_scale`` (n_pages, H) mark an int8 pool: each
-    resident page is dequantized in VMEM with ITS OWN per-head scale
-    (gathered per slot through the same table that indexes the
-    page)."""
-    import jax.experimental.pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    b, h, sq, d = q.shape
-    n_pages, hp, ps, dp = k_pool.shape
-    if (hp, dp) != (h, d):
-        raise ValueError(
-            f"pool layout {k_pool.shape} does not match q {q.shape}")
-    p_max = table.shape[1]
-    scale = scale if scale is not None else 1.0 / math.sqrt(d)
-    quant = k_scale is not None
-
-    def _kv_index(i, j, kb, lens, tbl):
-        last = jnp.maximum((lens[i] + ps - 1) // ps - 1, 0)
-        return (tbl[i, jnp.minimum(kb, last)], j, 0, 0)
-
-    in_specs = [
-        pl.BlockSpec((1, 1, sq, d),
-                     lambda i, j, kb, lens, tbl: (i, j, 0, 0)),
-        pl.BlockSpec((1, 1, ps, d), _kv_index),
-        pl.BlockSpec((1, 1, ps, d), _kv_index),
-    ]
-    operands = [q, k_pool, v_pool]
-    if quant:
-        # each slot's page scales, gathered through its table row to
-        # (B * H, P_max) and held whole in SMEM: grid step kb reads
-        # column kb (a (1, 1) VMEM block breaks the (8, 128) tiling)
-        in_specs += [pl.BlockSpec(memory_space=pltpu.SMEM)] * 2
-        operands += [
-            sc.astype(jnp.float32)[table].transpose(0, 2, 1)
-            .reshape(b * h, p_max) for sc in (k_scale, v_scale)]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b, h, p_max),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, sq, d),
-                               lambda i, j, kb, lens, tbl: (i, j, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((sq, 128), jnp.float32),   # running max
-            pltpu.VMEM((sq, 128), jnp.float32),   # running denominator
-            pltpu.VMEM((sq, d), jnp.float32),     # running numerator
-        ],
-    )
-    kernel = functools.partial(_paged_decode_fwd_kernel, scale=scale,
-                               block_k=ps, nkb=p_max, quant=quant)
-    return pl.pallas_call(
-        kernel,
-        name="paged_decode_attention",
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, h, sq, d), q.dtype),
-        interpret=interpret,
-    )(lengths.astype(jnp.int32), table.astype(jnp.int32), *operands)
+def gather_kv(k_pool, v_pool, table, k_scale=None, v_scale=None):
+    """Both pools' gathered views (``gather_pages``). ``k_scale``/
+    ``v_scale`` (n_pages, H) mark an INT8 pool: its views come back
+    fp32, every page dequantized with the scale it was written under."""
+    k, v = gather_pages(k_pool, table), gather_pages(v_pool, table)
+    if k_scale is not None:
+        ps = k_pool.shape[2]
+        k = k.astype(jnp.float32) \
+            * expand_page_scales(k_scale, table, ps)[..., None]
+        v = v.astype(jnp.float32) \
+            * expand_page_scales(v_scale, table, ps)[..., None]
+    return k, v
 
 
 def paged_decode_attention(q, k_pool, v_pool, table, lengths,
@@ -650,36 +584,29 @@ def paged_decode_attention(q, k_pool, v_pool, table, lengths,
     ``q`` is (B, H, Sq, D); ``k_pool``/``v_pool`` are the global page
     pools (n_pages, H, page_size, D); ``table`` (B, P_max) int32 maps
     each slot's logical page index to a physical pool page; ``lengths``
-    (B,) int32 marks each slot's valid token prefix. Semantics equal
-    ``decode_attention`` over the gathered per-slot view — the jnp
-    path literally IS that (gather + the same masked softmax, so a
-    paged cache holding the same values produces bit-identical logits
-    to the dense cache); the Pallas TPU path streams only each slot's
-    valid pages through VMEM via scalar-prefetched (lengths, table)
-    index maps.
+    (B,) int32 marks each slot's valid token prefix: every query row
+    attends keys ``[0, length)`` (``Sq > 1`` is a speculative verify).
+    Past a length K may hold anything (its scores are masked); V has to
+    be finite there, as for the chunk programs: 0 * NaN is NaN.
 
-    ``k_scale``/``v_scale`` (n_pages, H) fp32 mark an INT8 pool
-    (half the HBM per cached token vs bf16, a quarter vs fp32): the
-    jnp path dequantizes the gathered view with each page's per-head
-    scale; the Pallas path dequantizes each page in VMEM after the
-    DMA — int8 is what moves."""
+    It IS ``decode_attention``'s jnp path over the gathered per-slot
+    view (gather + the same masked softmax), on every backend, so a
+    paged cache holding the same values produces bit-identical logits
+    to the dense cache on that path. The gather moves all ``P_max``
+    pages of every slot whatever its length, and reads them from the
+    pool in whatever layout the pool's write left. A Pallas kernel has
+    to be handed the pools row-major, and that whole-pool copy of K and
+    of V a layer cost a GPT-2 large tick more than a kernel moving only
+    held pages saved (PERF.md §6, PR 25).
+
+    ``k_scale``/``v_scale`` (n_pages, H) fp32 mark an INT8 pool (half
+    the HBM per cached token vs bf16, a quarter vs fp32), dequantized
+    after the gather with each page's per-head scale."""
     scale_v = scale if scale is not None else 1.0 / math.sqrt(q.shape[-1])
-    lengths = jnp.asarray(lengths, jnp.int32)
-    table = jnp.asarray(table, jnp.int32)
-    if _use_pallas():
-        return paged_decode_attention_pallas(q, k_pool, v_pool, table,
-                                             lengths, scale=scale_v,
-                                             k_scale=k_scale,
-                                             v_scale=v_scale)
-    k = gather_pages(k_pool, table)
-    v = gather_pages(v_pool, table)
-    if k_scale is not None:
-        ps = k_pool.shape[2]
-        k = k.astype(jnp.float32) \
-            * expand_page_scales(k_scale, table, ps)[..., None]
-        v = v.astype(jnp.float32) \
-            * expand_page_scales(v_scale, table, ps)[..., None]
-    return _decode_fwd_jnp(q, k, v, lengths, scale_v)
+    k, v = gather_kv(k_pool, v_pool, jnp.asarray(table, jnp.int32),
+                     k_scale, v_scale)
+    return _decode_fwd_jnp(q, k, v, jnp.asarray(lengths, jnp.int32),
+                           scale_v)
 
 
 def chunked_prefill_attention(q, k, v, start, scale=None):

@@ -270,25 +270,93 @@ def test_paged_decode_attention_matches_gathered_reference():
     assert onp.abs(onp.asarray(out[0])).max() == 0.0   # empty slot
 
 
-@pytest.mark.requires_pallas
-def test_paged_decode_attention_pallas_parity():
-    """The Pallas paged kernel (scalar-prefetched lengths + page table
-    bounding DMA to each slot's valid pages) matches the jnp path."""
-    onp.random.seed(8)
-    B, H, D, PS, NP, P_MAX = 3, 2, 32, 16, 30, 6
-    mk = lambda *s: jnp.asarray(  # noqa: E731
-        onp.random.randn(*s).astype("float32") * 0.5)
-    kpool, vpool = mk(NP, H, PS, D), mk(NP, H, PS, D)
-    rng = onp.random.RandomState(9)
-    table = jnp.asarray(rng.permutation(onp.arange(1, NP))
-                        [:B * P_MAX].reshape(B, P_MAX).astype("i4"))
-    lengths = jnp.asarray([5, 0, 96], jnp.int32)
-    q = mk(B, H, 1, D)
-    ref = at.paged_decode_attention(q, kpool, vpool, table, lengths)
-    pal = at.paged_decode_attention_pallas(q, kpool, vpool, table,
-                                           lengths, interpret=True)
-    onp.testing.assert_allclose(onp.asarray(pal), onp.asarray(ref),
-                                rtol=2e-4, atol=2e-5)
+# every length the masks treat apart, in one batch: an empty slot, one
+# token, exactly a page, a page + 1, six pages, one more, ragged, and
+# the full table
+_PAGED_LENGTHS = [0, 1, 16, 17, 96, 97, 77, 128]
+
+
+def _paged_case(h, sq, dtype, seed=8, ps=16, d=64, p_max=8):
+    """A paged cache holding ``_PAGED_LENGTHS``: every slot's pages
+    scattered over the pool, slot 6 sharing its first two pages with
+    slot 4 (a cached prefix), free table entries on the scrap page 0.
+    Wherever no valid position lies — the scrap page and every held
+    page's rest past its slot's length — K is NaN and V large (V has to
+    be finite there: its probability is 0, and 0 * NaN is NaN)."""
+    rng = onp.random.RandomState(seed)
+    lengths = onp.asarray(_PAGED_LENGTHS, "i4")
+    b = len(lengths)
+    n_pages = 1 + b * p_max
+    mk = lambda *sh: (rng.randn(*sh) * 0.5).astype("f4")  # noqa: E731
+    kpool, vpool = mk(n_pages, h, ps, d), mk(n_pages, h, ps, d)
+    free = list(rng.permutation(onp.arange(1, n_pages)))
+    table = onp.zeros((b, p_max), "i4")
+    for i, n in enumerate(lengths):
+        held = -(-int(n) // ps)
+        table[i, :held] = [free.pop() for _ in range(held)]
+    table[6, :2] = table[4, :2]                    # a shared prefix
+    for pool, junk in ((kpool, onp.nan), (vpool, 1e4)):
+        pool[0] = junk                             # the scrap page
+        for i, n in enumerate(lengths):
+            if n % ps and i != 4:   # slot 4's pages are slot 6's too
+                pool[table[i, n // ps], :, n % ps:] = junk
+    q = mk(b, h, sq, d)
+    cast = lambda x: jnp.asarray(x).astype(dtype)  # noqa: E731
+    return (cast(q), cast(kpool), cast(vpool), jnp.asarray(table),
+            jnp.asarray(lengths))
+
+
+def _paged_reference(q, kpool, vpool, table, lengths, k_scale=None,
+                     v_scale=None):
+    """Plain numpy, slot by slot: the slot's pages in table order, cut
+    to its length, softmax in float64. An int8 pool is dequantized page
+    by page first."""
+    q, kpool, vpool = (onp.asarray(x, "f8") for x in (q, kpool, vpool))
+    if k_scale is not None:
+        kpool = kpool * onp.asarray(k_scale, "f8")[:, :, None, None]
+        vpool = vpool * onp.asarray(v_scale, "f8")[:, :, None, None]
+    table, lengths = onp.asarray(table), onp.asarray(lengths)
+    out = onp.zeros(q.shape)
+    for i, n in enumerate(lengths):
+        if n == 0:
+            continue
+        k = onp.concatenate(list(kpool[table[i]]), axis=1)[:, :n]
+        v = onp.concatenate(list(vpool[table[i]]), axis=1)[:, :n]
+        s = onp.einsum("hqd,hkd->hqk", q[i], k) / onp.sqrt(q.shape[-1])
+        p = onp.exp(s - s.max(axis=-1, keepdims=True))
+        out[i] = onp.einsum("hqk,hkd->hqd",
+                            p / p.sum(axis=-1, keepdims=True), v)
+    return out
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("sq", [1, 5], ids=["decode", "verify"])
+@pytest.mark.parametrize("heads", [2, 16, 20])
+def test_paged_decode_attention_parity(heads, sq, dtype):
+    """Paged decode matches a plain reference over each slot's own
+    pages for every length in ``_PAGED_LENGTHS``, a page two slots
+    share, and junk wherever no valid position lies."""
+    q, kpool, vpool, table, lengths = _paged_case(heads, sq, dtype)
+    out = at.paged_decode_attention(q, kpool, vpool, table, lengths)
+    assert out.dtype == q.dtype
+    out = onp.asarray(out.astype(jnp.float32))
+    assert (out[0] == 0).all()                     # the empty slot
+    tol = dict(rtol=2e-4, atol=2e-5) if dtype == "float32" \
+        else dict(rtol=2e-2, atol=4e-3)
+    onp.testing.assert_allclose(
+        out, _paged_reference(q, kpool, vpool, table, lengths), **tol)
+
+
+def test_paged_decode_attention_under_jit_follows_lengths():
+    """One compiled program serves every set of lengths."""
+    q, kpool, vpool, table, lengths = _paged_case(2, 1, "float32")
+    fn = jax.jit(at.paged_decode_attention)
+    for lens in (lengths, jnp.zeros_like(lengths),
+                 jnp.minimum(lengths, 16)):
+        onp.testing.assert_allclose(
+            onp.asarray(fn(q, kpool, vpool, table, lens)),
+            _paged_reference(q, kpool, vpool, table, lens),
+            rtol=2e-4, atol=2e-5)
 
 
 def test_chunked_prefill_attention_matches_reference():

@@ -5,7 +5,7 @@ Guarantees under test:
   scale step per channel (``ops.quantized.quantize_channelwise``);
 - the fused dequant-matmul pair — blocked jnp reference and Pallas
   kernel — is BITWISE identical (one numerical path, two executors);
-- int8-KV decode attention (dense and paged, jnp and Pallas) stays
+- int8-KV decode attention (dense: jnp and Pallas; paged: jnp) stays
   within a per-step error bound of the fp32 cache on the same values;
 - an int8-weights GenerationEngine holds the bounded-divergence
   contract against its fp32 twin (greedy agreement + logit bound,
@@ -154,7 +154,8 @@ def test_int8_kv_decode_attention_error_bound():
 @pytest.mark.requires_pallas
 def test_int8_kv_decode_attention_pallas_parity():
     """The Pallas int8 decode kernel (in-VMEM dequant) matches the jnp
-    dequant path, dense and paged."""
+    dequant path; the paged jnp path, with a scale a page, tracks the
+    unquantized cache."""
     from mxnet_tpu.ops import attention as att
     rng = onp.random.RandomState(4)
     B, H, S, D = 3, 2, 32, 8
@@ -196,11 +197,42 @@ def test_int8_kv_decode_attention_pallas_parity():
     ref = onp.asarray(att.decode_attention(q, kf, vf, lengths))
     pg_jnp = onp.asarray(att.paged_decode_attention(
         q, pool_k, pool_v, table, lengths, k_scale=sc_k, v_scale=sc_v))
-    pg_pl = onp.asarray(att.paged_decode_attention_pallas(
-        q, pool_k, pool_v, table, lengths, k_scale=sc_k, v_scale=sc_v,
-        interpret=True))
     assert onp.abs(pg_jnp - ref).max() < 0.05
-    assert onp.abs(pg_jnp - pg_pl).max() < 1e-5
+
+
+@pytest.mark.parametrize("sq", [1, 5], ids=["decode", "verify"])
+@pytest.mark.parametrize("heads", [16, 20])
+def test_int8_paged_decode_attention_parity(heads, sq):
+    """Paged decode over an int8 pool matches the same attention over
+    the pool dequantized by hand, at the served head counts: lengths
+    0, 1, a page, a page + 1, six pages, one more, ragged, the full
+    table; a page two slots share; on the scrap page garbage values
+    under a NaN scale for K and a large one for V (finite: 0 * NaN is
+    NaN)."""
+    from mxnet_tpu.ops import attention as att
+    rng = onp.random.RandomState(5)
+    ps, d, p_max = 16, 64, 8
+    lengths = onp.asarray([0, 1, 16, 17, 96, 97, 77, 128], "i4")
+    b = len(lengths)
+    n_pages = 1 + b * p_max
+    kf = rng.randn(n_pages, heads, ps, d).astype("f4")
+    vf = rng.randn(n_pages, heads, ps, d).astype("f4")
+    kq, vq, ks, vs = _quant_kv(kf, vf)             # one scale a page, head
+    free = list(rng.permutation(onp.arange(1, n_pages)))
+    table = onp.zeros((b, p_max), "i4")
+    for i, n in enumerate(lengths):
+        held = -(-int(n) // ps)
+        table[i, :held] = [free.pop() for _ in range(held)]
+    table[6, :2] = table[4, :2]                    # a shared prefix
+    q = rng.randn(b, heads, sq, d).astype("f4")
+    ref = onp.asarray(att.paged_decode_attention(
+        q, kq * ks[:, :, None, None], vq * vs[:, :, None, None], table,
+        lengths))
+    ks[0], vs[0] = onp.nan, 1e4
+    out = onp.asarray(att.paged_decode_attention(
+        q, kq, vq, table, lengths, k_scale=ks, v_scale=vs))
+    assert (out[0] == 0).all()                     # the empty slot
+    onp.testing.assert_allclose(out, ref, rtol=2e-4, atol=2e-5)
 
 
 # -- model-level bounded divergence ------------------------------------

@@ -1,9 +1,13 @@
-"""Operations and bytes, as functions of shapes.
+"""GPT-2's operations and bytes, as functions of shapes.
 
 Each counts what the algorithm needs, whatever implements it, so that a
 share of a peak cannot pass 100 %: recomputation, padding, layout copies,
 masked-out work and wasted slots are all left out. ``s`` is the dict of
 ``weights.sizes`` (D units, F MLP width, L layers, V vocabulary, H heads).
+The three whole-step counts are what ``reducers/mfu.py`` asks of every
+family; a kernel's cost function (named by ``"cost"`` in
+``layer_metrics/<metric>.json``) takes the traced window's facts and
+returns that window's ``(flops, bytes)``.
 """
 from __future__ import annotations
 
@@ -41,7 +45,7 @@ def train_step_flops(s, batch, seq):
     return dense + attn
 
 
-def flash_fwd_call(s, batch, seq, itemsize=2):
+def _flash_fwd_one(s, batch, seq, itemsize=2):
     """One causal flash-forward call over (batch, H, seq, D/H): the causal
     half of 4 B H S^2 (D/H) FLOPs; q, k, v read and o written once."""
     flops = 2 * batch * seq * seq * s["D"]
@@ -49,21 +53,10 @@ def flash_fwd_call(s, batch, seq, itemsize=2):
     return flops, nbytes
 
 
-def paged_decode_tokens(s, contexts, itemsize=2):
-    """The paged decode kernel's work for decoded tokens that attended to
-    ``contexts`` positions each, over all L layers: K and V of those
-    positions read once (2 ctx D), q read and out written (2 D); 4 D
-    FLOPs per key. Pages a slot holds but the token does not attend to,
-    and slots that decode nothing, are left out."""
-    total = sum(contexts)
-    n = len(contexts)
-    flops = s["L"] * 4 * s["D"] * total
-    nbytes = s["L"] * itemsize * (2 * s["D"] * total + 2 * s["D"] * n)
-    return flops, nbytes
-
-
-def least_seconds(flops, nbytes, peaks):
-    """The least time the chip could take, and which peak bounds it."""
-    t_f = flops / peaks["bf16_flops_per_s"]
-    t_b = nbytes / peaks["hbm_bytes_per_s"]
-    return (t_f, "compute") if t_f >= t_b else (t_b, "hbm")
+def flash_fwd_call(f):
+    """The flash-forward kernel's work in a traced training window ``f``:
+    one call a layer and step."""
+    s = f["sizes"]
+    flops, nbytes = _flash_fwd_one(s, f["batch"], f["sequence"])
+    calls = s["L"] * f["steps"]
+    return flops * calls, nbytes * calls

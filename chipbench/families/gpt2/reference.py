@@ -20,8 +20,9 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
-from chipbench import weights as W
+from chipbench.families.gpt2 import weights as W
 
 HI = jax.lax.Precision.HIGHEST
 
@@ -98,8 +99,8 @@ def _head(w, h, lowp):
 # ---------------------------------------------------------------------------
 @functools.partial(jax.jit, static_argnames=("n_head", "eps", "n_max",
                                              "control"))
-def served_gaps(w, tokens, start, served, n_valid, *, n_head, eps, n_max,
-                control=None):
+def _served_gaps(w, tokens, start, served, n_valid, *, n_head, eps, n_max,
+                 control=None):
     """One request: ``tokens`` (1, T) is the prompt followed by what was
     served, padded; the token served at step i was read off position
     ``start + i``. Returns, for the ``n_valid`` served tokens (the rest 0):
@@ -123,8 +124,39 @@ def served_gaps(w, tokens, start, served, n_valid, *, n_head, eps, n_max,
     return gaps, hits
 
 
+def served_gaps(model, w, prompt, served, n_max, control=None):
+    """One request as it was served: ``prompt`` ids and the ``served``
+    tokens (at most ``n_max``). Returns, per served token, its gap under
+    the float32 reference's best logit at its position and whether it is
+    the reference's first choice (with ``control`` the tokens judged are
+    the ones that forward puts first there).
+
+    GPT-2 has a table of ``n_positions`` learned positions: the request is
+    padded to a row of that length (one compiled program whatever the
+    request), and the logits are read off a window of ``n_max`` positions
+    that starts at the prompt's last. Where the prompt is so long that the
+    window would pass the last position it starts earlier and the served
+    tokens sit ``shift`` rows in."""
+    s = W.sizes(model)
+    p, n = len(prompt), len(served)
+    row = np.zeros((1, s["P"]), np.int32)
+    row[0, :p] = prompt
+    row[0, p:p + n] = served
+    padded = np.zeros((n_max,), np.int32)
+    padded[:n] = served
+    start = min(p - 1, s["P"] - n_max)
+    shift = (p - 1) - start
+    padded = np.roll(padded, shift)
+    g, h = _served_gaps(
+        w, jnp.asarray(row), jnp.int32(start), jnp.asarray(padded),
+        jnp.int32(n + shift), n_head=s["H"],
+        eps=float(model["layer_norm_epsilon"]), n_max=n_max,
+        control=control)
+    return np.asarray(g)[shift:shift + n], np.asarray(h)[shift:shift + n]
+
+
 # ---------------------------------------------------------------------------
-# training: loss, gradients, and Adam as the configuration states it
+# training: loss and gradients (Adam's steps are chipbench/adam.py)
 # ---------------------------------------------------------------------------
 def loss_fn(w, x, y, n_head, eps, lowp=None):
     """Mean next-token cross entropy over every position of (B, T)."""
@@ -141,13 +173,28 @@ def loss_fn(w, x, y, n_head, eps, lowp=None):
 
 
 @functools.partial(jax.jit, static_argnames=("n_head", "eps", "lowp"))
-def loss_and_grads(w, x, y, *, n_head, eps, lowp=None):
+def _loss_and_grads(w, x, y, *, n_head, eps, lowp=None):
     return jax.value_and_grad(loss_fn)(w, x, y, n_head, eps, lowp)
 
 
 @functools.partial(jax.jit, static_argnames=("n_head", "eps", "lowp"))
-def loss_only(w, x, y, *, n_head, eps, lowp=None):
+def _loss_only(w, x, y, *, n_head, eps, lowp=None):
     return loss_fn(w, x, y, n_head, eps, lowp)
+
+
+def _statics(model, lowp):
+    return dict(n_head=W.sizes(model)["H"],
+                eps=float(model["layer_norm_epsilon"]), lowp=lowp)
+
+
+def loss_and_grads(model, w, x, y, lowp=None):
+    """The mean loss of the batch ``(x, y)`` and its gradient, a tree
+    shaped like ``w``."""
+    return _loss_and_grads(w, x, y, **_statics(model, lowp))
+
+
+def loss_only(model, w, x, y, lowp=None):
+    return _loss_only(w, x, y, **_statics(model, lowp))
 
 
 def leaf_norms(tree):
@@ -158,37 +205,7 @@ def leaf_norms(tree):
         if n in W.LAYER_NAMES else None)) for n, a in tree.items()}
 
 
-def _adam_terms(t, lr, b1, b2):
-    return lr * jnp.sqrt(1.0 - b2 ** t) / (1.0 - b1 ** t)
-
-
-@functools.partial(jax.jit, donate_argnums=(0,),
-                   static_argnames=("lr", "b1", "b2", "eps"))
-def adam_first(w, g1, *, lr, b1, b2, eps):
-    """Step 1 of Adam from zero state (``m1 = (1-b1) g1``, ``v1 = (1-b2)
-    g1**2``); the update ``lr_t * m / (sqrt(v) + eps)`` with ``lr_t = lr *
-    sqrt(1 - b2**t) / (1 - b1**t)``, as the program's optimizer has it."""
-    lr1 = _adam_terms(1, lr, b1, b2)
-    return jax.tree.map(
-        lambda w, g: w - lr1 * (1 - b1) * g
-        / (jnp.sqrt((1 - b2) * jnp.square(g)) + eps), w, g1)
-
-
-@functools.partial(jax.jit, donate_argnums=(0,),
-                   static_argnames=("lr", "b1", "b2", "eps"))
-def adam_second(w1, g1, g2, *, lr, b1, b2, eps):
-    """Step 2 from the two gradients, and the per-leaf norm of the change
-    the two steps made together. Returns ``(w2, norms)``."""
-    lr1, lr2 = _adam_terms(1, lr, b1, b2), _adam_terms(2, lr, b1, b2)
-
-    def leaf(w, a, b):
-        m1, v1 = (1 - b1) * a, (1 - b2) * jnp.square(a)
-        m2 = b1 * m1 + (1 - b1) * b
-        v2 = b2 * v1 + (1 - b2) * jnp.square(b)
-        u1 = lr1 * m1 / (jnp.sqrt(v1) + eps)
-        u2 = lr2 * m2 / (jnp.sqrt(v2) + eps)
-        return w - u2, u1 + u2
-
-    both = jax.tree.map(leaf, w1, g1, g2)
-    w2 = {n: p[0] for n, p in both.items()}
-    return w2, leaf_norms({n: p[1] for n, p in both.items()})
+def leaf_index(model):
+    """``{program leaf name: (name in the weights' tree, layer or None)}``:
+    how a per-leaf reading of the program lines up with ``leaf_norms``."""
+    return W.program_leaf_index(W.sizes(model)["L"])

@@ -30,7 +30,6 @@ import time
 import numpy as np
 
 from chipbench import harness
-from chipbench import weights as W
 
 
 # how long past the window's close the load runs on for the first tokens
@@ -130,11 +129,12 @@ def _caller(idx, engine, requests, records, stop):
 def run(ctx):
     from chipbench import program
     model, mix = ctx.config["model"], ctx.mix
-    s = W.sizes(model)
+    s = harness.family(ctx.config, "weights").sizes(model)
+    fam_program = harness.family(ctx.config, "program")
     serve_args = dict(ctx.config["serve"])
     serve_args["max_new_tokens"] = int(mix["new_tokens"]["max"])
 
-    net = program.build_model(model, ctx.seed)
+    net = fam_program.build_model(model, ctx.seed)
     ctx.part("weights_and_model")
     engine = program.build_engine(net, serve_args)
     ctx.part("engine_and_warmup")
@@ -160,7 +160,9 @@ def run(ctx):
         time.sleep(0.01)
 
     ctx.part("ramp")
-    warm_traces, warm_compiles = program.traces(), ctx.compiles.n
+    trace_counters = fam_program.TRACE_COUNTERS
+    warm_traces = program.traces(trace_counters)
+    warm_compiles = ctx.compiles.n
     setup_s = ctx.mark_setup_done()
     t0 = time.perf_counter()
     c0 = program.counters(program.SERVE_COUNTERS)
@@ -188,7 +190,7 @@ def run(ctx):
             if r.submit is not None and r.submit <= t1):
         time.sleep(0.005)
     drained = time.perf_counter()
-    in_window = {"traces": program.traces() - warm_traces,
+    in_window = {"traces": program.traces(trace_counters) - warm_traces,
                  "backend_compiles": ctx.compiles.n - warm_compiles}
     stop.set()
     peak = harness.memory_peak_bytes(ctx.chips)
@@ -282,8 +284,9 @@ def _facts(s, ctx, records, t0, t1, counters, serve_args):
                     first_tokens += 1
                 else:
                     contexts.append(p + i)
-    return {"seconds": t1 - t0, "sizes": s, "peaks": ctx.peaks,
-            "chips": ctx.chips, "max_slots": int(serve_args["max_slots"]),
+    return {"seconds": t1 - t0, "family": ctx.config["family"], "sizes": s,
+            "peaks": ctx.peaks, "chips": ctx.chips,
+            "max_slots": int(serve_args["max_slots"]),
             "decode_contexts": contexts, "prompts_done": prompts_done,
             "tokens": len(contexts) + first_tokens, "counters": counters}
 
@@ -305,41 +308,24 @@ def _sample(records, n, seed):
 def check_served(ctx, model, sample, control=None):
     """Run the reference once over each sampled prompt with its served
     tokens; the number compared is the widest gap by which a served token
-    lies under the reference's best logit at its position."""
-    import jax.numpy as jnp
-    from chipbench import reference
+    lies under the reference's best logit at its position. The request
+    goes to the family's reference as it was served: how it is padded and
+    in how many blocks the forward runs is the family's."""
     limits = ctx.limits
+    reference = harness.family(ctx.config, "reference")
     if not sample:
         return [harness.Check("served_tokens_compared", 0,
                               limits["served_tokens_compared"],
                               at_least=True)]
-    s = W.sizes(model)
     n_max = int(ctx.mix["new_tokens"]["max"])
-    w = W.make(model, ctx.seed)
+    w = harness.family(ctx.config, "weights").make(model, ctx.seed)
     worst, hits, total = 0.0, 0, 0
     for r in sample:
-        p, n = len(r.prompt), len(r.tokens)
-        row = np.zeros((1, s["P"]), np.int32)
-        row[0, :p] = r.prompt
-        row[0, p:p + n] = r.tokens
-        served = np.zeros((n_max,), np.int32)
-        served[:n] = r.tokens
-        start = min(p - 1, s["P"] - n_max)
-        shift = (p - 1) - start
-        # the window of n_max positions starts at `start`; where the
-        # prompt is so long that the window would pass the last position
-        # it starts earlier and the served tokens sit `shift` rows in
-        served = np.roll(served, shift)
-        g, h = reference.served_gaps(
-            w, jnp.asarray(row), jnp.int32(start), jnp.asarray(served),
-            jnp.int32(n + shift), n_head=s["H"],
-            eps=float(model["layer_norm_epsilon"]), n_max=n_max,
-            control=control)
-        g = np.asarray(g)[shift:shift + n]
-        h = np.asarray(h)[shift:shift + n]
+        g, h = reference.served_gaps(model, w, r.prompt, r.tokens, n_max,
+                                     control)
         worst = max(worst, float(g.max()))
         hits += int(h.sum())
-        total += n
+        total += len(r.tokens)
     del w
     if control is None:
         ctx.note("greedy_agreement", hits / max(total, 1))

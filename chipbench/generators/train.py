@@ -18,7 +18,6 @@ import time
 import numpy as np
 
 from chipbench import harness
-from chipbench import weights as W
 
 FOLLOWED = 2          # full steps the reference follows; the third step's
 #                       loss is a forward pass on the weights after two
@@ -57,12 +56,14 @@ def run(ctx):
     import jax.numpy as jnp
     from chipbench import program
     model, job = ctx.config["model"], ctx.mix
-    s = W.sizes(model)
+    weights = harness.family(ctx.config, "weights")
+    s = weights.sizes(model)
     targs = ctx.config["train"]
     hp = dict(targs["optimizer_params"])
     tokens_per_step = int(job["batch"]) * int(job["sequence"])
 
-    net = program.build_model(model, ctx.seed)
+    net = harness.family(ctx.config, "program").build_model(
+        model, ctx.seed)
     step = program.build_train_step(net, targs)
     ctx.part("weights_and_model")
     names = None
@@ -73,7 +74,7 @@ def run(ctx):
     norms_of = jax.jit(lambda xs: [jnp.sqrt(jnp.sum(jnp.square(
         x.astype(jnp.float32)))) for x in xs])
 
-    index = W.program_leaf_index(s["L"])
+    index = harness.family(ctx.config, "reference").leaf_index(model)
 
     @jax.jit
     def change_norms(cur, w0):
@@ -98,7 +99,7 @@ def run(ctx):
             prog["grad"] = {n: float(x) / (1.0 - b1)
                             for n, x in zip(names, g)}
         if k == FOLLOWED - 1:
-            w0 = W.make(model, ctx.seed)
+            w0 = weights.make(model, ctx.seed)
             cur = program.step_params(step)
             d = change_norms([cur[n] for n in names], w0)
             prog["change"] = {n: float(x) for n, x in zip(names, d)}
@@ -148,8 +149,8 @@ def run(ctx):
     steps = len(done_at)
     e2e = {"train_tokens_per_s": steps * tokens_per_step / win,
            "setup_s": setup_s}
-    facts = {"seconds": win, "steps": steps, "sizes": s,
-             "peaks": ctx.peaks, "chips": ctx.chips,
+    facts = {"seconds": win, "steps": steps, "family": ctx.config["family"],
+             "sizes": s, "peaks": ctx.peaks, "chips": ctx.chips,
              "batch": int(job["batch"]), "sequence": int(job["sequence"]),
              "tokens": steps * tokens_per_step,
              "step_ms": [1e3 * (b - a) for a, b in
@@ -177,31 +178,31 @@ def follow(ctx, model, job, hp, lowp=None, half_batch=False):
     and the leaves whose change is compared. ``lowp`` is the control;
     ``half_batch`` plants the fault "half of the batch left out, the mean
     taken over the rest" in the reference."""
-    import jax
     import jax.numpy as jnp
-    from chipbench import reference
-    s = W.sizes(model)
-    kw = dict(n_head=s["H"], eps=float(model["layer_norm_epsilon"]),
-              lowp=lowp)
-    adam = dict(lr=float(hp["learning_rate"]), b1=float(hp["beta1"]),
-                b2=float(hp["beta2"]), eps=float(hp["epsilon"]))
-    index = W.program_leaf_index(s["L"])
+    from chipbench import adam
+    weights = harness.family(ctx.config, "weights")
+    reference = harness.family(ctx.config, "reference")
+    vocab = weights.sizes(model)["V"]
+    hyper = dict(lr=float(hp["learning_rate"]), b1=float(hp["beta1"]),
+                 b2=float(hp["beta2"]), eps=float(hp["epsilon"]))
+    index = reference.leaf_index(model)
 
     def xy(k):
-        b = batch_of(ctx.seed, k, job, s["V"])
+        b = batch_of(ctx.seed, k, job, vocab)
         if half_batch:
             b = b[:b.shape[0] // 2]
         return jnp.asarray(b[:, :-1]), jnp.asarray(b[:, 1:])
 
-    w = W.make(model, ctx.seed)
-    loss1, g1 = reference.loss_and_grads(w, *xy(0), **kw)
+    w = weights.make(model, ctx.seed)
+    loss1, g1 = reference.loss_and_grads(model, w, *xy(0), lowp)
     grad = _flat(reference.leaf_norms(g1), index)
-    w = reference.adam_first(w, g1, **adam)
-    loss2, g2 = reference.loss_and_grads(w, *xy(1), **kw)
-    w, change = reference.adam_second(w, g1, g2, **adam)
+    w = adam.adam_first(w, g1, **hyper)
+    loss2, g2 = reference.loss_and_grads(model, w, *xy(1), lowp)
+    w, change = adam.adam_second(w, g1, g2,
+                                 leaf_norms=reference.leaf_norms, **hyper)
     del g1, g2
     change = _flat(change, index)
-    loss3 = reference.loss_only(w, *xy(2), **kw)
+    loss3 = reference.loss_only(model, w, *xy(2), lowp)
     out = {"loss": [float(loss1), float(loss2), float(loss3)],
            "grad": grad, "change": change}
     del w

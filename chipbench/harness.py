@@ -28,13 +28,29 @@ def load_benchmark():
 
 
 def by_name(package, name):
-    """``chipbench/<package>/<name>.py``, found by name."""
+    """``chipbench/<package>/<name>.py``, found by name (``package`` may be
+    dotted: ``families.<family>``)."""
+    target = f"chipbench.{package}.{name}"
     try:
-        return importlib.import_module(f"chipbench.{package}.{name}")
+        return importlib.import_module(target)
     except ModuleNotFoundError as e:
-        if e.name != f"chipbench.{package}.{name}":
+        # the module itself or its package is missing, not one it imports
+        if not (target + ".").startswith((e.name or "?") + "."):
             raise
-        raise SystemExit(f"chipbench: no {package}/{name}.py")
+        raise SystemExit(f"chipbench: no {package.replace('.', '/')}/"
+                         f"{name}.py")
+
+
+def family(named, module):
+    """``chipbench/families/<family>/<module>.py`` of the family that a
+    configuration, or a window's facts, name under ``"family"``: one of
+    ``weights``, ``reference``, ``costs``, ``program`` (which alone imports
+    the system under test). None named is an error, never a default."""
+    name = named.get("family")
+    if not name:
+        raise SystemExit('chipbench: no "family" named: a configuration '
+                         'says which chipbench/families/<name>/ it is')
+    return by_name(f"families.{name}", module)
 
 
 def applies(metric, cell_name):
@@ -67,6 +83,13 @@ def peaks_for(device_kind):
         raise SystemExit(f"chipbench: no peaks for device_kind "
                          f"{device_kind!r} in chipbench/peaks.json")
     return table[device_kind]
+
+
+def least_seconds(flops, nbytes, peaks):
+    """The least time the chip could take, and which peak bounds it."""
+    t_f = flops / peaks["bf16_flops_per_s"]
+    t_b = nbytes / peaks["hbm_bytes_per_s"]
+    return (t_f, "compute") if t_f >= t_b else (t_b, "hbm")
 
 
 def memory_peak_bytes(chips):

@@ -1,11 +1,14 @@
-"""The system under test, as the benchmark sees it.
+"""The system under test, as the benchmark sees it: what is the same for
+every model.
 
-The one file of ``chipbench/`` that imports ``mxnet_tpu``. It goes through
-the entry points a user calls (``GPTModel``, ``GenerationEngine.submit`` ->
-``GenerationStream``, ``TrainStep.__call__``, ``mx.np.array``) and takes
-from the program only its spans, counters and kernel names. The yardstick
-(traffic, reference, costs, trace reduction, ``correct``) lives beside it
-and imports nothing from here but these functions.
+With a family's own ``families/<name>/program.py`` (which builds the model
+through the class a user would construct) the one file of ``chipbench/``
+that imports ``mxnet_tpu``. It goes through the entry points a user calls
+(``GenerationEngine.submit`` -> ``GenerationStream``, ``TrainStep.__call__``,
+``mx.np.array``) and takes from the program only its spans, counters and
+kernel names. The yardstick (traffic, reference, costs, trace reduction,
+``correct``) lives beside it and imports nothing from here but these
+functions.
 """
 from __future__ import annotations
 
@@ -15,15 +18,8 @@ import jax
 
 import mxnet_tpu as mx
 from mxnet_tpu import compile_cache, gluon, parallel, telemetry
-from mxnet_tpu.gluon.model_zoo.gpt import GPTModel
-from mxnet_tpu.ndarray.ndarray import NDArray
 from mxnet_tpu.serving import GenerationEngine
 
-from chipbench import weights as W
-
-#: counters of the program that count a trace or a compile of a generation
-#: program: more than zero of them inside a window fails the run
-TRACE_COUNTERS = ("model.gpt.trace", "ops.sampling.trace")
 #: the counters the serving reducers read
 SERVE_COUNTERS = tuple("serving.generate." + n for n in (
     "dispatches", "host_syncs", "tokens", "prefill_chunks", "prefills"))
@@ -39,30 +35,11 @@ def counters(names):
     return {n: telemetry.counter_value(n) for n in names}
 
 
-def traces():
-    return sum(telemetry.counter_value(c) for c in TRACE_COUNTERS)
-
-
-def build_model(model, seed):
-    """``GPTModel`` at the sizes of ``model`` (a GPT-2 ``config.json``
-    group), its parameters installed from the benchmark's seeded weights
-    the way ``load_parameters`` installs a checkpoint."""
-    s = W.sizes(model)
-    net = GPTModel(vocab_size=s["V"], units=s["D"], num_layers=s["L"],
-                   num_heads=s["H"], hidden_size=s["F"],
-                   max_length=s["P"],
-                   dropout=float(model.get("resid_pdrop", 0.0)))
-    stacked = W.make(model, seed)
-    leaves = W.program_leaves(stacked)
-    del stacked
-    params = net.collect_params()
-    missing = set(params) ^ set(leaves)
-    if missing:
-        raise SystemExit(f"chipbench: parameter names differ: "
-                         f"{sorted(missing)[:6]}")
-    for name, p in params.items():
-        p.set_data(NDArray(leaves[name]))
-    return net
+def traces(names):
+    """How often the program traced or compiled a generation program, by
+    the family's ``TRACE_COUNTERS``: more than zero of them inside a
+    window fails the run."""
+    return sum(counters(names).values())
 
 
 def build_engine(net, serve_args):

@@ -447,6 +447,35 @@ class _GenWorker(BoundedQueueWorker):
         self.stop(timeout=min(timeout, 2.0) if timeout > 0 else 0.1)
 
 
+def _refuse_unsupported(model, asked):
+    """The model-engine contract's first half (docs/SERVING.md): a model
+    that states what it supports (``generation_support``, a dict) is
+    held to it, and every other option is refused here, by the option's
+    name, before anything is built. ``asked`` maps an option's name to
+    ``(the capability it needs, whether it was asked for, its value)``.
+    A capability is ``True``/``False``, or for a dtype option the tuple
+    of values taken. A model that states nothing (``GPTModel``, test
+    doubles) is probed attribute by attribute as before."""
+    support = getattr(model, "generation_support", None)
+    if support is None:
+        return None
+    for option, (capability, wanted, value) in asked.items():
+        if not wanted:
+            continue
+        allowed = support.get(capability, False)
+        if allowed is True or (isinstance(allowed, tuple)
+                               and str(value) in allowed):
+            continue
+        only = f" (it takes {', '.join(allowed)})" \
+            if isinstance(allowed, tuple) and allowed else ""
+        raise ValueError(
+            f"{option}={value!r} is not supported by "
+            f"{type(model).__name__}{only}: the model's "
+            f"generation_support states what the engine may be asked "
+            f"for with it")
+    return support
+
+
 class GenerationEngine:
     """Continuously-batched greedy generation over a decoder model.
 
@@ -621,6 +650,30 @@ class GenerationEngine:
                  lora_rank=None, max_adapters=None,
                  decode_ticks: int = 1, compute_dtype=None):
         self.paged = bool(paged)
+        support = _refuse_unsupported(model, {
+            "paged": ("paged" if paged else "dense_cache", True,
+                      bool(paged)),
+            "prefix_cache": ("prefix_cache", paged and prefix_cache,
+                             prefix_cache),
+            "quantize": ("quantize", quantize is not None, quantize),
+            "kv_dtype": ("kv_dtype", kv_dtype is not None, kv_dtype),
+            "cache_dtype": ("cache_dtype", cache_dtype is not None,
+                            cache_dtype),
+            "draft_model": ("speculative", draft_model is not None,
+                            draft_model),
+            "speculative": ("speculative", bool(speculative),
+                            speculative),
+            "decode_ticks": ("decode_ticks", int(decode_ticks) != 1,
+                             decode_ticks),
+            "mesh_layout": ("mesh_layout", mesh_layout is not None,
+                            mesh_layout),
+            "mesh": ("mesh_layout", mesh is not None, mesh),
+            "lora_rank": ("lora", lora_rank is not None, lora_rank),
+            "max_adapters": ("lora", max_adapters is not None,
+                             max_adapters),
+            "compute_dtype": ("compute_dtype", True,
+                              compute_dtype or "float32"),
+        })
         if speculative is None:
             speculative = draft_model is not None
         self.speculative = bool(speculative)
@@ -680,26 +733,31 @@ class GenerationEngine:
                 f"'float32' or 'bfloat16')")
         self.compute_dtype = "float32" if compute_dtype is None \
             else str(compute_dtype)
+        self._cast_shadow = False
         if self.compute_dtype == "bfloat16":
             if mesh_layout is not None:
                 raise ValueError(
                     "compute_dtype='bfloat16' does not compose with "
                     "mesh_layout yet: the cast shadow buffers are not "
                     "re-placed over the mesh")
-            if not callable(getattr(model, "cast_compute_params",
-                                    None)):
+            # a model with ``cast_compute_params`` keeps fp32 masters:
+            # the closures consume a bf16 shadow list installed as
+            # runtime arguments (the int8 quant-table discipline —
+            # load_weights re-casts with zero retraces). The draft
+            # model, if any, stays fp32: its logits only steer
+            # proposals. A model without it that STATES bfloat16
+            # compute holds bfloat16 leaves itself: no shadow is kept.
+            cast = getattr(model, "cast_compute_params", None)
+            self._cast_shadow = callable(cast)
+            if self._cast_shadow:
+                t0 = telemetry.clock()
+                cast("bfloat16")
+                telemetry.hist_since("serving.generate.cast.cast", t0)
+            elif support is None:
                 raise TypeError(
                     "compute_dtype='bfloat16' needs a model exposing "
                     "cast_compute_params() "
                     "(gluon.model_zoo.gpt.GPTModel)")
-            # master weights stay fp32; the closures consume a bf16
-            # shadow list installed as runtime arguments (the int8
-            # quant-table discipline — load_weights re-casts with
-            # zero retraces). The draft model, if any, stays fp32:
-            # its logits only steer proposals.
-            t0 = telemetry.clock()
-            model.cast_compute_params("bfloat16")
-            telemetry.hist_since("serving.generate.cast.cast", t0)
         self.lora_enabled = lora_rank is not None
         if max_adapters is not None and not self.lora_enabled:
             raise ValueError(
@@ -728,10 +786,12 @@ class GenerationEngine:
         else:
             self.lora_rank = None
             self.max_adapters = 0
-        api = ("init_paged_cache", "prefill_paged", "decode_step_paged",
-               "peek_logits_paged", "bind_slot_paged",
-               "copy_page_paged") if self.paged \
-            else ("init_cache", "prefill", "decode_step")
+        api = ("init_paged_cache", "prefill_paged", "decode_step_paged") \
+            if self.paged else ("init_cache", "prefill", "decode_step")
+        if self.paged and prefix_cache:
+            # a prefix hit peeks, binds a table row and copies on write
+            api += ("peek_logits_paged", "bind_slot_paged",
+                    "copy_page_paged")
         if self.speculative:
             api += (("verify_commit_paged",)
                     if self.paged else ("verify_commit",))
@@ -891,6 +951,13 @@ class GenerationEngine:
                     f"prefill_chunk {chunk} must be a positive "
                     f"multiple of page_size {ps} within the cache "
                     f"capacity {self._s_max}")
+            widest = (support or {}).get("prefill_chunk_max")
+            if widest is not None and chunk > int(widest):
+                raise ValueError(
+                    f"prefill_chunk={chunk} is wider than "
+                    f"{type(model).__name__} takes ({widest}): a chunk "
+                    f"is written into its window layers' ring before it "
+                    f"is attended")
             self._chunk = chunk
             if policy is None:
                 policy = BucketingPolicy(mode="pow2",
@@ -1495,11 +1562,22 @@ class GenerationEngine:
         _, _, _, cache = vc(zb, dt, ones, cache, q=q, keys=keys,
                             temps=tf, top_ks=zb, top_ps=pf)
 
+    def _chunk_widths(self):
+        """Every width ``_admit`` can give a chunk. A prefix hit starts
+        a prompt's chunks at any page, so near the cache's end a chunk
+        may shrink to any page multiple; without a prefix index chunks
+        start at multiples of the chunk width, and only the one that
+        reaches the cache's end is narrower."""
+        if self._prefix is not None:
+            return list(range(self._ps, self._chunk + 1, self._ps))
+        tail = self._s_max % self._chunk
+        return [tail, self._chunk] if tail else [self._chunk]
+
     def _warmup_paged(self):
         """Compile the paged steady state against a throwaway cache:
         one fresh-prefill program per bucket <= the chunk width, one
-        chunk program per page-multiple width <= the chunk width (tail
-        chunks shrink near the cache end), the decode step, the peek
+        chunk program per width a chunk can take (``_chunk_widths``:
+        tail chunks shrink near the cache end), the decode step, the peek
         (prefix-hit) path, and the table-bind / page-copy (COW)
         helpers. Physical page ids are DATA, not shape — id choice
         here is arbitrary."""
@@ -1514,7 +1592,7 @@ class GenerationEngine:
                 onp.zeros((1, sb), "i4"), sb, 0, row, cache,
                 fresh=True)
             cache = self._recommit(cache)
-        for w in range(self._ps, self._chunk + 1, self._ps):
+        for w in self._chunk_widths():
             _, cache = self.model.prefill_paged(
                 onp.zeros((1, w), "i4"), w, 0, row, cache, start=0)
             cache = self._recommit(cache)
@@ -1524,10 +1602,12 @@ class GenerationEngine:
         cache = self._recommit(cache)
         if self.decode_ticks > 1:
             cache = self._warmup_multi(cache)
-        self.model.peek_logits_paged(0, 0, cache)
-        cache = self._recommit(self.model.bind_slot_paged(0, row, 1,
-                                                          cache))
-        cache = self._recommit(self.model.copy_page_paged(1, 1, cache))
+        if self._prefix is not None:
+            self.model.peek_logits_paged(0, 0, cache)
+            cache = self._recommit(self.model.bind_slot_paged(0, row, 1,
+                                                              cache))
+            cache = self._recommit(self.model.copy_page_paged(1, 1,
+                                                              cache))
         self._warm_samplers(int(lg.shape[-1]))
         if self.speculative:
             self._warmup_spec(cache)
@@ -1582,7 +1662,7 @@ class GenerationEngine:
                     self.model.shard_generation_state(self._part)
                 telemetry.hist_since(
                     "serving.generate.quant.requantize", tq)
-            if self.compute_dtype == "bfloat16":
+            if self.compute_dtype == "bfloat16" and self._cast_shadow:
                 # re-cast the bf16 shadow buffers from the fresh fp32
                 # masters INSIDE the swap window — same avals, so zero
                 # retraces (the quant-table discipline); a decode step

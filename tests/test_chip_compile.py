@@ -177,3 +177,39 @@ def test_kernel_is_named_in_the_compiled_program(one_chip,
     # this text gives once more under operand_layout_constraints
     shapes = inst.split("operand_layout_constraints={")[1].split("}, ")[0]
     assert inst.index("tpu_custom_call") + len(shapes) < 1200
+
+
+# -- the serving expert layer's grouped product (ops/moe.py), at the
+# -- published widths of dots3-note-prev: 32 held experts of 5120 x 1536
+E_HELD, D_MODEL, F_EXPERT, TOP_K = 32, 5120, 1536, 8
+
+
+@pytest.mark.parametrize("tokens,tm", [(32, 32), (512, 128)],
+                         ids=["tick", "chunk"])
+@pytest.mark.parametrize("kn", [(D_MODEL, F_EXPERT), (F_EXPERT, D_MODEL)],
+                         ids=["gate-up", "down"])
+def test_moe_grouped_matmul(one_chip, no_compile_cache, tokens, tm, kn):
+    """A decode tick's rows (32 slots x top 8, tiles of 32) and a prefill
+    chunk's (512 x 8, tiles of 128): the grid's middle axis is the
+    number of tiles that hold rows, known only on the device, and the
+    kernel keeps its name in the compiled program."""
+    from mxnet_tpu.ops import moe
+    k, n = kn
+    m = moe.rows_for(tokens * TOP_K, E_HELD, tm)
+
+    def fn(x, w, tile_expert, n_active):
+        with jax.named_scope("moe"):
+            return moe.grouped_matmul_pallas(x, w, tile_expert,
+                                             n_active[0], tm)
+
+    text = _compile(fn, one_chip, ((m, k), jnp.bfloat16),
+                    ((E_HELD, k, n), jnp.bfloat16),
+                    ((m // tm,), jnp.int32), ((1,), jnp.int32))
+    calls = [line.strip() for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 1, calls
+    inst = calls[0].removeprefix("ROOT ")
+    assert inst.split(" = ")[0].lstrip("%").split(".")[0] \
+        == moe.KERNEL_NAME, inst[:120]
+    assert f"/moe/{moe.KERNEL_NAME}" in \
+        inst.split("op_name=")[1].split('"')[1]

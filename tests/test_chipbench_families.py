@@ -1,0 +1,207 @@
+"""The benchmark's model families, in tier 1.
+
+``chipbench/tests/test_families.py`` (PR 26) holds the family interface,
+the reducers on a stub family's constant costs and the scan for a model's
+names outside its family; tier 1 runs ``tests/`` only, so its cases are
+run from here. **Two of them cannot pass once a second family exists**
+(they pin the list of modules that import the program to
+``families/gpt2/program.py``, and look for GPT-2's ``n_head`` as a
+substring, which ``index_n_heads`` contains), and a ``model_config`` PR
+edits no file the benchmark has. They fail in ``python -m pytest
+chipbench/tests`` on this tree; here they are left out of the cases taken
+over and superseded by the two cases marked ``SUPERSEDES`` below, which
+say the same for every family by whole words. The ``benchmark`` PR that
+edits the two in place deletes the two marked here (``PERF.md`` section 7).
+The ``dots3`` family is held to the same interface, and its configuration
+to the catalog's published keys.
+"""
+import importlib.util
+import json
+import os
+import re
+import sys
+
+import numpy as np
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+
+from chipbench import harness  # noqa: E402
+
+BENCH = os.path.join(ROOT, "chipbench")
+_spec = importlib.util.spec_from_file_location(
+    "chipbench_tests_test_families",
+    os.path.join(BENCH, "tests", "test_families.py"))
+_cases = importlib.util.module_from_spec(_spec)
+_spec.loader.exec_module(_cases)
+
+# -- the cases of chipbench/tests/test_families.py that hold for any number
+# -- of families, run as they stand
+stub_family = _cases.stub_family
+test_gpt2_family_has_the_documented_interface = \
+    _cases.test_gpt2_family_has_the_documented_interface
+test_a_family_is_named_or_the_run_ends = \
+    _cases.test_a_family_is_named_or_the_run_ends
+test_every_configuration_names_a_family_that_is_there = \
+    _cases.test_every_configuration_names_a_family_that_is_there
+test_mfu_counts_by_the_family_the_facts_name = \
+    _cases.test_mfu_counts_by_the_family_the_facts_name
+test_kernel_roofline_counts_by_the_family_the_facts_name = \
+    _cases.test_kernel_roofline_counts_by_the_family_the_facts_name
+test_op_share_on_a_made_up_trace = _cases.test_op_share_on_a_made_up_trace
+test_every_per_layer_metric_has_its_reader_and_no_reader_is_left_over = \
+    _cases.test_every_per_layer_metric_has_its_reader_and_no_reader_is_left_over
+
+FAMILIES = sorted(
+    d for d in os.listdir(os.path.join(BENCH, "families"))
+    if os.path.isfile(os.path.join(BENCH, "families", d, "__init__.py")))
+#: names that belong to one family's block and program, as whole words
+NAMES = {
+    "gpt2": ("n_embd", "n_head", "n_positions", "layer_norm_epsilon",
+             "GPTModel", "model.gpt.trace", "families.gpt2"),
+    "dots3": ("Dots3Model", "model.dots3.trace", "families.dots3",
+              "kv_lora_rank", "index_topk", "swa_kv_lora_rank",
+              "router_experts", "n_routed_experts"),
+}
+
+
+def test_the_families_are_the_ones_named_here():
+    assert FAMILIES == sorted(NAMES)
+
+
+@pytest.mark.parametrize("family", sorted(NAMES))
+@pytest.mark.parametrize("module", sorted(_cases.INTERFACE))
+def test_family_has_the_documented_interface(family, module):
+    got = harness.family({"family": family}, module)
+    for name in _cases.INTERFACE[module]:
+        assert hasattr(got, name), (family, module, name)
+
+
+def _files_outside(family):
+    """Code, text and data of ``chipbench/`` that belong to no one family:
+    not ``families/<family>/``, not the configurations (each is its
+    model's own published group), not a cell's, a mix's or a metric's
+    data file that names the family's cell, program or kernel."""
+    for d, dirs, files in os.walk(BENCH):
+        dirs[:] = [x for x in dirs if x != "__pycache__"]
+        rel = os.path.relpath(d, BENCH)
+        if rel.startswith(os.path.join("families", family)) \
+                or rel == "configs":
+            continue
+        for f in files:
+            if f.endswith((".py", ".md")) or (
+                    f.endswith(".json")
+                    and rel not in ("limits", "traffic", "layer_metrics")):
+                yield os.path.join(d, f)
+
+
+# SUPERSEDES chipbench/tests/test_families.py::
+# test_no_gpt2_name_outside_its_family
+@pytest.mark.parametrize("family", sorted(NAMES))
+def test_no_family_name_outside_its_family(family):
+    found = []
+    for path in _files_outside(family):
+        with open(path) as f:
+            text = f.read()
+        found += [(os.path.relpath(path, ROOT), n) for n in NAMES[family]
+                  if re.search(rf"(?<![\w.]){re.escape(n)}(?![\w])", text)]
+    # the cases taken over from PR 26 name GPT-2's keys to look for them
+    found = [x for x in found
+             if x[0] != os.path.join("chipbench", "tests",
+                                     "test_families.py")]
+    assert not found, found
+
+
+# SUPERSEDES chipbench/tests/test_families.py::
+# test_only_the_two_program_modules_import_the_program
+def test_only_the_program_modules_import_the_program():
+    importing = []
+    for d, dirs, files in os.walk(BENCH):
+        dirs[:] = [x for x in dirs if x not in ("__pycache__", "tests")]
+        for f in files:
+            if not f.endswith(".py"):
+                continue
+            with open(os.path.join(d, f)) as fh:
+                if re.search(r"^\s*(import|from) mxnet_tpu", fh.read(),
+                             re.M):
+                    importing.append(os.path.relpath(
+                        os.path.join(d, f), BENCH))
+    assert sorted(importing) == sorted(
+        ["program.py"] + [f"families/{f}/program.py" for f in FAMILIES])
+
+
+# -- the dots3 configuration against the catalog's published keys -----------
+CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
+WIDTHS = {
+    "hidden_size": 5120, "num_attention_heads": 128,
+    "qk_nope_head_dim": 128, "qk_rope_head_dim": 64, "v_head_dim": 128,
+    "swa_num_attention_heads": 64, "swa_qk_nope_head_dim": 192,
+    "swa_qk_rope_head_dim": 64, "swa_v_head_dim": 128,
+    "q_lora_rank": 1024, "kv_lora_rank": 512, "swa_q_lora_rank": 1024,
+    "swa_kv_lora_rank": 1024, "index_n_heads": 64, "index_head_dim": 128,
+    "index_topk": 2048, "sliding_window_size": 513,
+    "num_experts_per_tok": 8, "moe_intermediate_size": 1536,
+    "intermediate_size": 13824,
+}
+
+
+@pytest.fixture(scope="module")
+def config():
+    return harness.load_json("configs", "dots3-note-prev.json")
+
+
+def test_dots3_configuration_keeps_every_published_width(config):
+    model = config["model"]
+    for key, value in WIDTHS.items():
+        assert model[key] == value, key
+    assert model["router_experts"] == 256
+    assert sorted(config["reduced"]) == [
+        "layer_types", "n_routed_experts", "num_hidden_layers",
+        "vocab_size"]
+    assert not set(config["reduced"]) & set(WIDTHS)
+    assert config["published"]["n_routed_experts"] == 256
+    assert config["published"]["vocab_size"] == 8 * model["vocab_size"]
+    assert model["layer_types"] == config["published"]["layer_types"][:5]
+    assert "8 chips share each layer" in config["deployment"]
+
+
+def test_dots3_configuration_states_its_keys_twice_alike(config):
+    """The published keys stand twice, for two readers: at the top of
+    the file, where the benchmark's contract compares them with the
+    catalog's entry (a key left out there is refused before any run),
+    and in the ``model`` group, which is all ``generators/closed_loop.py``
+    hands the family. One truth."""
+    model = config["model"]
+    extra = {"router_experts", "expert_rank", "initializer_range"}
+    for key, value in model.items():
+        if key not in extra:
+            assert config[key] == value, key
+
+
+def test_dots3_configuration_is_the_catalog_entry_but_for_reduced(config):
+    if not os.path.isfile(CATALOG):
+        pytest.skip("no catalog beside the model-configs guide here")
+    with open(CATALOG) as f:
+        rows = [json.loads(line) for line in f]
+    entry = next(r for r in rows if r["name"] == "dots3-note-prev")
+    assert config["source"] == entry["source_url"]
+    for key, value in entry["config"].items():
+        if key in config["reduced"]:
+            assert config["published"][key] == value, key
+        else:
+            assert config[key] == value and config["model"][key] == value, key
+
+
+def test_dots3_parameter_count_is_the_share_the_configuration_states(config):
+    weights = harness.family(config, "weights")
+    s = weights.sizes(config["model"])
+    n = weights.parameter_count(s)
+    assert 4.08e9 < n < 4.10e9
+    # bytes held by the program: two a parameter, and two more for the
+    # leaves it keeps in float32 (the router, the indexer's branch)
+    wide = sum(
+        int(np.prod(shape)) for i in range(s["L"])
+        for name, shape, _ in weights.layer_leaves(s, i)
+        if weights.float32_in_program(s, i, name))
+    assert (2 * n + 2 * wide) / (2 * n) < 1.01

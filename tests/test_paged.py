@@ -309,6 +309,21 @@ def test_engine_paged_zero_steady_state_compiles(net):
     eng.close()
 
 
+def test_warmup_without_prefix_index_covers_every_chunk_width(net):
+    """With no prefix index chunks start at multiples of the chunk width:
+    ``warmup()`` compiles the whole width and the one that reaches the
+    cache's end, and prompts of every kind trace nothing after it."""
+    eng = _paged_engine(net, prefix_cache=False, max_length=SMAX - PS)
+    assert eng._chunk_widths() == [CHUNK - PS, CHUNK]
+    eng.warmup()
+    telemetry.reset()
+    rng = onp.random.RandomState(9)
+    for n in (3, CHUNK, CHUNK + 1, 3 * CHUNK, SMAX - PS - 2):
+        eng.generate(_prompt(rng, n), max_new_tokens=1, timeout=300)
+    assert telemetry.counter_value("model.gpt.trace") == 0
+    eng.close()
+
+
 def test_engine_paged_prefix_hit_skips_prefill(net):
     """An exact repeat of a cached prompt admits via the peek path:
     zero prefill chunks, first token identical."""

@@ -142,13 +142,29 @@ def _as_i32(x):
     return jnp.asarray(x, jnp.int32)
 
 
-def _to_pages(a, page_size, dtype):
-    """Reshape a (1, H, C, Dh) chunk of K or V into page-pool layout
-    (C/page_size, H, page_size, Dh) for a scatter into the pool —
-    the ONE place the pool's page layout is encoded."""
-    h, c, d = a.shape[1:]
-    return a[0].reshape(h, c // page_size, page_size, d) \
-        .transpose(1, 0, 2, 3).astype(dtype)
+def _write_chunk(k_pool, v_pool, k_scale, v_scale, page_ids, k, v):
+    """Scatter a chunk's (1, H, C, Dh) K and V into pool pages
+    ``page_ids`` (C / page_size of them). ``k_scale``/``v_scale``
+    (n_pages, H) mark an INT8 pool: each written page gets its own
+    per-head amax scale. Returns the two pools and the two scale
+    tables (None where there are none). Where a page lies in a pool
+    is ``ops.attention.write_pages``' to know."""
+    if k_scale is None:
+        return (_att.write_pages(k_pool, page_ids, k),
+                _att.write_pages(v_pool, page_ids, v), None, None)
+    ps = _att.pool_page_size(k_pool)
+
+    def quantized(pool, scales, a):
+        _one, h, c, d = a.shape
+        a = a.astype(jnp.float32)
+        sc = _kv_scale(a.reshape(h, c // ps, ps, d), (2, 3))  # (H, C/ps)
+        aq = _kv_quantize(a, jnp.repeat(sc, ps, axis=1)[None, :, :, None])
+        return (_att.write_pages(pool, page_ids, aq),
+                scales.at[page_ids].set(sc.T))
+
+    kp, ksp = quantized(k_pool, k_scale, k)
+    vp, vsp = quantized(v_pool, v_scale, v)
+    return kp, vp, ksp, vsp
 
 
 def _gathered_attention(q, k_pool, v_pool, k_scale, v_scale, table,
@@ -158,7 +174,8 @@ def _gathered_attention(q, k_pool, v_pool, k_scale, v_scale, table,
     coordinates from ``start``. ``k_scale``/``v_scale`` (n_pages, H)
     mark an INT8 pool, whose every page is dequantized with the scale
     it was written under."""
-    kg, vg = _att.gather_kv(k_pool, v_pool, table, k_scale, v_scale)
+    kg, vg = _att.gather_kv(k_pool, v_pool, table, q.shape[1],
+                            k_scale, v_scale)
     if k_scale is None:
         kg, vg = kg.astype(q.dtype), vg.astype(q.dtype)
     return _att.chunked_prefill_attention(q, kg, vg, start)
@@ -369,16 +386,15 @@ class GPTBlock(HybridBlock):
                                    v_scale[page])
                 ksp = k_scale.at[page].set(ks_eff)
                 vsp = v_scale.at[page].set(vs_eff)
-                kp = k_pool.at[page, :, offset, :].set(_kv_quantize(
+                kp = _att.write_rows(k_pool, page, offset, _kv_quantize(
                     k._data[:, :, 0, :], ks_eff[:, :, None]))
-                vp = v_pool.at[page, :, offset, :].set(_kv_quantize(
+                vp = _att.write_rows(v_pool, page, offset, _kv_quantize(
                     v._data[:, :, 0, :], vs_eff[:, :, None]))
             else:
-                dt = k_pool.dtype
-                kp = k_pool.at[page, :, offset, :].set(
-                    k._data[:, :, 0, :].astype(dt))
-                vp = v_pool.at[page, :, offset, :].set(
-                    v._data[:, :, 0, :].astype(dt))
+                kp = _att.write_rows(k_pool, page, offset,
+                                     k._data[:, :, 0, :])
+                vp = _att.write_rows(v_pool, page, offset,
+                                     v._data[:, :, 0, :])
         with _scope("attn"):
             attn = NDArray(
                 _att.paged_decode_attention(q._data, kp, vp, table,
@@ -399,24 +415,10 @@ class GPTBlock(HybridBlock):
         gathered view dequantizes every page — shared-prefix pages
         included — with the scale that page was written under."""
         q, k, v = self._qkv(x)
-        ps = k_pool.shape[2]
-        ksp = vsp = None
         with _scope("kv_write"):
-            if k_scale is not None:
-                kpg = _to_pages(k._data, ps, jnp.float32)
-                vpg = _to_pages(v._data, ps, jnp.float32)
-                ks_new = _kv_scale(kpg, (2, 3))          # (C/ps, H)
-                vs_new = _kv_scale(vpg, (2, 3))
-                kp = k_pool.at[page_ids].set(
-                    _kv_quantize(kpg, ks_new[:, :, None, None]))
-                vp = v_pool.at[page_ids].set(
-                    _kv_quantize(vpg, vs_new[:, :, None, None]))
-                ksp = k_scale.at[page_ids].set(ks_new)
-                vsp = v_scale.at[page_ids].set(vs_new)
-            else:
-                dt = k_pool.dtype
-                kp = k_pool.at[page_ids].set(_to_pages(k._data, ps, dt))
-                vp = v_pool.at[page_ids].set(_to_pages(v._data, ps, dt))
+            kp, vp, ksp, vsp = _write_chunk(
+                k_pool, v_pool, k_scale, v_scale, page_ids, k._data,
+                v._data)
         with _scope("attn"):
             attn = NDArray(_gathered_attention(
                 q._data, kp, vp, ksp, vsp, pages[None], start),
@@ -455,14 +457,13 @@ class GPTBlock(HybridBlock):
                                    v_scale[page])
                 ksp = k_scale.at[page].set(ks_eff)
                 vsp = v_scale.at[page].set(vs_eff)
-                kp = k_pool.at[page, :, offset, :].set(
-                    _kv_quantize(kt, ks_eff[..., None]))
-                vp = v_pool.at[page, :, offset, :].set(
-                    _kv_quantize(vt, vs_eff[..., None]))
+                kp = _att.write_rows(k_pool, page, offset,
+                                     _kv_quantize(kt, ks_eff[..., None]))
+                vp = _att.write_rows(v_pool, page, offset,
+                                     _kv_quantize(vt, vs_eff[..., None]))
             else:
-                dt = k_pool.dtype
-                kp = k_pool.at[page, :, offset, :].set(kt.astype(dt))
-                vp = v_pool.at[page, :, offset, :].set(vt.astype(dt))
+                kp = _att.write_rows(k_pool, page, offset, kt)
+                vp = _att.write_rows(v_pool, page, offset, vt)
         with _scope("attn"):
             attn = NDArray(_gathered_attention(
                 q._data, kp, vp, ksp, vsp, table, start), ctx=x.ctx)
@@ -1161,7 +1162,7 @@ class GPTModel(HybridBlock):
         0), attend the gathered view, return (B, R, V) logits with
         ``len`` unchanged."""
         b, r = tokens.shape
-        ps = cache["k"][0].shape[2]
+        ps = _att.pool_page_size(cache["k"][0])
         s_max = cache["table"].shape[1] * ps
         quant_kv = cache["k"][0].dtype == jnp.int8
         ln = cache["len"]
@@ -1254,7 +1255,7 @@ class GPTModel(HybridBlock):
         its write is redirected into scrap page 0 and its ``len``
         stands still — which is exactly how the multi-tick scan
         freezes rows that hit eos/budget mid-scan."""
-        ps = cache["k"][0].shape[2]
+        ps = _att.pool_page_size(cache["k"][0])
         s_max = cache["table"].shape[1] * ps
         quant_kv = cache["k"][0].dtype == jnp.int8
         ln = cache["len"]
@@ -1786,10 +1787,13 @@ class GPTModel(HybridBlock):
                          max_length=None, dtype=None):
         """Preallocated PAGED KV cache: a global pool of ``n_pages``
         fixed-size pages per layer plus a static-shape page table —
-        ``{"k": tuple of L (n_pages, H, page_size, Dh) arrays, "v":
+        ``{"k": tuple of L (n_pages, page_size, H * Dh) arrays, "v":
         same, "table": (B, P_max) int32, "len": (B,) int32}`` with
-        ``P_max = max_length // page_size``. Logical position ``t`` of
-        slot ``b`` lives at ``pool[table[b, t // ps], :, t % ps]``.
+        ``P_max = max_length // page_size``. A page is ``page_size``
+        rows, a row one position's heads one after another: logical
+        position ``t`` of slot ``b`` lives at ``pool[table[b, t // ps],
+        t % ps, h * Dh:(h + 1) * Dh]`` (the layout, and why the chip
+        keeps it: ``ops/attention.py``, "the paged pool's layout").
         Page 0 is the reserved SCRAP page: free table entries point at
         it and redirected writes land in it — callers must never
         allocate it to a slot. Explicit argument/result of the paged
@@ -1806,7 +1810,7 @@ class GPTModel(HybridBlock):
         if int(n_pages) < 2:
             raise ValueError("n_pages must be >= 2 (page 0 is the "
                              "reserved scrap page)")
-        shape = (int(n_pages), self._num_heads, ps, self._head_dim)
+        shape = (int(n_pages), ps, self._num_heads * self._head_dim)
         dt = onp.dtype(dtype or self._dtype)
         zeros = lambda: tuple(jnp.zeros(shape, dt)  # noqa: E731
                               for _ in range(self._num_layers))
@@ -1842,7 +1846,7 @@ class GPTModel(HybridBlock):
             int8 pool, quantized per page with per-head amax
             scales)."""
             _b, w = tokens.shape
-            ps = cache["k"][0].shape[2]
+            ps = _att.pool_page_size(cache["k"][0])
             x = self._embed(NDArray(tokens))
             ks, vs = [], []
             for blk in blocks:
@@ -1853,40 +1857,23 @@ class GPTModel(HybridBlock):
             last = x._data[0, idx][None, None, :]
             logits = self._head(NDArray(last))
             with _scope("kv_write"):
-                dt = cache["k"][0].dtype
+                quant_kv = cache["k"][0].dtype == jnp.int8
                 page_ids = pages[:w // ps]          # start == 0: static
-                if dt == jnp.int8:
-                    kpgs = [_to_pages(k, ps, jnp.float32) for k in ks]
-                    vpgs = [_to_pages(v, ps, jnp.float32) for v in vs]
-                    kscs = [_kv_scale(p, (2, 3)) for p in kpgs]
-                    vscs = [_kv_scale(p, (2, 3)) for p in vpgs]
-                    new_cache = {
-                        "k": tuple(
-                            p.at[page_ids].set(
-                                _kv_quantize(pg, s[:, :, None, None]))
-                            for p, pg, s in zip(cache["k"], kpgs, kscs)),
-                        "v": tuple(
-                            p.at[page_ids].set(
-                                _kv_quantize(pg, s[:, :, None, None]))
-                            for p, pg, s in zip(cache["v"], vpgs, vscs)),
-                        "k_scale": tuple(
-                            p.at[page_ids].set(s)
-                            for p, s in zip(cache["k_scale"], kscs)),
-                        "v_scale": tuple(
-                            p.at[page_ids].set(s)
-                            for p, s in zip(cache["v_scale"], vscs)),
-                        "table": cache["table"].at[slot].set(pages),
-                        "len": cache["len"].at[slot].set(n_valid),
-                    }
-                else:
-                    new_cache = {
-                        "k": tuple(p.at[page_ids].set(_to_pages(k, ps, dt))
-                                   for p, k in zip(cache["k"], ks)),
-                        "v": tuple(p.at[page_ids].set(_to_pages(v, ps, dt))
-                                   for p, v in zip(cache["v"], vs)),
-                        "table": cache["table"].at[slot].set(pages),
-                        "len": cache["len"].at[slot].set(n_valid),
-                    }
+                kps, vps, kscs, vscs = zip(*(
+                    _write_chunk(
+                        cache["k"][li], cache["v"][li],
+                        cache["k_scale"][li] if quant_kv else None,
+                        cache["v_scale"][li] if quant_kv else None,
+                        page_ids, ks[li], vs[li])
+                    for li in range(len(blocks))))
+                new_cache = {
+                    "k": kps, "v": vps,
+                    "table": cache["table"].at[slot].set(pages),
+                    "len": cache["len"].at[slot].set(n_valid),
+                }
+                if quant_kv:
+                    new_cache["k_scale"] = kscs
+                    new_cache["v_scale"] = vscs
             return logits._data[:, 0, :].astype(jnp.float32), new_cache
 
         def chunk_raw(tokens, start, n_valid, slot, pages, cache):
@@ -1894,7 +1881,7 @@ class GPTModel(HybridBlock):
             global position ``start`` (a multiple of page_size;
             traced, so every chunk runs this one program)."""
             _b, c = tokens.shape
-            ps = cache["k"][0].shape[2]
+            ps = _att.pool_page_size(cache["k"][0])
             positions = start + jnp.arange(c, dtype=jnp.int32)
             pw = self.position_weight.data()._data
             x = NDArray(self.word_embed(NDArray(tokens))._data
@@ -2027,7 +2014,7 @@ class GPTModel(HybridBlock):
         if tokens.ndim != 2 or tokens.shape[0] != 1:
             raise ValueError(f"paged prefill tokens must be (1, W), "
                              f"got shape {tokens.shape}")
-        ps = cache["k"][0].shape[2]
+        ps = _att.pool_page_size(cache["k"][0])
         s_max = cache["table"].shape[1] * ps
         w = tokens.shape[1]
         if w % ps or w > s_max:

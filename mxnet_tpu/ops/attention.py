@@ -21,9 +21,11 @@ here because they shape the core design on TPU:
   everywhere; Pallas TPU kernel (scalar-prefetched lengths, KV
   streamed through VMEM) behind the same `_use_pallas()` gate.
 - `paged_decode_attention` — the same against a PAGED cache: a page
-  pool `(n_pages, H, page_size, D)` and a per-slot page table. Each
-  slot's view is gathered from the pool and attended on the jnp path,
-  on every backend: no Pallas kernel (PERF.md §6, PR 25).
+  pool `(n_pages, page_size, H * D)` (a page is `page_size` rows of
+  all heads, row-major on the chip: "the paged pool's layout" below)
+  and a per-slot page table. Each slot's view is gathered from the
+  pool and attended on the jnp path, on every backend: no Pallas
+  kernel (PERF.md §6, PRs 25 and 28).
 
 All shapes are (batch, heads, seq, head_dim). `kv_len` arguments mean
 "only the first kv_len entries of the key/value buffer are real" —
@@ -541,16 +543,50 @@ def decode_attention_pallas(q, k, v, lengths, scale=None, block_k=128,
     )(lengths.astype(jnp.int32), *operands)
 
 
-def gather_pages(pool, table):
+# -- the paged pool's layout ---------------------------------------------
+# A pool is (n_pages, page_size, H * Dh): a page is ``page_size`` rows,
+# a row one position's heads one after another (head h at columns
+# [h * Dh, (h + 1) * Dh)). The minor dimension is the model's width, so
+# on a TPU the array is row-major in whole (8, 128) tiles and a scatter
+# into it, a gather from it and a donated result all run on the layout
+# the argument came in: no paged program copies a pool. The write of
+# pages, the write of rows and the gather below are the only code that
+# knows where a position lies; everything else hands them chunks, rows
+# and tables.
+def pool_page_size(pool):
+    """Positions a page of ``pool`` holds."""
+    return pool.shape[1]
+
+
+def write_pages(pool, page_ids, chunk):
+    """Scatter a (1, H, C, Dh) chunk of K or V, C a multiple of the
+    page size, into whole pool pages ``page_ids`` (C / page_size,)."""
+    h, c, d = chunk.shape[1:]
+    ps = pool_page_size(pool)
+    return pool.at[page_ids].set(
+        chunk[0].transpose(1, 0, 2).reshape(c // ps, ps, h * d)
+        .astype(pool.dtype))
+
+
+def write_rows(pool, page, offset, rows):
+    """Scatter single positions: ``rows`` (..., H, Dh) go to row
+    ``offset`` of pool page ``page`` (both of shape ``...``: (B,) for a
+    decode tick, (B, R) for a speculative verify)."""
+    return pool.at[page, offset].set(
+        rows.reshape(*page.shape, -1).astype(pool.dtype))
+
+
+def gather_pages(pool, table, num_heads):
     """Materialize each slot's logical KV view from a paged pool:
-    ``pool`` (n_pages, H, page_size, D) + ``table`` (B, P_max) int32
+    ``pool`` (n_pages, page_size, H * D) + ``table`` (B, P_max) int32
     -> (B, H, P_max * page_size, D). Logical position ``t`` of slot
-    ``b`` lives at ``pool[table[b, t // ps], :, t % ps]``. Free table
-    entries point at the reserved scrap page (id 0) — their rows are
-    garbage that per-row length masking must exclude."""
-    g = pool[table]                       # (B, P_max, H, ps, D)
-    b, pm, h, ps, d = g.shape
-    return g.transpose(0, 2, 1, 3, 4).reshape(b, h, pm * ps, d)
+    ``b`` lives at ``pool[table[b, t // ps], t % ps, h * D:(h + 1) * D]``.
+    Free table entries point at the reserved scrap page (id 0) — their
+    rows are garbage that per-row length masking must exclude."""
+    g = pool[table]                       # (B, P_max, ps, H * D)
+    b, pm, ps, hd = g.shape
+    return g.reshape(b, pm * ps, num_heads, hd // num_heads) \
+        .transpose(0, 2, 1, 3)
 
 
 def expand_page_scales(pool_scale, table, page_size):
@@ -563,13 +599,15 @@ def expand_page_scales(pool_scale, table, page_size):
     return jnp.repeat(g.transpose(0, 2, 1), page_size, axis=2)
 
 
-def gather_kv(k_pool, v_pool, table, k_scale=None, v_scale=None):
+def gather_kv(k_pool, v_pool, table, num_heads, k_scale=None,
+              v_scale=None):
     """Both pools' gathered views (``gather_pages``). ``k_scale``/
     ``v_scale`` (n_pages, H) mark an INT8 pool: its views come back
     fp32, every page dequantized with the scale it was written under."""
-    k, v = gather_pages(k_pool, table), gather_pages(v_pool, table)
+    k = gather_pages(k_pool, table, num_heads)
+    v = gather_pages(v_pool, table, num_heads)
     if k_scale is not None:
-        ps = k_pool.shape[2]
+        ps = pool_page_size(k_pool)
         k = k.astype(jnp.float32) \
             * expand_page_scales(k_scale, table, ps)[..., None]
         v = v.astype(jnp.float32) \
@@ -582,7 +620,7 @@ def paged_decode_attention(q, k_pool, v_pool, table, lengths,
     """Decode attention against a PAGED KV cache.
 
     ``q`` is (B, H, Sq, D); ``k_pool``/``v_pool`` are the global page
-    pools (n_pages, H, page_size, D); ``table`` (B, P_max) int32 maps
+    pools (n_pages, page_size, H * D); ``table`` (B, P_max) int32 maps
     each slot's logical page index to a physical pool page; ``lengths``
     (B,) int32 marks each slot's valid token prefix: every query row
     attends keys ``[0, length)`` (``Sq > 1`` is a speculative verify).
@@ -593,18 +631,23 @@ def paged_decode_attention(q, k_pool, v_pool, table, lengths,
     view (gather + the same masked softmax), on every backend, so a
     paged cache holding the same values produces bit-identical logits
     to the dense cache on that path. The gather moves all ``P_max``
-    pages of every slot whatever its length, and reads them from the
-    pool in whatever layout the pool's write left. A Pallas kernel has
-    to be handed the pools row-major, and that whole-pool copy of K and
-    of V a layer cost a GPT-2 large tick more than a kernel moving only
-    held pages saved (PERF.md §6, PR 25).
+    pages of every slot whatever its length; a page is one contiguous
+    block of ``page_size`` rows of all heads, read from the pool as the
+    write left it: row-major, with no copy of a pool on either side
+    (tests/test_chip_compile.py counts them). PR 25's Pallas kernel,
+    which moved only held pages, lost to this path because the pool
+    then was ``(n_pages, H, page_size, D)``, which the TPU keeps pages
+    minor-most, and a kernel's operand has to be row-major: a copy of
+    each whole pool a layer cost more than the kernel saved (PERF.md
+    §6). This pool is row-major already, so that reason is gone; a
+    kernel is an issue of its own (ROADMAP S4).
 
     ``k_scale``/``v_scale`` (n_pages, H) fp32 mark an INT8 pool (half
     the HBM per cached token vs bf16, a quarter vs fp32), dequantized
     after the gather with each page's per-head scale."""
     scale_v = scale if scale is not None else 1.0 / math.sqrt(q.shape[-1])
     k, v = gather_kv(k_pool, v_pool, jnp.asarray(table, jnp.int32),
-                     k_scale, v_scale)
+                     q.shape[1], k_scale, v_scale)
     return _decode_fwd_jnp(q, k, v, jnp.asarray(lengths, jnp.int32),
                            scale_v)
 

@@ -327,20 +327,25 @@ class Partitioner:
         return specs
 
     # -- KV-cache placement (serving TP) -------------------------------
-    def cache_spec(self, shape, num_heads) -> PartitionSpec:
-        """Spec for one KV-cache leaf: shard the heads axis (the dim
-        equal to ``num_heads`` at position 1 — dense caches are
-        ``(B, H, S, Dh)``, paged pools ``(n_pages, H, ps, Dh)``, scale
-        tables ``(B|n_pages, H)``) over the axis the ``"heads"`` rule
-        names; everything else (tables, lengths) replicated."""
+    def cache_spec(self, shape, num_heads, heads_dim=1) -> PartitionSpec:
+        """Spec for one KV-cache leaf: shard dimension ``heads_dim``,
+        the one the heads lie along, over the axis the ``"heads"``
+        rule names. That is dimension 1 of a dense cache ``(B, H, S,
+        Dh)`` and of a scale table ``(B|n_pages, H)``, and the LAST of
+        a paged pool ``(n_pages, ps, H * Dh)``, whose rows hold the
+        heads one after another, so an even split gives each device
+        a contiguous block of ``H / tp`` whole heads. The caller says
+        which (``cache_shardings`` knows a paged cache by its pytree):
+        a pool's dimension 1 is its page size, which may well equal
+        ``num_heads``."""
         tp_axis = next((a for l, a in self.rules
                         if l == "heads" and a is not None), None)
         if tp_axis is None or _axis_size(self.mesh, tp_axis) <= 1:
             return P()
-        if len(shape) >= 2 and int(shape[1]) == int(num_heads) \
+        if len(shape) >= 2 and int(shape[heads_dim]) % int(num_heads) == 0 \
                 and int(num_heads) % _axis_size(self.mesh, tp_axis) == 0:
             entries = [None] * len(shape)
-            entries[1] = tp_axis
+            entries[heads_dim] = tp_axis
             return P(*entries)
         return P()
 
@@ -356,15 +361,20 @@ class Partitioner:
         pytree (``init_cache``/``init_paged_cache`` layout): K/V
         buffers (and their int8 scale tables) shard over the heads
         axis; the page table and lengths replicate — keyed by the
-        pytree path, not by shape coincidence."""
+        pytree path, not by shape coincidence. A ``table`` key marks
+        a PAGED cache, whose ``k``/``v`` pools carry their heads in
+        the last dimension."""
         mesh = self.mesh
         rep = NamedSharding(mesh, P())
+        paged = "table" in cache
 
         def leaf_sh(path, leaf):
             keys = {getattr(p, "key", None) for p in path}
             if keys & self._CACHE_SHARDED_KEYS:
-                return NamedSharding(
-                    mesh, self.cache_spec(tuple(leaf.shape), num_heads))
+                pool = paged and bool(keys & {"k", "v"})
+                return NamedSharding(mesh, self.cache_spec(
+                    tuple(leaf.shape), num_heads,
+                    heads_dim=-1 if pool else 1))
             return rep
 
         return jax.tree_util.tree_map_with_path(leaf_sh, cache)

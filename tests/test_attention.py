@@ -256,14 +256,15 @@ def test_paged_decode_attention_matches_gathered_reference():
     P_MAX = 8                                      # capacity 128
     mk = lambda *s: jnp.asarray(  # noqa: E731
         onp.random.randn(*s).astype("float32") * 0.5)
-    kpool, vpool = mk(NP, H, PS, D), mk(NP, H, PS, D)
+    kpool, vpool = mk(NP, PS, H * D), mk(NP, PS, H * D)
     rng = onp.random.RandomState(7)
     table = jnp.asarray(rng.permutation(onp.arange(1, NP))
                         [:B * P_MAX].reshape(B, P_MAX).astype("i4"))
     lengths = jnp.asarray([0, 1, 77, 128], jnp.int32)
     q = mk(B, H, 1, D)
-    kg = at.gather_pages(kpool, table)
-    vg = at.gather_pages(vpool, table)
+    kg = at.gather_pages(kpool, table, H)
+    vg = at.gather_pages(vpool, table, H)
+    assert kg.shape == (B, H, P_MAX * PS, D)
     ref = at.decode_attention(q, kg, vg, lengths)
     out = at.paged_decode_attention(q, kpool, vpool, table, lengths)
     assert (onp.asarray(out) == onp.asarray(ref)).all()
@@ -288,7 +289,7 @@ def _paged_case(h, sq, dtype, seed=8, ps=16, d=64, p_max=8):
     b = len(lengths)
     n_pages = 1 + b * p_max
     mk = lambda *sh: (rng.randn(*sh) * 0.5).astype("f4")  # noqa: E731
-    kpool, vpool = mk(n_pages, h, ps, d), mk(n_pages, h, ps, d)
+    kpool, vpool = mk(n_pages, ps, h * d), mk(n_pages, ps, h * d)
     free = list(rng.permutation(onp.arange(1, n_pages)))
     table = onp.zeros((b, p_max), "i4")
     for i, n in enumerate(lengths):
@@ -299,7 +300,7 @@ def _paged_case(h, sq, dtype, seed=8, ps=16, d=64, p_max=8):
         pool[0] = junk                             # the scrap page
         for i, n in enumerate(lengths):
             if n % ps and i != 4:   # slot 4's pages are slot 6's too
-                pool[table[i, n // ps], :, n % ps:] = junk
+                pool[table[i, n // ps], n % ps:] = junk
     q = mk(b, h, sq, d)
     cast = lambda x: jnp.asarray(x).astype(dtype)  # noqa: E731
     return (cast(q), cast(kpool), cast(vpool), jnp.asarray(table),
@@ -309,22 +310,26 @@ def _paged_case(h, sq, dtype, seed=8, ps=16, d=64, p_max=8):
 def _paged_reference(q, kpool, vpool, table, lengths, k_scale=None,
                      v_scale=None):
     """Plain numpy, slot by slot: the slot's pages in table order, cut
-    to its length, softmax in float64. An int8 pool is dequantized page
-    by page first."""
-    q, kpool, vpool = (onp.asarray(x, "f8") for x in (q, kpool, vpool))
+    to its length, softmax in float64. A pool is (n_pages, page_size,
+    H * D), a row one position's heads one after another; an int8 pool
+    is dequantized page by page first."""
+    q = onp.asarray(q, "f8")
+    h, d = q.shape[1], q.shape[3]
+    kpool, vpool = (onp.asarray(x, "f8").reshape(*x.shape[:2], h, d)
+                    for x in (kpool, vpool))
     if k_scale is not None:
-        kpool = kpool * onp.asarray(k_scale, "f8")[:, :, None, None]
-        vpool = vpool * onp.asarray(v_scale, "f8")[:, :, None, None]
+        kpool = kpool * onp.asarray(k_scale, "f8")[:, None, :, None]
+        vpool = vpool * onp.asarray(v_scale, "f8")[:, None, :, None]
     table, lengths = onp.asarray(table), onp.asarray(lengths)
     out = onp.zeros(q.shape)
     for i, n in enumerate(lengths):
         if n == 0:
             continue
-        k = onp.concatenate(list(kpool[table[i]]), axis=1)[:, :n]
-        v = onp.concatenate(list(vpool[table[i]]), axis=1)[:, :n]
-        s = onp.einsum("hqd,hkd->hqk", q[i], k) / onp.sqrt(q.shape[-1])
+        k = onp.concatenate(list(kpool[table[i]]), axis=0)[:n]
+        v = onp.concatenate(list(vpool[table[i]]), axis=0)[:n]
+        s = onp.einsum("hqd,khd->hqk", q[i], k) / onp.sqrt(d)
         p = onp.exp(s - s.max(axis=-1, keepdims=True))
-        out[i] = onp.einsum("hqk,hkd->hqd",
+        out[i] = onp.einsum("hqk,khd->hqd",
                             p / p.sum(axis=-1, keepdims=True), v)
     return out
 

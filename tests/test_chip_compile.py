@@ -1,7 +1,10 @@
 """The main path's Pallas kernels, and paged decode attention (which
 has none), compiled for a DESCRIBED v5e at GPT-2 large shapes (20
 heads x 64, 1280 units, MLP 5120, vocabulary 50257, 1024 positions,
-page 16, 8 slots).
+page 16, 8 slots); and the paged decode and chunk PROGRAMS of a
+two-layer ``GPTModel`` at GPT-2 large and medium widths, whose text
+says which layout the chip gives the KV pools and whether a program
+copies one.
 
 Interpret-mode parity tests cannot see what the chip's compiler
 refuses: a block shape off the (8, 128) tiling, a scalar operand in
@@ -15,6 +18,8 @@ import, in a skipif or in parametrize: only one process may hold the
 TPU library, and every xdist worker imports this file. All such tests
 stay in this one file for the same reason.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -104,7 +109,7 @@ def test_paged_decode(one_chip, no_compile_cache, kind, sq, heads):
     the compiler's own gather + masked softmax: no kernel of ours is
     in the program."""
     qdt = jnp.float32 if kind == "int8" else _KV[kind]
-    pool = ((N_PAGES, heads, PAGE, D), _KV[kind])
+    pool = ((N_PAGES, PAGE, heads * D), _KV[kind])
 
     def fn(q, k, v, table, lens, *sc):
         return att.paged_decode_attention(
@@ -114,6 +119,78 @@ def test_paged_decode(one_chip, no_compile_cache, kind, sq, heads):
     _compile(fn, one_chip, ((B, heads, sq, D), qdt), pool, pool,
              ((B, P_MAX), jnp.int32), ((B,), jnp.int32),
              *_scales(kind, (N_PAGES, heads)), kernel=False)
+
+
+# -- the paged programs themselves: where the pools lie ------------------
+_HLO_DTYPE = {"bf16": "bf16", "fp32": "f32", "int8": "s8"}
+CHUNK = 32
+
+
+def _paged_program(sharding, heads, kind, role):
+    """``gpt_paged_<role>`` of a two-layer GPTModel ``heads`` x 64 wide
+    as the benchmark's engine runs it (bf16 compute, page 16, 8 slots,
+    1024 positions, chunks of 32; a small vocabulary, which no pool
+    sees), compiled for the described chip. Returns the compiled text
+    and the pool's shape as that text prints it."""
+    from mxnet_tpu.gluon.model_zoo.gpt import GPTModel
+    from mxnet_tpu.random_state import next_key
+    net = GPTModel(512, units=heads * D, num_layers=2, num_heads=heads,
+                   max_length=S_MAX)
+    net.initialize()
+    net.cast_compute_params("bfloat16")
+    cache = net.init_paged_cache(B, N_PAGES, PAGE, S_MAX,
+                                 dtype=_KV[kind])
+    progs = net._ensure_paged()
+    i32 = lambda *shape: jnp.zeros(shape, jnp.int32)  # noqa: E731
+    rows, args = {
+        "decode": (B, (i32(B), i32(B))),
+        "chunk": (1, (i32(1, CHUNK), i32(), i32(), i32(), i32(P_MAX))),
+    }[role]
+    args = (next_key(), net._param_call_datas(progs["params"]),
+            net._quant_arg(), net._lora_arg(), net._lora_idx(None, rows),
+            *args, cache)
+    avals = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                       sharding=sharding), args)
+    text = progs[role].lower(*avals).compile().as_text()
+    pool = cache["k"][0]
+    return text, "%s[%s]" % (_HLO_DTYPE[kind],
+                             ",".join(map(str, pool.shape)))
+
+
+@pytest.mark.parametrize("role", ["decode", "chunk"])
+@pytest.mark.parametrize("heads", [H, 16], ids=["large", "medium"])
+def test_paged_program_copies_no_pool(one_chip, no_compile_cache, heads,
+                                      role):
+    """The chip keeps a bf16 pool ``(n_pages, page_size, H * 64)``
+    row-major (its rows are whole (8, 128) tiles), so the scatter into
+    it, the gather from it and the donated result all run on the layout
+    the argument came in: the program holds no ``copy`` of a pool's
+    shape. On ``(n_pages, H, page_size, 64)`` the same programs held
+    four a layer, two thirds of a decode tick on the chip (PERF.md §6,
+    PR 28). Metadata of a compile, not a chip reading."""
+    text, pool = _paged_program(one_chip, heads, "bf16", role)
+    layouts = set(re.findall(re.escape(pool) + r"\{([\d,]*)", text))
+    assert layouts == {"2,1,0"}, layouts
+    # the four pools are parameters of the entry and alias its results
+    entry = text[text.index("ENTRY "):]
+    assert len(re.findall(re.escape(pool) + r"\S* parameter\(", entry)) \
+        == 4
+    assert text.count("may-alias") >= 4
+    copies = re.findall(r"= (\w+\[[\d,]*\])\S* copy\(", text)
+    assert pool not in copies, copies
+
+
+@pytest.mark.parametrize("role", ["decode", "chunk"])
+@pytest.mark.parametrize("kind", ["fp32", "int8"])
+@pytest.mark.parametrize("heads", [H, 16], ids=["large", "medium"])
+def test_paged_program_compiles_for_other_pools(one_chip,
+                                                no_compile_cache, heads,
+                                                kind, role):
+    """fp32 and int8 pools (an int8 tile is 32 rows, a page has 16)
+    compile; what their text shows is in PERF.md §7."""
+    text, pool = _paged_program(one_chip, heads, kind, role)
+    assert pool in text
 
 
 @pytest.mark.parametrize("n,k", [(1280, 1280), (5120, 1280),

@@ -30,10 +30,10 @@ pytestmark = pytest.mark.requires_mesh(8)
 VOCAB, UNITS, LAYERS, HEADS, SMAX = 64, 32, 2, 4, 64
 
 
-def _gpt(seed=0, layers=LAYERS, tied=True):
+def _gpt(seed=0, layers=LAYERS, tied=True, heads=HEADS):
     mx.np.random.seed(seed)
     net = GPTModel(vocab_size=VOCAB, units=UNITS, num_layers=layers,
-                   num_heads=HEADS, max_length=SMAX)
+                   num_heads=heads, max_length=SMAX)
     net.initialize(mx.init.Xavier())
     if tied:
         # tied lm_head: peaky logits so the tp partial-sum noise
@@ -77,7 +77,7 @@ def _lora_params(seed=7, rank=2):
 
 
 def _engine(tp=False, paged=False, quant=False, spec=False,
-            lora=False, **kw):
+            lora=False, heads=HEADS, **kw):
     mesh = _mesh24() if tp else None
     if paged:
         kw.setdefault("page_size", 8)
@@ -91,7 +91,8 @@ def _engine(tp=False, paged=False, quant=False, spec=False,
         kw.update(lora_rank=2, max_adapters=2)
     if tp:
         kw.update(mesh_layout="tp", mesh=mesh)
-    return GenerationEngine(_gpt(), max_slots=4, max_length=SMAX,
+    return GenerationEngine(_gpt(heads=heads), max_slots=4,
+                            max_length=SMAX,
                             max_new_tokens=10, **kw)
 
 
@@ -272,15 +273,19 @@ def test_tp_fsdp_per_device_bytes_below_both_1d_layouts(mesh_devices):
 # paged-pool sharding round-trip
 # ---------------------------------------------------------------------------
 
-def test_paged_pool_sharding_round_trip(mesh_devices):
+@pytest.mark.parametrize("page_size", [8, HEADS],
+                         ids=["pmax-eq-heads", "page-eq-heads"])
+def test_paged_pool_sharding_round_trip(mesh_devices, page_size):
     """Shard a paged pool over the heads axis, gather it back to host:
     bitwise equal to the unsharded pool; the page table and lengths
     stay replicated — by pytree KEY, even when the table's P_max dim
-    numerically equals num_heads."""
+    numerically equals num_heads; and the pool shards its last
+    dimension even when its page size does."""
     mesh = _mesh24()
     net = _gpt()
-    # P_max == num_heads == 4 on purpose: 32 / 8 = 4 logical pages
-    cache = net.init_paged_cache(2, 12, 8, 32, dtype="int8")
+    # P_max == num_heads == 4 on purpose: 32 / 8 = 4 logical pages;
+    # or page_size == num_heads == 4
+    cache = net.init_paged_cache(2, 12, page_size, 32, dtype="int8")
     rng = onp.random.RandomState(9)
     filled = {
         "k": tuple(rng.randint(-127, 127, c.shape).astype("i1")
@@ -294,10 +299,13 @@ def test_paged_pool_sharding_round_trip(mesh_devices):
         "table": rng.randint(0, 12, cache["table"].shape).astype("i4"),
         "len": rng.randint(0, 32, cache["len"].shape).astype("i4"),
     }
-    assert filled["table"].shape[1] == HEADS  # the coincidence trap
+    # the coincidence traps
+    assert HEADS in (filled["table"].shape[1], filled["k"][0].shape[1])
     part = partition.Partitioner("tp", mesh=mesh)
     placed = part.place_cache(filled, HEADS)
-    assert placed["k"][0].sharding.spec == P(None, "tp", None, None)
+    # a pool's rows hold the heads one after another: the LAST
+    # dimension shards, into contiguous blocks of H / tp whole heads
+    assert placed["k"][0].sharding.spec == P(None, None, "tp")
     assert placed["k_scale"][0].sharding.spec == P(None, "tp")
     assert placed["table"].sharding.spec == P()
     assert placed["len"].sharding.spec == P()
@@ -319,19 +327,27 @@ def test_paged_pool_sharding_round_trip(mesh_devices):
 # composed TP serving: token identity + zero steady-state compiles
 # ---------------------------------------------------------------------------
 
-def test_tp_paged_engine_token_identity():
+@pytest.mark.parametrize("heads,page_size", [(HEADS, 8), (16, 16)],
+                         ids=["h4-ps8", "h16-ps16"])
+def test_tp_paged_engine_token_identity(heads, page_size):
     """mesh_layout="tp" + paged: greedy output token-identical to the
-    single-device paged engine; the pool shards by heads (per-device
-    KV-pool bytes = full / tp); steady state traces nothing."""
+    single-device paged engine; the pool shards by heads, its last
+    dimension (per-device KV-pool bytes = full / tp); steady state
+    traces nothing. With ``page_size == num_heads`` (16 and 16, GPT-2
+    medium's) a pool's dimension 1 LOOKS like a heads axis: the pool
+    is told apart by the cache pytree, never by that coincidence, and
+    a page's rows stay whole."""
     prompts = _prompts()
-    ref = _engine(paged=True)
+    ref = _engine(paged=True, heads=heads, page_size=page_size)
     want = _serve(ref, prompts)
     ref.close()
-    eng = _engine(tp=True, paged=True).warmup()
+    eng = _engine(tp=True, paged=True, heads=heads,
+                  page_size=page_size).warmup()
     try:
-        assert eng._cache["k"][0].sharding.spec \
-            == P(None, "tp", None, None)
+        assert eng._cache["k"][0].shape[1:] == (page_size, UNITS)
+        assert eng._cache["k"][0].sharding.spec == P(None, None, "tp")
         assert eng._cache["table"].sharding.spec == P()
+        assert eng._cache["len"].sharding.spec == P()
         got = _serve(eng, prompts[:4])
         telemetry.reset()
         got += _serve(eng, prompts[4:])

@@ -192,6 +192,58 @@ def test_paged_fresh_prefill_bitwise_matches_dense(net):
         tok = int(onp.asarray(lgd)[2].argmax())
 
 
+@pytest.mark.parametrize("write", ["fresh", "chunk"])
+def test_paged_pool_holds_each_position_where_the_layout_says(net, write):
+    """A pool is (n_pages, page_size, H * Dh): position ``t`` of slot
+    ``b``, head ``h``, lives at ``pool[table[b, t // ps], t % ps,
+    h * Dh:(h + 1) * Dh]`` — held against the dense cache's
+    ``k[b, h, t]`` after a prefill (one fresh chunk: bitwise; two
+    chunks over the gathered view: to rounding) and four decode
+    steps, for K and V of every layer."""
+    rng = onp.random.RandomState(7)
+    n, slot, heads = 21, 2, 4
+    prompt = _prompt(rng, n)
+    width = 32 if write == "fresh" else CHUNK
+    dense = net.init_cache(SLOTS, SMAX)
+    padded = onp.zeros((1, 32), "i4")
+    padded[0, :n] = prompt
+    lg, dense = net.prefill(padded, [n], dense, slots=[slot])
+    paged = net.init_paged_cache(SLOTS, N_PAGES, PS, SMAX)
+    assert paged["k"][0].shape == (N_PAGES, PS, 32)
+    row = onp.zeros(SMAX // PS, "i4")
+    row[:5] = [9, 3, 17, 4, 12]
+    for pos in range(0, n, width):
+        nv = min(width, n - pos)
+        chunk = onp.zeros((1, width), "i4")
+        chunk[0, :nv] = prompt[pos:pos + nv]
+        _lg, paged = net.prefill_paged(chunk, nv, slot, row, paged,
+                                       start=pos, fresh=write == "fresh")
+    tok = int(onp.asarray(lg)[0].argmax())
+    active = onp.zeros(SLOTS, "i4")
+    active[slot] = 1
+    for _ in range(4):
+        step = onp.zeros((SLOTS,), "i4")
+        step[slot] = tok
+        lgd, dense = net.decode_step(step, dense)
+        _lgp, paged = net.decode_step_paged(step, active, paged)
+        tok = int(onp.asarray(lgd)[slot].argmax())
+    length = n + 4
+    assert int(onp.asarray(paged["len"])[slot]) == length
+    table = onp.asarray(paged["table"])
+    for key in ("k", "v"):
+        for pool, cache in zip(paged[key], dense[key]):
+            pool, cache = onp.asarray(pool), onp.asarray(cache)
+            dh = pool.shape[2] // heads
+            for t in range(length):
+                got = pool[table[slot, t // PS], t % PS] \
+                    .reshape(heads, dh)
+                if write == "fresh":
+                    assert (got == cache[slot, :, t]).all(), (key, t)
+                else:
+                    onp.testing.assert_allclose(
+                        got, cache[slot, :, t], rtol=2e-3, atol=2e-4)
+
+
 def test_chunked_prefill_and_peek_match_full_forward(net):
     """Multi-chunk prefill reproduces the full causal forward's
     last-token logits, and peek (prefix-hit path) reproduces the last

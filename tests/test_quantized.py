@@ -173,8 +173,10 @@ def test_int8_kv_decode_attention_pallas_parity():
     # paged: scatter the same rows into a pool with per-page scales
     ps, pm = 8, S // 8
     npages = 1 + B * pm
-    pool_k = onp.zeros((npages, H, ps, D), "i1")
-    pool_v = onp.zeros((npages, H, ps, D), "i1")
+    # a page is ps rows of H * D: head h at columns [h * D, (h + 1) * D)
+    pool_k = onp.zeros((npages, ps, H * D), "i1")
+    pool_v = onp.zeros((npages, ps, H * D), "i1")
+    rows = lambda x: x.transpose(1, 0, 2).reshape(ps, H * D)  # noqa: E731
     sc_k = onp.zeros((npages, H), "f4")
     sc_v = onp.zeros((npages, H), "f4")
     table = onp.zeros((B, pm), "i4")
@@ -187,10 +189,10 @@ def test_int8_kv_decode_attention_pallas_parity():
                              1e-12) / 127.0
             sv = onp.maximum(onp.abs(seg_v).max(axis=(1, 2)),
                              1e-12) / 127.0
-            pool_k[pid] = onp.clip(onp.round(seg_k / sk[:, None, None]),
-                                   -127, 127)
-            pool_v[pid] = onp.clip(onp.round(seg_v / sv[:, None, None]),
-                                   -127, 127)
+            pool_k[pid] = rows(onp.clip(
+                onp.round(seg_k / sk[:, None, None]), -127, 127))
+            pool_v[pid] = rows(onp.clip(
+                onp.round(seg_v / sv[:, None, None]), -127, 127))
             sc_k[pid], sc_v[pid] = sk, sv
             table[b, p] = pid
             pid += 1
@@ -225,12 +227,15 @@ def test_int8_paged_decode_attention_parity(heads, sq):
         table[i, :held] = [free.pop() for _ in range(held)]
     table[6, :2] = table[4, :2]                    # a shared prefix
     q = rng.randn(b, heads, sq, d).astype("f4")
+    # (n_pages, H, ps, D) -> the pool's (n_pages, ps, H * D)
+    pool = lambda x: x.transpose(0, 2, 1, 3).reshape(  # noqa: E731
+        n_pages, ps, heads * d)
     ref = onp.asarray(att.paged_decode_attention(
-        q, kq * ks[:, :, None, None], vq * vs[:, :, None, None], table,
-        lengths))
+        q, pool(kq * ks[:, :, None, None]),
+        pool(vq * vs[:, :, None, None]), table, lengths))
     ks[0], vs[0] = onp.nan, 1e4
     out = onp.asarray(att.paged_decode_attention(
-        q, kq, vq, table, lengths, k_scale=ks, v_scale=vs))
+        q, pool(kq), pool(vq), table, lengths, k_scale=ks, v_scale=vs))
     assert (out[0] == 0).all()                     # the empty slot
     onp.testing.assert_allclose(out, ref, rtol=2e-4, atol=2e-5)
 

@@ -11,7 +11,7 @@ or generic trial), executed with warmup, then timed with engine sync so
 async dispatch can't hide execution time.
 
 Usage:
-    python benchmark/opperf.py [--output OPPERF_r3.json] [--runs 10]
+    python benchmark/opperf.py [--output <file>.json] [--runs 10]
         [--warmup 2] [--platform cpu|tpu] [--filter SUBSTR]
 
 Output JSON:

@@ -21,9 +21,9 @@ real checkpoint stack:
   .load_weights``).
 
 See docs/CHECKPOINT.md for the on-disk layout, atomicity and
-retention rules, resume semantics, and the serving rollover story;
-``bench.py --checkpoint`` (BENCH_r10.json) for the measured
-async-vs-sync training-step stall.
+retention rules, resume semantics, and the serving rollover story.
+The stall a save costs a training step is not measured on the chip
+(no cell saves: PERF.md section 7).
 """
 from __future__ import annotations
 

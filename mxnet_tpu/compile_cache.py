@@ -10,7 +10,7 @@ re-running the compiler. The directory is placed from OUTSIDE:
   cache lives there and nothing in this repo points it elsewhere;
   `configure()` at package import only adopts it.
 - unset — the library runs without a persistent cache; the repo's
-  entry scripts (``chip_smoke.py``, ``bench.py``) pass
+  entry scripts (``chip_smoke.py``, ``chipbench/run.py``) pass
   ``CHECKOUT_DIR``, a fixed git-ignored directory inside the checkout
   (the path is part of the cache key, so it never moves).
 

@@ -860,10 +860,6 @@ class GPTModel(HybridBlock):
              for name, bank in tab.items()} for tab in self._lora]
         return self
 
-    def lora_bank_bytes(self) -> int:
-        """HBM bytes of the armed adapter banks (0 when unarmed)."""
-        return _lora.bank_bytes(self._lora) if self._lora else 0
-
     def _lora_arg(self):
         """The LoRA-bank runtime argument every closure call carries:
         the live banks, or an empty pytree for unarmed models (a

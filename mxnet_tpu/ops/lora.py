@@ -28,9 +28,9 @@ tenant).
 
 The ``ops.lora.trace`` telemetry counter increments only when a
 LoRA-bearing closure actually TRACES (this module's ``apply`` runs at
-trace time only) — the bank analog of ``model.gpt.trace``, used by
-tests and ``bench.py --lora`` to prove adapter load/unload/refresh
-causes zero retraces.
+trace time only) — the bank analog of ``model.gpt.trace``, which
+tests/test_lora.py holds flat across adapter load, refresh and
+unload.
 """
 from __future__ import annotations
 
@@ -38,8 +38,7 @@ import jax.numpy as jnp
 
 from .. import telemetry, tracing
 
-__all__ = ["init_bank", "set_slot", "clear_slot", "apply",
-           "bank_bytes"]
+__all__ = ["init_bank", "set_slot", "clear_slot", "apply"]
 
 
 def init_bank(n_adapters, d_in, d_out, rank):
@@ -125,15 +124,3 @@ def apply(y, x, bank, idx):
     lo = jnp.einsum("bsd,bdr->bsr", jnp.asarray(x, jnp.float32), a)
     delta = jnp.einsum("bsr,bro->bso", lo, b) * s[:, None, None]
     return y + delta
-
-
-def bank_bytes(banks):
-    """Total HBM bytes of a model's adapter banks (an iterable of
-    per-block ``{proj: bank}`` dicts) — the numerator of the
-    tenants-per-HBM-byte consolidation story (``bench.py --lora``)."""
-    total = 0
-    for tab in banks:
-        for bank in tab.values():
-            total += sum(int(v.size) * v.dtype.itemsize
-                         for v in bank.values())
-    return total

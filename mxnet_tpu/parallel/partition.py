@@ -52,9 +52,9 @@ Built-in layouts:
   backend).
 
 Per-device footprint is MEASURED, not modeled: ``per_device_bytes``
-walks real ``jax.Array`` shards, so the bench gate "this model's
-param+optimizer footprint exceeds one device's share" is checked
-against what the runtime actually placed.
+walks real ``jax.Array`` shards, so "this model's param+optimizer
+footprint exceeds one device's share" is checked against what the
+runtime actually placed (tests/test_partition.py).
 """
 from __future__ import annotations
 

@@ -8,8 +8,8 @@ XLA's latency-hiding scheduler overlaps the gradient all-reduce over the
 'dp' mesh axis with remaining backward compute, and buffer donation
 makes the parameter/optimizer-state update fully in-place.
 
-This is the throughput path used by bench.py and the multi-chip
-dryrun; the imperative Trainer path (gluon/trainer.py) remains for
+This is the throughput path (the one the benchmark's training cell
+and the multi-chip dryrun run); the imperative Trainer path (gluon/trainer.py) remains for
 step-by-step parity with the reference's
 `autograd.record → backward → trainer.step` flow.
 """
@@ -492,9 +492,9 @@ class TrainStep:
                 if not _placed_as(d, frozen_sh[j]):
                     frozen_nds[j]._data = jax.device_put(d, frozen_sh[j])
             # layout accounting: the analytic grad-sync wire bytes of
-            # the resolved layout (the bench A/B's comm metric) and the
-            # MEASURED per-device param+optimizer footprint (the "fits
-            # one device's share of HBM" gate walks real shards)
+            # the resolved layout and the MEASURED per-device
+            # param+optimizer footprint (walks real shards;
+            # tests/test_partition.py holds fsdp under dp in both)
             from . import partition as _partition
             spec_map = {names[i]: diff_sh[k].spec
                         for k, i in enumerate(diff_idx)}
@@ -908,12 +908,13 @@ class TrainStep:
     # -- introspection -------------------------------------------------
     def compiled_hlo(self, data, label, optimized=True):
         """Compiled HLO text of the entry serving this batch signature
-        — the bench's structural-evidence hook: ``bench.py --shard``
-        feeds it to ``partition.hlo_collectives`` to show the fsdp
-        program really contains the per-layer all-gathers (and the dp
-        program contains none). Build the entry (run one step) first;
-        this lowers/compiles a fresh executable for inspection, so
-        call it OUTSIDE any timed window.
+        — the structural-evidence hook: fed to
+        ``partition.hlo_collectives`` it shows that the fsdp program
+        really contains the per-layer all-gathers and the dp program
+        none (tests/test_partition.py; ``chip_smoke.py --chips 4``).
+        Build the entry (run one step) first; this lowers/compiles a
+        fresh executable for inspection, so call it OUTSIDE any timed
+        window.
 
         ``optimized=False`` returns the LOWERED (pre-optimization)
         StableHLO instead — the hook for asserting program STRUCTURE

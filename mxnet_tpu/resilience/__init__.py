@@ -21,8 +21,8 @@ hangs:
   injection, slow-step, kill-mid-checkpoint (faults.py).
 
 Telemetry lands under ``resilience.*`` (docs/OBSERVABILITY.md);
-``bench.py --resilience`` chaos-proves the whole stack
-(BENCH_r12.json); docs/RESILIENCE.md is the narrative.
+tests/test_resilience.py chaos-proves the whole stack;
+docs/RESILIENCE.md is the narrative.
 """
 from __future__ import annotations
 

@@ -144,8 +144,8 @@ class TrainFaultInjector:
 
     @classmethod
     def from_spec(cls, spec: str, seed: int = 0):
-        """Build an injector from a compact schedule string — the
-        bench harness's per-attempt fault plan, e.g.
+        """Build an injector from a compact schedule string — a
+        respawn loop's per-attempt fault plan, e.g.
         ``"kill@27;nan_batch@32;kill_mid_save@45;preempt@51"``. Each
         entry is ``kind@N`` with ``N`` applied to the kind's natural
         key (step for crash/kill/preempt/slow, batch index for

@@ -27,10 +27,10 @@ module makes long runs actually *survive* the three real killers:
 
 Everything is observable under ``resilience.*``
 (docs/OBSERVABILITY.md) and chaos-provable through
-:class:`~mxnet_tpu.resilience.TrainFaultInjector`;
-``bench.py --resilience`` kills the run repeatedly and demands the
-final parameters bitwise-match an uninterrupted control run at >= 90%
-goodput (BENCH_r12.json, docs/RESILIENCE.md).
+:class:`~mxnet_tpu.resilience.TrainFaultInjector`:
+tests/test_resilience.py kills the run and demands that the final
+parameters bitwise-match an uninterrupted control run, with goodput
+counted over every step executed (docs/RESILIENCE.md).
 """
 from __future__ import annotations
 
@@ -50,7 +50,7 @@ __all__ = ["TrainSupervisor", "TrainingAborted"]
 class TrainingAborted(RuntimeError):
     """The in-process restart budget is exhausted; the last failure is
     the ``__cause__``. At this point the process-level supervisor
-    (cluster scheduler, bench harness respawn loop) takes over — the
+    (cluster scheduler, a respawn loop) takes over — the
     latest committed checkpoint is still the resume point."""
 
 
@@ -99,7 +99,8 @@ class TrainSupervisor:
     stats_file : str, optional
         Path of a tiny text file persisting the total-executed-steps
         counter ACROSS process kills, so run-level goodput stays
-        honest after a SIGKILL (the bench harness uses it).
+        honest after a SIGKILL (the respawn loop hands every
+        attempt the same file).
     """
 
     def __init__(self, manager, net=None, trainer=None, loss_fn=None,

@@ -19,9 +19,8 @@ health/circuit-breaker state, cross-replica retry, per-tenant quotas,
 priority load shedding, and rolling zero-downtime weight rollover
 (router.py); `FaultInjector` (faults.py) is the deterministic
 chaos-injection seam that proves all of it. See docs/SERVING.md for
-knobs and operational guidance, ``bench.py --serving`` / ``--generate``
-/ ``--router`` / ``--prefix`` (BENCH_r08/r09/r11/r13.json) for the
-measured A/Bs.
+knobs and operational guidance; what is measured on the chip, and in
+which cell, is the table at the top of docs/PERFORMANCE.md.
 """
 from .engine import (  # noqa: F401
     InferenceEngine, ServingError, EngineClosedError, QueueFullError,

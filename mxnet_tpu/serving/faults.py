@@ -21,9 +21,9 @@ reproducible production pathology:
 
 Rules fire deterministically: ``after_n`` triggers on exactly the n-th
 dispatch of the matching replica (each rule at most once), ``rate``
-draws from the injector's own seeded RNG. Tests and benches may also
-call :meth:`FaultInjector.crash` directly to kill a replica at a
-scripted moment (``bench.py --router`` kills one mid-window).
+draws from the injector's own seeded RNG. Tests may also call
+:meth:`FaultInjector.crash` directly to kill a replica at a scripted
+moment.
 """
 from __future__ import annotations
 

@@ -154,7 +154,7 @@ class GenerationStream:
         self._watchers: list = []
         #: ``time.perf_counter()`` stamps of the first token and of
         #: completion — producer-side, so latency measurement needs no
-        #: consumer thread racing the stream (bench.py --generate).
+        #: consumer thread racing the stream.
         self.first_token_at = None
         self.done_at = None
         #: the request's tracing.Trace, or None (tracing off for this
@@ -2384,9 +2384,9 @@ class GenerationEngine:
         (``host_syncs``), dispatched ``dispatches`` jitted programs
         to produce them, and fused ``fused`` decode iterations behind
         that sync (``ticks_per_sync`` — the ``decode_ticks`` knob's
-        live readout; 1 on a plain tick). ``bench.py --latency``
-        gates host-syncs/token and dispatch counts from these
-        counters, so the amortization is measured, never asserted."""
+        live readout; 1 on a plain tick). tests/test_multitick.py
+        holds host syncs a token and dispatch counts from these
+        counters, so the amortization is counted, never asserted."""
         telemetry.counter("serving.generate.host_syncs")
         telemetry.counter("serving.generate.dispatches",
                           int(dispatches))

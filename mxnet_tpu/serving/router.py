@@ -54,8 +54,7 @@ it:
 Every replica dispatch passes through the
 :class:`~mxnet_tpu.serving.FaultInjector` seam (``fault_injector=``),
 so each behavior above is provable with seeded, deterministic faults
-(tests/test_router.py; ``bench.py --router`` kills a replica
-mid-window and measures goodput/recovery — BENCH_r11.json).
+(tests/test_router.py).
 
 Telemetry (docs/OBSERVABILITY.md): counters
 ``serving.router.{requests,completed,retries,replica_failures,

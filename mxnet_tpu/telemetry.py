@@ -101,8 +101,7 @@ def counter(name: str, delta: float = 1):
 
 def counter_value(name: str) -> float:
     """Current value of one counter (0 if never incremented) — the
-    point read used by tests and ``bench.py --trainer-path`` without
-    paying for a full snapshot."""
+    point read, without paying for a full snapshot."""
     with _lock:
         return _counters.get(name, 0)
 

@@ -35,8 +35,8 @@ Design constraints (mirrors telemetry.py):
   ``submit(trace=True)``.
 - **Host-side only**: spans are recorded strictly outside the jitted
   closures, so an armed trace can never retrace or reshape the
-  fixed-shape serving programs (tests/test_telemetry_overhead.py and
-  ``bench.py --obs`` hold the zero-steady-state-compile gate).
+  fixed-shape serving programs (tests/test_telemetry_overhead.py
+  holds the zero-steady-state-compile gate).
 - **Thread-safe**: a trace crosses threads (submitter, engine worker,
   router callbacks on replica workers); every mutation is a few list
   ops under the trace's own lock.

@@ -695,33 +695,6 @@ def test_parallel_shim_delegates_and_warns(tmp_path):
                                    net.weight.data().asnumpy())
 
 
-def test_bench_checkpoint_schema():
-    import importlib.util
-    spec = importlib.util.spec_from_file_location(
-        "bench", os.path.join(os.path.dirname(os.path.dirname(
-            os.path.abspath(__file__))), "bench.py"))
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-    cfg = {"stall_ms": 1.0, "stall_frac_of_step": 0.01,
-           "mean_plain_step_ms": 100.0, "mean_save_step_ms": 101.0,
-           "saves": 4, "checkpoint_bytes": 1000}
-    doc = {"metric": "checkpoint_async_stall_frac", "value": 0.01,
-           "unit": "u", "model": "m", "n_devices": 8,
-           "async": dict(cfg), "sync": dict(cfg),
-           "restore": {"restore_ms": 5.0, "bit_identical": True},
-           "sync_vs_async_stall_ratio": 10.0,
-           "async_stall_under_10pct": True,
-           "resume_bit_identical": True}
-    assert bench._ckpt_check_schema(doc) is doc
-    with pytest.raises(ValueError, match="missing key"):
-        bench._ckpt_check_schema(
-            {k: v for k, v in doc.items() if k != "restore"})
-    bad = dict(doc, sync={k: v for k, v in cfg.items()
-                          if k != "stall_ms"})
-    with pytest.raises(ValueError, match="sync.stall_ms"):
-        bench._ckpt_check_schema(bad)
-
-
 @pytest.mark.slow
 def test_concurrent_saves_with_rollover_soak(tmp_path):
     """Training loop checkpointing async while a serving engine

@@ -28,15 +28,31 @@ class SquareDataset(gdata.Dataset):
         return x * x, onp.int32(i)
 
 
-class SlowDataset(SquareDataset):
+class RendezvousDataset(SquareDataset):
+    """Labels each sample with the process that loaded it. Sample 0
+    (the first batch) is not handed over until ANOTHER process has
+    loaded the last sample of the second batch, so one process alone
+    can never finish the first batch; the wait is bounded, and a
+    sample that gave up says so in its label."""
+
+    def __init__(self, n, batch_size, root):
+        super().__init__(n)
+        self._last_of_second = 2 * batch_size - 1
+        self._root = root
+
     def __getitem__(self, i):
-        # pure-python CPU burn that HOLDS the GIL (what the process
-        # path exists for)
-        acc = 0.0
-        for k in range(20000):
-            acc += (i * k) % 7
-        x, y = super().__getitem__(i)
-        return x + (acc * 0.0), y
+        x, _ = super().__getitem__(i)
+        me = os.getpid()
+        met = 0
+        if i == self._last_of_second:
+            open(os.path.join(self._root, str(me)), "w").close()
+        if i == 0:
+            deadline = time.monotonic() + 60
+            while not met and time.monotonic() < deadline:
+                met = int(any(int(f) != me
+                              for f in os.listdir(self._root)))
+                time.sleep(0.005)
+        return x, onp.array([me, met], onp.int32)
 
 
 def test_process_loader_matches_thread_loader():
@@ -63,23 +79,19 @@ def test_process_loader_multiple_epochs_and_shuffle():
 
 @pytest.mark.skipif((os.cpu_count() or 1) < 2,
                     reason="needs >1 core to demonstrate scaling")
-def test_process_loader_scales_past_gil():
-    ds = SlowDataset(24)
-    serial = gdata.DataLoader(ds, batch_size=4, num_workers=0,
-                              prefetch=0)
+def test_process_loader_scales_past_gil(tmp_path):
+    """What lets the loader scale past the GIL, by count: the batches
+    of one epoch are made in at least two worker processes, none of
+    them this one, and the second batch's samples were loaded before
+    the first batch was consumed here."""
+    ds = RendezvousDataset(24, 4, str(tmp_path))
     proc = gdata.DataLoader(ds, batch_size=4, num_workers=2,
                             thread_pool=False)
-    t0 = time.perf_counter()
-    for _ in serial:
-        pass
-    t_serial = time.perf_counter() - t0
-    next(iter(proc))  # warm the spawn pool outside the timed region
-    t0 = time.perf_counter()
-    for _ in proc:
-        pass
-    t_proc = time.perf_counter() - t0
-    # two GIL-free workers + pipelining must beat the serial loop
-    assert t_proc < t_serial * 0.9, (t_serial, t_proc)
+    labels = [l.asnumpy() for _, l in proc]
+    assert len(labels) == 6
+    pids = {int(row[0]) for batch in labels for row in batch}
+    assert len(pids) >= 2 and os.getpid() not in pids, pids
+    assert labels[0][0][1] == 1, "the first batch waited in vain"
 
 
 def test_partial_epoch_releases_shared_memory():

@@ -450,7 +450,7 @@ def test_tp_int8_teacher_forced_bounded_divergence():
     # noise (~1e-5) vanishes inside it
     assert onp.abs(ref - quant).max() < 0.7
     # greedy corpus agreement at the engine level (the >= 0.9 floor
-    # of test_quantized's engine gate; the bench ties the head)
+    # of test_quantized's random-head engine case)
     ref_eng = _engine(quant=True)
     want = _serve(ref_eng, prompts)
     ref_eng.close()

@@ -28,8 +28,7 @@ def _gpt(seed=0, tied=False, vocab=VOCAB, units=UNITS):
     net.initialize(mx.init.Xavier())
     if tied:
         # tied lm_head: peaky logits, a real greedy gap for the TP
-        # reduction-order noise (~1e-5) to clear — the established
-        # bench discipline (BENCH_r14/r15)
+        # reduction-order noise (~1e-5) to clear
         net._gen_params()
         params = net.collect_params()
         params["lm_head.weight"].set_data(
@@ -298,6 +297,20 @@ def test_trainstep_comm_bytes_fsdp_below_dp():
     _, step_d, _ = _layout_run(None, (8,), ("dp",), n_steps=1)
     _, step_f, _ = _layout_run("fsdp", (8,), ("dp",), n_steps=1)
     assert 0 < step_f.comm_bytes_per_step < step_d.comm_bytes_per_step
+
+
+def test_trainstep_fsdp_program_holds_the_all_gathers():
+    """The layout is in the program, not only in the placements: the
+    compiled fsdp step gathers its sharded weights before use, and
+    the dp step, whose weights are whole on every device, gathers
+    nothing."""
+    x, y = _lm_batch()
+    counts = {}
+    for layout in (None, "fsdp"):
+        _, step, _ = _layout_run(layout, (8,), ("dp",), n_steps=1)
+        colls = partition.hlo_collectives(step.compiled_hlo(x, y))
+        counts[layout] = colls.get("all-gather", {"count": 0})["count"]
+    assert counts["fsdp"] > 0 and counts[None] == 0, counts
 
 
 @pytest.mark.parametrize("layout,mesh_shape,axes", [

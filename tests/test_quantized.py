@@ -322,8 +322,8 @@ def test_engine_int8_weights_bounded_divergence():
     pairs = [(a, b) for ra, rb in zip(ref, out)
              for a, b in zip(ra, rb)]
     agree = sum(a == b for a, b in pairs) / len(pairs)
-    assert agree >= 0.9      # tiny random model: loose engine-level
-    # floor; the bench gates the tied-head corpus at >= 0.98
+    assert agree >= 0.9      # tiny random model, all near-ties: a
+    # loose engine-level floor
 
 
 def test_engine_rollover_requantizes_without_retrace():
@@ -528,39 +528,16 @@ def test_quantized_dense_eager_zero_batch_forward():
     assert onp.isfinite(y).all()
 
 
-def test_bench_quant_schema():
-    import os
-    import sys
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    sys.path.insert(0, repo)
-    try:
-        import bench
-    finally:
-        sys.path.pop(0)
-    doc = {
-        "metric": "m", "value": 1.0, "unit": "u", "model": "g",
-        "smoke": True,
-        "parity": {"greedy_agreement": 1.0, "w8_logit_maxerr": 0.1,
-                   "kv_logit_maxerr": 0.1, "tokens_compared": 10},
-        "fp32": {"tokens_per_sec": 1.0, "slots": 2,
-                 "hbm_budget_bytes": 1, "compiles_in_window": 0,
-                 "decode_p50_ms": 1.0},
-        "w8": {"tokens_per_sec": 2.0, "slots": 8,
-               "hbm_budget_bytes": 1, "compiles_in_window": 0,
-               "decode_p50_ms": 1.0},
-        "kv_fp32": {"effective_slots_same_hbm": 30.0, "pool_bytes": 9,
-                    "n_pages": 5, "pages_shared": 1,
-                    "compiles_in_window": 0},
-        "kv_int8": {"effective_slots_same_hbm": 120.0, "pool_bytes": 8,
-                    "n_pages": 20, "pages_shared": 1,
-                    "compiles_in_window": 0},
-        "throughput_ratio": 2.0, "kv_effective_ratio": 4.0,
-        "kv_multiplier_vs_r13": 3.0, "greedy_agreement": 1.0,
-        "zero_compiles_in_window": True, "throughput_ge_1_3x": True,
-        "kv_effective_ge_1_8x": True, "agreement_ge_98pct": True,
-        "logit_bounds_hold": True,
-    }
-    assert bench._qnt_check_schema(doc) is doc
-    bad = dict(doc, kv_int8=dict(doc["kv_int8"], pool_bytes=10))
-    with pytest.raises(ValueError, match="pool bytes"):
-        bench._qnt_check_schema(bad)
+def test_int8_paged_pool_bytes_against_fp32():
+    """At one pool geometry an int8 pool, its per-head-per-page scales
+    counted against it, holds a little over a quarter of the fp32
+    pool's bytes: the same memory holds nearly four times the pages."""
+    net = _net()
+
+    def pool_bytes(dtype):
+        cache = net.init_paged_cache(4, 33, 8, SMAX, dtype=dtype)
+        return sum(int(a.nbytes) for key, pools in cache.items()
+                   if key not in ("table", "len") for a in pools)
+
+    fp32, int8 = pool_bytes("float32"), pool_bytes("int8")
+    assert fp32 / 4 < int8 <= 0.30 * fp32, (fp32, int8)

@@ -234,6 +234,28 @@ def test_engine_spec_greedy_token_identical_dense(target, draft):
     assert "serving.generate.spec.tokens_per_step" in snap["gauges"]
 
 
+def test_engine_spec_agreeing_draft_multiplies_tokens_per_step(target):
+    """Speculation multiplies tokens per iteration exactly when the
+    draft agrees: with the target as its own draft an iteration
+    commits ``spec_k + 1`` tokens a slot, so a lone request costs
+    fewer host syncs than tokens, and the tokens are the plain
+    engine's."""
+    rng = onp.random.RandomState(5)
+    p = _prompt(rng, 6)
+    plain = _engine(target, max_new=9)
+    ref = plain.submit(p).result(timeout=120).tokens
+    plain.close()
+    spec = _engine(target, max_new=9, draft_model=target, spec_k=3)
+    telemetry.reset()
+    out = spec.submit(p).result(timeout=120)
+    snap = telemetry.snapshot()
+    spec.close()
+    assert out.tokens == ref and len(ref) == 9
+    per_step = snap["gauges"]["serving.generate.spec.tokens_per_step"]
+    assert per_step["peak"] == 4
+    assert snap["counters"]["serving.generate.host_syncs"] < len(ref) - 1
+
+
 def test_engine_spec_greedy_token_identical_paged(target, draft):
     """Paged + speculative: shared-prefix prompts (prefix reuse + COW
     under verify writes) and chunked prefill compose with speculation

@@ -3,8 +3,8 @@
 Pins JAX to the host CPU with a chosen number of virtual devices.
 ``JAX_PLATFORMS`` is read when jax is imported, so a process that may
 already have imported it also needs ``jax.config.update`` — before any
-backend starts. This is the single home for that; bench.py,
-__graft_entry__.py and tests/conftest.py all use it.
+backend starts. This is the single home for that;
+__graft_entry__.py and tests/conftest.py use it.
 """
 from __future__ import annotations
 

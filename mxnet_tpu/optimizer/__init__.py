@@ -69,8 +69,7 @@ def _jitted_multi_step(cls, mp):
         # scalar, so the traced math is unchanged.
         new_ws, new_states = [], []
         for i, (w, g, s) in enumerate(zip(ws, gs, states)):
-            h = {k: (None if v is None else v[i])
-                 for k, v in hypers.items()}
+            h = cls._hyper_at(hypers, i)
             if mp:
                 nw, ns = cls._step_mp(w, g, s, h)
             else:
@@ -213,6 +212,41 @@ class Optimizer:
             "t": onp.int32(t),
         }
 
+    def _stack_hypers(self, dicts, check=False):
+        """The `_hyper` dicts of several parameters as ONE dict: each
+        numpy scalar field becomes an ``(n,)`` numpy array of its own
+        dtype (``t`` stays int32, the rest float32), so a compiled
+        multi-parameter update takes a handful of host arguments, not
+        5-8 per PARAMETER (each host scalar is a host-to-device
+        transfer of its own). A field that is no numpy scalar — ``None``,
+        or AdamW's Python-float ``correct`` — says how the update is
+        traced, by presence or as a weakly typed scalar; it is the same
+        for every parameter of an optimizer and passes through as it
+        is (``check`` holds the optimizer to that: for whoever builds a
+        program once, not for every step). `_hyper_at` is the inverse,
+        inside the program."""
+        first = dicts[0] if dicts else self._hyper(0)
+        if check:
+            for f, v0 in first.items():
+                if not isinstance(v0, onp.generic) \
+                        and any(d[f] != v0 for d in dicts):
+                    raise TypeError(
+                        f"{type(self).__name__}._hyper: field {f!r} "
+                        f"differs between parameters but is no numpy "
+                        f"scalar ({type(v0).__name__}); only numpy "
+                        f"scalars are stacked")
+        return {f: (onp.array([d[f] for d in dicts], dtype=v0.dtype)
+                    if isinstance(v0, onp.generic) else v0)
+                for f, v0 in first.items()}
+
+    @staticmethod
+    def _hyper_at(hypers, i):
+        """Parameter ``i``'s hyper dict out of `_stack_hypers`' (traced:
+        a static index into each ``(n,)`` field, so the update sees the
+        exact per-parameter scalar)."""
+        return {f: v[i] if getattr(v, "ndim", 0) else v
+                for f, v in hypers.items()}
+
     @staticmethod
     def _pre(g, w, hyper, wd_in_grad=True):
         """rescale → clip → (optionally) add L2 wd into the gradient."""
@@ -295,12 +329,7 @@ class Optimizer:
                 and s[0].dtype == jnp.float32
             groups.setdefault((str(w._data.dtype), mp), []).append(pos)
         for (_, mp), poss in groups.items():
-            # stack per field ((n,) array or None) — field presence is
-            # per-optimizer, so it is uniform across the group
-            hypers = {k: (None if v0 is None
-                          else onp.stack([hyper_dicts[p][k]
-                                          for p in poss]))
-                      for k, v0 in hyper_dicts[poss[0]].items()}
+            hypers = self._stack_hypers([hyper_dicts[p] for p in poss])
             ws = tuple(weight[p]._data for p in poss)
             gs = tuple(grad[p]._data for p in poss)
             ss = tuple(state[p] for p in poss)

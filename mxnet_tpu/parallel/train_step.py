@@ -70,6 +70,17 @@ def _layer_groups(diff_names, frozen_names):
     return ordered if len(ordered) >= 2 else None
 
 
+def _stacked_hypers(opt, n_diff, check=False):
+    """The hyperparameters of parameters ``0..n_diff-1`` as the step
+    program takes them: ONE dict of stacked fields
+    (`Optimizer._stack_hypers`), not a dict of scalars a parameter —
+    4,067 host scalars a call kept a v5e idle two thirds of every GPT-2
+    large step. Read anew on every call: nothing here outlives the
+    step, so a scheduler or a changed multiplier reaches the next one."""
+    return opt._stack_hypers([opt._hyper(k) for k in range(n_diff)],
+                             check=check)
+
+
 class TrainStep:
     """Compile `loss_fn(net(data), label)` + grad + optimizer update into
     one jitted, donation-friendly XLA program, optionally sharded over a
@@ -315,6 +326,9 @@ class TrainStep:
 
         opt_cls = type(opt)
         n_diff = len(diff_nds)
+        # once a program: a hyper field that is not stacked is one
+        # value for every parameter
+        _stacked_hypers(opt, n_diff, check=True)
 
         # gather-compute layouts (tp_fsdp): weights AND gradients are
         # pinned replicated INSIDE the step — the forward all-gathers
@@ -408,7 +422,7 @@ class TrainStep:
             new_ws, new_ss = [], []
             for k in range(n_diff):
                 w, g, s, h = (diff_datas[k], grads[k], opt_states[k],
-                              hypers[k])
+                              opt_cls._hyper_at(hypers, k))
                 if mp_flags[k]:
                     nw, ns = opt_cls._step_mp(w, g, s, h)
                 else:
@@ -470,8 +484,8 @@ class TrainStep:
 
             data_sh = tuple(batch_sh(l) for l in data_leaves)
             label_sh = tuple(batch_sh(l) for l in label_leaves)
-            hyper_sh = [jax.tree.map(lambda _: rep, opt._hyper(k))
-                        for k in range(n_diff)]
+            hyper_sh = jax.tree.map(lambda _: rep,
+                                    _stacked_hypers(opt, n_diff))
             jit_kwargs["in_shardings"] = (
                 rep, tuple(diff_sh), tuple(frozen_sh),
                 tuple(state_sh), hyper_sh, data_sh, label_sh, rep)
@@ -582,7 +596,7 @@ class TrainStep:
                 ks = jax.random.split(key)
                 key, sub = ks[0], ks[1]
                 d, l, nv = xs
-                hy = [{**h, "t": h["t"] + t_off} for h in hypers]
+                hy = {**hypers, "t": hypers["t"] + t_off}
                 new_ws, new_ss, loss, aux = step_fn(
                     sub, diff, frozen, states, hy, d, l, nv)
                 frozen2 = list(frozen)
@@ -736,7 +750,7 @@ class TrainStep:
         # bias correction needs t>=1), then the remaining n-1; the
         # scan body advances t by its step offset
         opt._update_count(list(range(n_diff)))
-        hypers = [opt._hyper(k) for k in range(n_diff)]
+        hypers = _stacked_hypers(opt, n_diff)
         for _ in range(n_steps - 1):
             opt._update_count(list(range(n_diff)))
 
@@ -829,7 +843,7 @@ class TrainStep:
     def _prepare(self, data, label, pad):
         """Everything of a call before the program is handed its
         arguments: flatten and bucket the batch, find the entry, count
-        the update, build the hypers, place the batch. Returns
+        the update, stack the hypers, place the batch. Returns
         ``(entry, args, pad)``."""
         data_leaves, data_spec = _flatten_arrays(_as_tuple(data))
         label_leaves, label_spec = _flatten_arrays(_as_tuple(label))
@@ -840,7 +854,7 @@ class TrainStep:
         opt = self.optimizer
         n_diff = len(entry["diff_nds"])
         opt._update_count(list(range(n_diff)))
-        hypers = [opt._hyper(k) for k in range(n_diff)]
+        hypers = _stacked_hypers(opt, n_diff)
 
         data_datas = [l._data for l in data_leaves]
         label_datas = [l._data for l in label_leaves]
@@ -861,6 +875,17 @@ class TrainStep:
                 tuple(nd._data for nd in entry["frozen_nds"]),
                 tuple(self._opt_states), hypers,
                 tuple(data_datas), tuple(label_datas), n_valid)
+        if telemetry.enabled():
+            # what the call hands over from the host: each such leaf is
+            # a transfer of its own inside the program's call, so this
+            # must not grow with the number of parameters (counted once
+            # an entry: its calls all pass the same tree)
+            if "host_arg_leaves" not in entry:
+                entry["host_arg_leaves"] = sum(
+                    not isinstance(a, jax.Array)
+                    for a in jax.tree.leaves(args))
+            telemetry.gauge("parallel.train_step.host_arg_leaves",
+                            entry["host_arg_leaves"])
         return entry, args, pad
 
     @staticmethod
@@ -929,7 +954,7 @@ class TrainStep:
                                    label_leaves, label_spec)
         opt = self.optimizer
         n_diff = len(entry["diff_nds"])
-        hypers = [opt._hyper(k) for k in range(n_diff)]
+        hypers = _stacked_hypers(opt, n_diff)
         abstract = [jax.ShapeDtypeStruct(l.shape, l.dtype)
                     for l in data_leaves]
         labstract = [jax.ShapeDtypeStruct(l.shape, l.dtype)
@@ -1007,10 +1032,11 @@ class TrainStep:
                 continue
             opt = self.optimizer
             n_diff = len(entry["diff_nds"])
-            # hypers carry the CURRENT counters; their avals (strong
-            # numpy scalars) are what matters for the compiled
-            # signature, not the values
-            hypers = [opt._hyper(k) for k in range(n_diff)]
+            # hypers carry the CURRENT counters; their avals (one
+            # strong (n_diff,) numpy array a field, as every call
+            # passes) are what matters for the compiled signature, not
+            # the values
+            hypers = _stacked_hypers(opt, n_diff)
             abstract = [jax.ShapeDtypeStruct(l.shape, l.dtype)
                         for l in data_leaves]
             labstract = [jax.ShapeDtypeStruct(l.shape, l.dtype)

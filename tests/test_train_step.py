@@ -255,3 +255,258 @@ def test_train_step_releases_imperative_grad_buffers(req):
     grads = [p.grad().asnumpy() for p in params]
     assert all(g.shape == p.shape for g, p in zip(grads, params))
     assert any(onp.abs(g).max() > 0 for g in grads)
+
+
+# -- stacked hyperparameters -------------------------------------------
+# The step program takes ONE (n_params,) numpy array a hyper field
+# (Optimizer._stack_hypers), not one scalar a field a parameter.
+
+_VOCAB, _SEQ = 48, 12
+
+_HYPER_OPTS = {
+    "sgd-momentum": ("sgd", {"learning_rate": 0.1, "momentum": 0.9,
+                             "wd": 0.01}),
+    "adam": ("adam", {"learning_rate": 0.01, "wd": 0.01}),
+    "adamw-correct": ("adamw", {"learning_rate": 0.01, "wd": 0.05}),
+    "adamw-nocorrect": ("adamw", {"learning_rate": 0.01, "wd": 0.05,
+                                  "correct_bias": False}),
+    "nag-clip": ("nag", {"learning_rate": 0.1, "momentum": 0.8,
+                         "clip_gradient": 0.01}),
+    "lamb-clip": ("lamb", {"learning_rate": 0.01, "wd": 0.02,
+                           "clip_gradient": 0.05}),
+}
+
+
+def _tiny_gpt(seed=0):
+    from mxnet_tpu.gluon.model_zoo.gpt import GPTModel
+    mx.np.random.seed(seed)
+    net = GPTModel(vocab_size=_VOCAB, units=16, num_layers=1,
+                   num_heads=2, max_length=16)
+    net.initialize(mx.init.Xavier())
+    return net
+
+
+def _lm_batch(n=8, seed=1):
+    rng = onp.random.RandomState(seed)
+    x = rng.randint(0, _VOCAB, (n, _SEQ + 1)).astype("i4")
+    return np.array(x[:, :-1]), np.array(x[:, 1:])
+
+
+def _lm_loss(out, label):
+    return gluon.loss.SoftmaxCrossEntropyLoss()(
+        out.reshape(-1, out.shape[-1]), label.reshape(-1))
+
+
+def _uneven(opt, n):
+    """Per-parameter multipliers that all differ and unequal update
+    counts: a field permuted between parameters, or one stacked in the
+    wrong dtype, changes some parameter's update."""
+    opt.set_lr_mult({k: 0.5 + 0.125 * k for k in range(n)})
+    opt.set_wd_mult({k: 2.0 - 0.0625 * k for k in range(n)})
+    opt._index_update_count = {k: (3 * k) % 7 for k in range(n)}
+    opt.num_update = max(opt._index_update_count.values())
+
+
+def _host(tree):
+    import jax
+    return [onp.asarray(l) for l in jax.tree.leaves(tree)]
+
+
+@pytest.mark.parametrize("layout", [None, "dp", "fsdp"])
+@pytest.mark.parametrize("opt_id", sorted(_HYPER_OPTS))
+def test_stacked_hypers_bitwise_equal_per_param_dicts(opt_id, layout,
+                                                      monkeypatch):
+    """Three TrainStep steps give BITWISE the weights and optimizer
+    state of the same step_fn fed what the step took before the hypers
+    were stacked: the list of ``opt._hyper(k)`` dicts, one numpy
+    scalar a field a parameter."""
+    import contextlib
+    import jax
+    if layout is not None and len(jax.devices()) < 8:
+        pytest.skip("needs 8 virtual devices")
+    name, kwargs = _HYPER_OPTS[opt_id]
+    mesh = None if layout is None \
+        else parallel.make_mesh((8,), ("dp",))
+    x, y = _lm_batch()
+
+    def mk(like=None):
+        net = _tiny_gpt()
+        net(x)  # materialize deferred shapes
+        if like is not None:
+            for pa, pb in zip(like.collect_params().values(),
+                              net.collect_params().values()):
+                pb.set_data(pa.data().copy())  # TrainStep donates
+        step = parallel.TrainStep(
+            net, _lm_loss, name, dict(kwargs), mesh=mesh,
+            layout=None if layout == "dp" else layout)
+        return net, step
+
+    net_a, step_a = mk()
+    net_b, step_b = mk(like=net_a)
+    with parallel.mesh_scope(mesh) if mesh is not None \
+            else contextlib.nullcontext():
+        # -- the change: TrainStep as it is
+        n = len([p for p in net_a.collect_params().values()
+                 if p.grad_req != "null"])
+        _uneven(step_a.optimizer, n)
+        for _ in range(3):
+            step_a(x, y)
+
+        # -- the reference: the same step_fn, per-parameter dicts
+        _uneven(step_b.optimizer, n)
+        opt = step_b.optimizer
+        ref = None
+        for _ in range(3):
+            entry, args, pad = step_b._prepare(x, y, None)
+            dicts = [opt._hyper(k) for k in range(n)]
+            assert all(isinstance(v, onp.generic) or v is None
+                       or isinstance(v, float)
+                       for d in dicts for v in d.values())
+            if ref is None:
+                kw = dict(entry["jit_kwargs"])
+                if "in_shardings" in kw:
+                    rep = kw["in_shardings"][0]
+                    sh = list(kw["in_shardings"])
+                    sh[4] = [jax.tree.map(lambda _: rep, d)
+                             for d in dicts]
+                    kw["in_shardings"] = tuple(sh)
+                monkeypatch.setattr(
+                    type(opt), "_hyper_at",
+                    staticmethod(lambda hypers, k: hypers[k]))
+                ref = jax.jit(entry["step_fn"], **kw)
+            out = ref(*args[:4], dicts, *args[5:])
+            step_b._writeback(entry, out, pad)
+
+    assert n > 8 and len(step_a._opt_states) == n
+    for (ka, pa), (kb, pb) in zip(net_a.collect_params().items(),
+                                  net_b.collect_params().items()):
+        onp.testing.assert_array_equal(pa.data().asnumpy(),
+                                       pb.data().asnumpy(), err_msg=ka)
+    for sa, sb in zip(_host(step_a._opt_states),
+                      _host(step_b._opt_states)):
+        onp.testing.assert_array_equal(sa, sb)
+    assert step_a.optimizer._index_update_count \
+        == step_b.optimizer._index_update_count
+
+
+def test_run_chain_advances_the_stacked_update_counts():
+    """run_chain of 3 steps equals 3 sequential calls when every
+    parameter has its own update count and multipliers: the scan body
+    advances ``t`` on the stacked (n,) field."""
+    n_steps, batch = 3, 16
+    x, y = _data(n=n_steps * batch)
+    xs = x.asnumpy().reshape(n_steps, batch, -1)
+    ys = y.asnumpy().reshape(n_steps, batch)
+    net_a, net_b = _mlp(), _mlp()
+    net_a(np.array(xs[0])), net_b(np.array(xs[0]))
+    for pa, pb in zip(net_a.collect_params().values(),
+                      net_b.collect_params().values()):
+        pb.set_data(pa.data().copy())
+    mk = lambda net: parallel.TrainStep(
+        net, gluon.loss.SoftmaxCrossEntropyLoss(), "adam",
+        {"learning_rate": 0.01, "wd": 0.01}, mesh=None)
+    step_a, step_b = mk(net_a), mk(net_b)
+    _uneven(step_a.optimizer, 4)
+    _uneven(step_b.optimizer, 4)
+    seq = [float(step_a(np.array(xs[i]), np.array(ys[i])))
+           for i in range(n_steps)]
+    chain = step_b.run_chain(np.array(xs), np.array(ys))
+    onp.testing.assert_allclose(chain.asnumpy(), seq, rtol=2e-4,
+                                atol=2e-5)
+    assert step_a.optimizer._index_update_count \
+        == step_b.optimizer._index_update_count \
+        == {k: (3 * k) % 7 + n_steps for k in range(4)}
+    for (na, pa), pb in zip(net_a.collect_params().items(),
+                            net_b.collect_params().values()):
+        onp.testing.assert_allclose(pa.data().asnumpy(),
+                                    pb.data().asnumpy(),
+                                    rtol=2e-4, atol=2e-5, err_msg=na)
+    for sa, sb in zip(_host(step_a._opt_states),
+                      _host(step_b._opt_states)):
+        onp.testing.assert_allclose(sa, sb, rtol=2e-4, atol=1e-6)
+
+
+@pytest.mark.parametrize("opt_id", ["sgd-momentum", "adam",
+                                    "adamw-correct", "nag-clip"])
+def test_warmup_lowers_the_avals_the_call_passes(opt_id):
+    """After ``warmup()`` the first ``__call__`` goes through the AOT
+    executable: no fallback, no compile. The stacked fields, ``None``
+    fields and AdamW's Python-float ``correct`` lower to the avals the
+    call hands over."""
+    import jax
+    from mxnet_tpu import telemetry
+    name, kwargs = _HYPER_OPTS[opt_id]
+    x, y = _data(n=16)
+    net = _mlp()
+    net(x)
+    step = parallel.TrainStep(net, gluon.loss.SoftmaxCrossEntropyLoss(),
+                              name, dict(kwargs), mesh=None)
+    step.warmup([((16, 16), (16,))])
+    _uneven(step.optimizer, 4)
+    telemetry.reset()
+    compiles = []
+
+    def listener(event, duration, **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiles.append(event)
+
+    jax.monitoring.register_event_duration_secs_listener(listener)
+    try:
+        losses = [float(step(x, y)) for _ in range(2)]
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listener)
+    snap = telemetry.snapshot()
+    assert "parallel.train_step.aot_fallback" not in snap["counters"]
+    assert "parallel.train_step.build" not in snap["counters"]
+    assert "parallel.train_step.compile" not in snap["durations"]
+    assert snap["durations"]["parallel.train_step.run"]["count"] == 2
+    assert not compiles, compiles
+    assert all(onp.isfinite(l) for l in losses)
+
+
+@pytest.mark.parametrize("opt_id", ["adam", "adamw-correct"])
+def test_host_arg_leaves_do_not_grow_with_the_parameters(opt_id):
+    """``parallel.train_step.host_arg_leaves``: the leaves of a call's
+    arguments that are no device arrays. One a hyper field and
+    ``n_valid``, whatever the number of parameters."""
+    from mxnet_tpu import telemetry
+    name, kwargs = _HYPER_OPTS[opt_id]
+    x, y = _data(n=16)
+    reads = {}
+    for layers in (2, 20):
+        net = nn.HybridSequential()
+        for _ in range(layers - 1):
+            net.add(nn.Dense(8, activation="relu"))
+        net.add(nn.Dense(4))
+        net.initialize(mx.init.Xavier())
+        step = parallel.TrainStep(
+            net, gluon.loss.SoftmaxCrossEntropyLoss(), name,
+            dict(kwargs), mesh=None)
+        step(x, y)
+        assert len(step._opt_states) == 2 * layers
+        reads[2 * layers] = telemetry.gauge_value(
+            "parallel.train_step.host_arg_leaves")
+    assert reads[4] == reads[40], reads
+    assert 0 < reads[4] < 10, reads
+
+
+def test_unstackable_hyper_field_is_refused_at_build():
+    """A hyper field that is no numpy scalar is not stacked: it is ONE
+    value for every parameter, and an optimizer whose `_hyper` varies
+    one is told so when the program is built, not given parameter 0's
+    value for all."""
+    from mxnet_tpu.optimizer import SGD
+
+    class PerParamFloat(SGD):
+        def _hyper(self, index):
+            h = super()._hyper(index)
+            h["scale"] = 1.0 + index   # a Python float a parameter
+            return h
+
+    x, y = _data(n=16)
+    net = _mlp()
+    net(x)
+    step = parallel.TrainStep(net, gluon.loss.SoftmaxCrossEntropyLoss(),
+                              PerParamFloat(learning_rate=0.1), mesh=None)
+    with pytest.raises(TypeError, match="'scale' differs"):
+        step(x, y)

@@ -376,22 +376,52 @@ flash_attention.defvjp(_flash_fwd, _flash_bwd)
 # ---------------------------------------------------------------------------
 # decode attention (single-query KV-cache attention, per-row lengths)
 # ---------------------------------------------------------------------------
-def _masked_attend(q, k, v, valid, scale):
+def _masked_softmax(s, valid):
+    """Softmax of float32 scores over the positions ``valid`` allows,
+    with the two guards ``masked_attention`` describes."""
+    s = jnp.where(valid, s, NEG_INF)
+    m = s.max(axis=-1, keepdims=True)
+    p = jnp.where(valid, jnp.exp(s - m), 0.0)
+    l = p.sum(axis=-1, keepdims=True)
+    l_safe = jnp.where(l > 0, l, 1.0)
+    return p / l_safe
+
+
+def masked_attention(q, k, v, valid, scale):
     """Single-pass masked-softmax attention: score, mask, softmax with
     the two non-obvious guards the cache paths need — RE-MASK after
     the exp (a fully-masked row's scores are all NEG_INF, so
     exp(s - m) would be exp(0)=1 across the board instead of 0) and an
     l_safe denominator (a fully-masked row — an empty serving slot —
     returns zeros, not NaN). Shared by decode attention and chunked
-    prefill, which differ only in the validity predicate."""
-    s = jnp.einsum("bhqd,bhkd->bhqk", q, k).astype(jnp.float32) * scale
-    s = jnp.where(valid, s, NEG_INF)
-    m = s.max(axis=-1, keepdims=True)
-    p = jnp.where(valid, jnp.exp(s - m), 0.0)
-    l = p.sum(axis=-1, keepdims=True)
-    l_safe = jnp.where(l > 0, l, 1.0)
-    p = (p / l_safe).astype(v.dtype)
-    return jnp.einsum("bhqk,bhkd->bhqd", p, v)
+    prefill, which differ only in the validity predicate, and by a
+    model whose predicate is its own (a ring of window positions).
+
+    ``q`` (B, Hq, Sq, D), ``k`` (B, Hk, S, D), ``v`` (B, Hv, S, Dv),
+    ``valid`` broadcastable to (B, Hq, Sq, S). With equal head counts
+    this is plain multi-head attention. Otherwise the heads are GROUPED:
+    ``Hk`` divides ``Hq`` and ``Hv`` divides ``Hq``, query head ``h``
+    reads key head ``h // (Hq / Hk)`` and value head ``h // (Hq / Hv)``
+    (grouped-query attention has ``Hk == Hv``; a differential layer
+    reads a pair of value heads as one head twice as wide, so its
+    ``Hv`` is half its ``Hk``), and the scores are float32 products."""
+    hq, hk, hv = q.shape[1], k.shape[1], v.shape[1]
+    if hq == hk == hv:
+        s = jnp.einsum("bhqd,bhkd->bhqk", q, k).astype(jnp.float32) * scale
+    else:
+        if hq % hk or hq % hv:
+            raise ValueError(f"{hq} query heads are no multiple of {hk} "
+                             f"key heads and {hv} value heads")
+        qg = q.reshape(q.shape[0], hk, hq // hk, *q.shape[2:])
+        s = jnp.einsum("bhgqd,bhkd->bhgqk", qg, k,
+                       preferred_element_type=jnp.float32)
+        s = s.reshape(q.shape[0], hq, q.shape[2], k.shape[2]) * scale
+    p = _masked_softmax(s, valid).astype(v.dtype)
+    if hq == hv:
+        return jnp.einsum("bhqk,bhkd->bhqd", p, v)
+    pg = p.reshape(p.shape[0], hv, hq // hv, *p.shape[2:])
+    o = jnp.einsum("bhgqk,bhkd->bhgqd", pg, v)
+    return o.reshape(q.shape[0], hq, q.shape[2], v.shape[3])
 
 
 def _decode_fwd_jnp(q, k, v, lengths, scale):
@@ -401,8 +431,8 @@ def _decode_fwd_jnp(q, k, v, lengths, scale):
     compared to prefill, and XLA fuses the chain."""
     shape = (*q.shape[:3], k.shape[2])
     col = lax.broadcasted_iota(jnp.int32, shape, 3)
-    return _masked_attend(q, k, v,
-                          col < lengths[:, None, None, None], scale)
+    return masked_attention(q, k, v,
+                            col < lengths[:, None, None, None], scale)
 
 
 def _decode_fwd_kernel(len_ref, q_ref, k_ref, v_ref, *rest, scale,
@@ -583,10 +613,18 @@ def gather_pages(pool, table, num_heads):
     ``b`` lives at ``pool[table[b, t // ps], t % ps, h * D:(h + 1) * D]``.
     Free table entries point at the reserved scrap page (id 0) — their
     rows are garbage that per-row length masking must exclude."""
+    g = gather_rows(pool, table)
+    b, s, hd = g.shape
+    return g.reshape(b, s, num_heads, hd // num_heads).transpose(0, 2, 1, 3)
+
+
+def gather_rows(pool, table):
+    """Each slot's logical view as ROWS: (B, P_max * page_size, H * D),
+    a position's heads side by side as the pool holds them (what
+    ``rows_decode_attention`` reads)."""
     g = pool[table]                       # (B, P_max, ps, H * D)
     b, pm, ps, hd = g.shape
-    return g.reshape(b, pm * ps, num_heads, hd // num_heads) \
-        .transpose(0, 2, 1, 3)
+    return g.reshape(b, pm * ps, hd)
 
 
 def expand_page_scales(pool_scale, table, page_size):
@@ -613,6 +651,38 @@ def gather_kv(k_pool, v_pool, table, num_heads, k_scale=None,
         v = v.astype(jnp.float32) \
             * expand_page_scales(v_scale, table, ps)[..., None]
     return k, v
+
+
+def rows_decode_attention(q, k_rows, v_rows, valid, kv_heads, scale=None):
+    """One query a row against K/V ROWS AS THEY LIE in a pool or a ring:
+    ``q`` (B, Hq, D); ``k_rows`` (B, S, Hk * D) and ``v_rows`` (B, S, Hv *
+    Dv), a position's heads side by side; ``valid`` (B, S); ``kv_heads``
+    ``(Hk, Hv)``, the rows' head counts (heads grouped as in
+    ``masked_attention``). Returns (B, Hq, Dv) float32.
+
+    The rows are never split into heads: the query is laid out
+    block-diagonally, ``(B, Hq, Hk * D)`` with head ``h`` in the columns
+    of its key head and zeros elsewhere, so the scores are ONE product
+    over whole rows, and the values likewise (a product with whole rows,
+    of which each head keeps its own columns). That spends ``Hk`` (``Hv``)
+    times the multiply-adds of the split form, which a decode tick has to
+    spare (one query a row: the rows' bytes bound it), and saves the
+    re-tiling of every view from ``H * D``-wide rows to ``D``-wide heads,
+    which cost more than the attention itself (PERF.md section 5)."""
+    hq, d = q.shape[1], q.shape[2]
+    hk, hv = kv_heads
+    scale = scale if scale is not None else 1.0 / math.sqrt(d)
+    own_k = jnp.arange(hq)[:, None] // (hq // hk) == jnp.arange(hk)[None]
+    own_v = jnp.arange(hq)[:, None] // (hq // hv) == jnp.arange(hv)[None]
+    q_rows = jnp.where(own_k[None, :, :, None], q[:, :, None, :], 0) \
+        .reshape(q.shape[0], hq, hk * d)
+    s = jnp.einsum("bhc,bsc->bhs", q_rows, k_rows,
+                   preferred_element_type=jnp.float32) * scale
+    p = _masked_softmax(s, valid[:, None, :]).astype(v_rows.dtype)
+    o = jnp.einsum("bhs,bsc->bhc", p, v_rows,
+                   preferred_element_type=jnp.float32)
+    o = o.reshape(q.shape[0], hq, hv, -1)
+    return jnp.sum(jnp.where(own_v[None, :, :, None], o, 0.0), axis=2)
 
 
 def paged_decode_attention(q, k_pool, v_pool, table, lengths,
@@ -674,7 +744,7 @@ def chunked_prefill_attention(q, k, v, start, scale=None):
     row = lax.broadcasted_iota(jnp.int32, shape, 2)
     col = lax.broadcasted_iota(jnp.int32, shape, 3)
     valid = col <= start[:, None, None, None] + row
-    return _masked_attend(q, k, v, valid, scale_v)
+    return masked_attention(q, k, v, valid, scale_v)
 
 
 def decode_attention(q, k, v, lengths, scale=None, k_scale=None,

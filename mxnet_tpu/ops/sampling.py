@@ -49,8 +49,8 @@ import jax.numpy as jnp
 #: masked logits must survive softmax without minting NaNs
 NEG_INF = -1e30
 
-__all__ = ["warp_logits", "sample_tokens", "sample_with_probs",
-           "greedy_accept", "speculative_accept"]
+__all__ = ["warp_logits", "sample_tokens", "greedy_tokens",
+           "sample_with_probs", "greedy_accept", "speculative_accept"]
 
 
 def warp_logits(logits, temperature, top_k, top_p):
@@ -110,6 +110,14 @@ def sample_tokens(keys, logits, temperature, top_k, top_p):
     sampled = jax.vmap(jax.random.categorical)(sub, w)
     tok = jnp.where(greedy, jnp.argmax(logits, axis=-1), sampled)
     return tok.astype(jnp.int32), new_keys
+
+
+def greedy_tokens(logits):
+    """The greedy rows of ``sample_tokens`` alone: ``argmax`` of the RAW
+    logits (B, V) -> (B,) int32, the first of equal maxima as the host's
+    argmax takes it. What an all-greedy engine's tick runs, so that
+    (B,) ints and not (B, V) floats come to the host."""
+    return jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
 
 def sample_with_probs(keys, logits, temperature, top_k, top_p):

@@ -959,6 +959,12 @@ class GenerationEngine:
                     f"is written into its window layers' ring before it "
                     f"is attended")
             self._chunk = chunk
+            #: a model that states ``prefill_last`` is told, with each
+            #: chunk, whether it is the prompt's last (it may then run
+            #: less than its whole depth on the others); any other is
+            #: called with the arguments it always was
+            self._prefill_last = bool(
+                (support or {}).get("prefill_last"))
             if policy is None:
                 policy = BucketingPolicy(mode="pow2",
                                          min_size=max(8, ps))
@@ -1338,16 +1344,23 @@ class GenerationEngine:
             self._samplers = {
                 "sample": jax.jit(counted(_smp.sample_tokens,
                                           "sampling_sample")),
+                "greedy": jax.jit(counted(_smp.greedy_tokens,
+                                          "sampling_greedy")),
             }
         return self._samplers
 
-    def _warm_samplers(self, vocab: int):
+    def _warm_samplers(self, logits):
         """Compile every engine-level sampler shape the steady state
-        can hit: the (1, V) first-token pick and the (B, V)
-        decode-step pick (the speculative draft/accept math lives
-        inside the model's fused closures — ``_warmup_spec``)."""
+        can hit: the (1, V) first-token pick, the (B, V) decode-step
+        pick and an all-greedy tick's (B, V) argmax, the last on the
+        warm-up tick's own ``logits`` (as the live tick's are placed: a
+        committed and an uncommitted input compile apart). The
+        speculative draft/accept math lives inside the model's fused
+        closures (``_warmup_spec``)."""
         smp = self._ensure_samplers()
-        b = self.max_slots
+        b, vocab = self.max_slots, int(logits.shape[-1])
+        if self._part is None:
+            smp["greedy"](logits)
         smp["sample"](onp.zeros((1, 2), "u4"),
                       onp.zeros((1, vocab), "f4"),
                       onp.zeros((1,), "f4"),
@@ -1472,7 +1485,7 @@ class GenerationEngine:
             cache = self._recommit(cache)
             if self.decode_ticks > 1:
                 cache = self._warmup_multi(cache)
-            self._warm_samplers(int(lg.shape[-1]))
+            self._warm_samplers(lg)
             if self.speculative:
                 self._warmup_spec(cache)
             self._warmup_telemetry()
@@ -1573,6 +1586,12 @@ class GenerationEngine:
         tail = self._s_max % self._chunk
         return [tail, self._chunk] if tail else [self._chunk]
 
+    def _last_kw(self, last: bool) -> dict:
+        """``last=`` for a model that asked to be told (the
+        ``prefill_last`` key of its ``generation_support``), nothing for
+        any other."""
+        return {"last": last} if self._prefill_last else {}
+
     def _warmup_paged(self):
         """Compile the paged steady state against a throwaway cache:
         one fresh-prefill program per bucket <= the chunk width, one
@@ -1590,12 +1609,16 @@ class GenerationEngine:
                 continue
             _, cache = self.model.prefill_paged(
                 onp.zeros((1, sb), "i4"), sb, 0, row, cache,
-                fresh=True)
+                fresh=True, **self._last_kw(True))
             cache = self._recommit(cache)
         for w in self._chunk_widths():
-            _, cache = self.model.prefill_paged(
-                onp.zeros((1, w), "i4"), w, 0, row, cache, start=0)
-            cache = self._recommit(cache)
+            # a model told which chunk is last has a program for each
+            for kw in ([{"last": False}, {"last": True}]
+                       if self._prefill_last else [{}]):
+                _, cache = self.model.prefill_paged(
+                    onp.zeros((1, w), "i4"), w, 0, row, cache, start=0,
+                    **kw)
+                cache = self._recommit(cache)
         lg, cache = self.model.decode_step_paged(
             onp.zeros((self.max_slots,), "i4"),
             onp.ones((self.max_slots,), "i4"), cache)
@@ -1608,7 +1631,7 @@ class GenerationEngine:
                                                               cache))
             cache = self._recommit(self.model.copy_page_paged(1, 1,
                                                               cache))
-        self._warm_samplers(int(lg.shape[-1]))
+        self._warm_samplers(lg)
         if self.speculative:
             self._warmup_spec(cache)
 
@@ -2303,7 +2326,7 @@ class GenerationEngine:
                            start=start, tokens=n_valid, fresh=fresh):
             logits, self._cache = self.model.prefill_paged(
                 toks, n_valid, best, s.row, self._cache, start=start,
-                fresh=fresh,
+                fresh=fresh, **self._last_kw(not s.chunks),
                 **self._akw(self._adapter_idx[best:best + 1]))
             self._cache = self._recommit(self._cache)
         telemetry.hist_since("serving.generate.prefill", t0)
@@ -2347,10 +2370,13 @@ class GenerationEngine:
 
     def _pick_step_tokens(self, logits):
         """Per-slot next tokens from a decode step's raw (B, V)
-        logits: the host argmax when every active slot is greedy (the
-        pre-sampling engine's exact path), otherwise one fixed-shape
-        sampler call whose greedy rows are in-program argmax (the same
-        ints) and whose stochastic rows consume their slot's key."""
+        logits. Every active slot greedy: their argmax, taken where the
+        logits lie (``ops.sampling.greedy_tokens``), so that ``B`` ints
+        come to the host and not ``B x V`` floats (32 slots over a
+        vocabulary of 200,064 are 25.6 MB a tick). Otherwise one
+        fixed-shape sampler call whose greedy rows are in-program argmax
+        (the same ints) and whose stochastic rows consume their slot's
+        key."""
         if self._n_sampling:
             if self._part is not None:
                 # TP mode: hand the sampler HOST logits — the device
@@ -2367,7 +2393,10 @@ class GenerationEngine:
             # this buffer per admission
             self._keys = onp.array(nk, dtype="u4")
             return onp.asarray(tok)
-        return onp.asarray(logits).argmax(axis=-1)
+        if self._part is not None:
+            # TP mode: the logits are vocab-sharded (see above)
+            return onp.asarray(logits).argmax(axis=-1)
+        return onp.asarray(self._ensure_samplers()["greedy"](logits))
 
     def _decode_idxs(self):
         """The slots a decode/spec tick serves this iteration: every

@@ -290,3 +290,107 @@ def test_moe_grouped_matmul(one_chip, no_compile_cache, tokens, tm, kn):
         == moe.KERNEL_NAME, inst[:120]
     assert f"/moe/{moe.KERNEL_NAME}" in \
         inst.split("op_name=")[1].split('"')[1]
+
+
+# -- the phi4flash family at its published widths: the scan kernel of a
+# -- 512-token chunk over 5120 channels x 16 states, and the pool's readers
+# -- under grouped heads (40 query heads over a pool of 20 K heads x 64 and,
+# -- read as pairs, 10 V heads x 128; page 64)
+def test_ssm_chunk_scan(one_chip, no_compile_cache):
+    from mxnet_tpu.ops import ssm
+    t, ch, n = 512, 5120, 16
+    f32 = jnp.float32
+
+    def fn(x, dt, a, b, c, d, h0):
+        with jax.named_scope("ssm_scan"):
+            return ssm.selective_scan_pallas(x, dt, a, b, c, d, h0)
+
+    text = _compile(fn, one_chip, ((t, ch), f32), ((t, ch), f32),
+                    ((n, ch), f32), ((t, n), f32), ((t, n), f32),
+                    ((ch,), f32), ((n, ch), f32))
+    calls = [line.strip() for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 1, calls
+    inst = calls[0].removeprefix("ROOT ")
+    assert inst.split(" = ")[0].lstrip("%").split(".")[0] \
+        == ssm.KERNEL_NAME, inst[:120]
+    assert f"/ssm_scan/{ssm.KERNEL_NAME}" in \
+        inst.split("op_name=")[1].split('"')[1]
+
+
+GQ, GKV, GPAGE, GS = 40, 20, 64, 5120
+
+
+@pytest.mark.parametrize("kv_heads", [(GKV, GKV), (GKV, GKV // 2)],
+                         ids=["grouped", "differential"])
+def test_rows_decode_with_grouped_heads(one_chip, no_compile_cache,
+                                        kv_heads):
+    """The pool's readers of ``Phi4FlashModel``'s tick, ``gather_rows``
+    and ``rows_decode_attention``: 40 query heads over 20 K/V heads, and
+    the differential layers' reading of the same rows (V as 10 heads
+    twice as wide). The gathered view is never re-tiled into heads."""
+    slots, p_max = 8, GS // GPAGE
+    pool = ((slots * p_max + 1, GPAGE, GKV * D), jnp.bfloat16)
+
+    def fn(q, k, v, table, valid):
+        return att.rows_decode_attention(
+            q, att.gather_rows(k, table), att.gather_rows(v, table), valid,
+            kv_heads)
+
+    text = _compile(fn, one_chip, ((slots, GQ, D), jnp.bfloat16), pool,
+                    pool, ((slots, p_max), jnp.int32),
+                    ((slots, GS), jnp.bool_), kernel=False)
+    wide = GKV * D // kv_heads[1]
+    assert f"f32[{slots},{GQ},{wide}]" in text
+    assert f"[{slots},{GS},{GKV},{D}]" not in text
+
+
+def test_phi4flash_tick_has_the_operations_ssm_share_reads(
+        one_chip, no_compile_cache):
+    """``model.ssm_share`` finds the tick's state update by the SHAPE of
+    its result (a layer's float32 states of all 32 slots), which is the
+    compiler's to choose: the decode program of ``Phi4FlashModel`` at the
+    published widths and the cell's 32 slots, compiled for the described
+    v5e, holds operations whose short name the metric's pattern matches.
+    Should a compiler fuse the update into an operation of another shape,
+    this fails before the metric falls silent."""
+    import json
+    import os
+    from chipbench import trace_reduce
+    from mxnet_tpu.gluon.model_zoo.phi4flash import Phi4FlashModel
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "chipbench", "configs",
+                           "phi4-mini-flash-reasoning.json")) as f:
+        cfg = json.load(f)
+    with open(os.path.join(root, "chipbench", "layer_metrics",
+                           "model.ssm_share.json")) as f:
+        pattern = re.compile(json.load(f)["args"]["pattern"])
+    model, serve = cfg["model"], cfg["serve"]
+    net = Phi4FlashModel(**{k: model[k] for k in (
+        "vocab_size", "hidden_size", "num_hidden_layers",
+        "num_attention_heads", "num_key_value_heads", "intermediate_size",
+        "sliding_window", "mb_per_layer", "layer_norm_eps", "d_state",
+        "d_conv", "expand", "dt_rank", "prefill_chunk")},
+        max_length=serve["max_length"])
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one_chip), tree)
+
+    slots, ps = serve["max_slots"], serve["page_size"]
+    params = {("top" if g is None else g): {
+        k: jax.ShapeDtypeStruct(p.shape, jnp.dtype(p.dtype),
+                                sharding=one_chip) for k, p in ps_.items()}
+        for g, ps_ in net._params.items()}
+    cache = on_chip(jax.eval_shape(lambda: net.init_paged_cache(
+        slots, slots * serve["max_length"] // ps + 1, ps,
+        serve["max_length"])))
+    rows = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one_chip)
+    text = jax.jit(net._decode_body).lower(
+        params, rows, rows, cache).compile().as_text()
+    names = [trace_reduce.short(line.strip().removeprefix("ROOT "))[0]
+             for line in text.splitlines() if " = " in line]
+    # a fusion is an operation the device runs and the trace times
+    hits = [n for n in names
+            if n.startswith("fusion ") and pattern.search(n)]
+    assert hits, sorted({n for n in names if "5120]" in n})[:20]

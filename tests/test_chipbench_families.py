@@ -12,8 +12,8 @@ chipbench/tests`` on this tree; here they are left out of the cases taken
 over and superseded by the two cases marked ``SUPERSEDES`` below, which
 say the same for every family by whole words. The ``benchmark`` PR that
 edits the two in place deletes the two marked here (``PERF.md`` section 7).
-The ``dots3`` family is held to the same interface, and its configuration
-to the catalog's published keys.
+The ``dots3`` and ``phi4flash`` families are held to the same interface,
+and their configurations to the catalog's published keys.
 """
 import importlib.util
 import json
@@ -63,6 +63,9 @@ NAMES = {
     "dots3": ("Dots3Model", "model.dots3.trace", "families.dots3",
               "kv_lora_rank", "index_topk", "swa_kv_lora_rank",
               "router_experts", "n_routed_experts"),
+    "phi4flash": ("Phi4FlashModel", "model.phi4flash.trace",
+                  "families.phi4flash", "mb_per_layer", "sliding_window",
+                  "d_state", "dt_rank", "ssm_scan_call"),
 }
 
 
@@ -205,3 +208,90 @@ def test_dots3_parameter_count_is_the_share_the_configuration_states(config):
         for name, shape, _ in weights.layer_leaves(s, i)
         if weights.float32_in_program(s, i, name))
     assert (2 * n + 2 * wide) / (2 * n) < 1.01
+
+
+# -- the phi4flash configuration: the catalog's row, nothing cut -------------
+PHI = "phi4-mini-flash-reasoning"
+
+
+@pytest.fixture(scope="module")
+def phi_config():
+    return harness.load_json("configs", PHI + ".json")
+
+
+def test_phi4flash_configuration_is_the_catalog_entry_key_for_key(
+        phi_config):
+    assert phi_config["reduced"] == [] and phi_config["published"] == {}
+    assert "one chip, one replica" in phi_config["deployment"]
+    if not os.path.isfile(CATALOG):
+        pytest.skip("no catalog beside the model-configs guide here")
+    with open(CATALOG) as f:
+        rows = [json.loads(line) for line in f]
+    entry = next(r for r in rows if r["name"] == "Phi-4-mini-flash-reasoning")
+    assert phi_config["source"] == entry["source_url"]
+    for key, value in entry["config"].items():
+        assert phi_config[key] == value, key
+        assert phi_config["model"][key] == value, key
+    bench = harness.load_benchmark()
+    listed = next(c for c in bench["configs"] if c["name"] == PHI)
+    assert listed["reduced"] == [] and listed["source"] == entry["source_url"]
+
+
+def test_phi4flash_configuration_states_its_keys_twice_alike(phi_config):
+    """As for ``dots3``: the published keys at the top for the contract,
+    and in the ``model`` group for the family; beside them in the group
+    only what ``assumed`` accounts for."""
+    model = phi_config["model"]
+    extra = {"d_state", "d_conv", "expand", "dt_rank", "initializer_range",
+             "prefill_chunk"}
+    for key, value in model.items():
+        if key not in extra:
+            assert phi_config[key] == value, key
+    assert extra <= set(model)
+    assert model["dt_rank"] == -(-model["hidden_size"] // 16)
+    # the ring is built for the chunk the engine is given
+    assert model["prefill_chunk"] == phi_config["serve"]["prefill_chunk"]
+    assert phi_config["serve"]["prefix_cache"] is False
+    for entry in phi_config["assumed"].values():
+        assert len(entry) > 20
+
+
+def test_phi4flash_parameter_count_is_the_whole_model(phi_config):
+    weights = harness.family(phi_config, "weights")
+    s = weights.sizes(phi_config["model"])
+    n = weights.parameter_count(s)
+    assert abs(n - 3.85e9) < 0.01 * 3.85e9
+    kinds = [weights.kind(s, i) for i in range(s["L"])]
+    assert [kinds.count(k) for k in ("ssm", "swa", "full", "gmu",
+                                     "cross")] == [9, 8, 1, 7, 7]
+    assert kinds[16] == "ssm" and kinds[17] == "full"
+    # bytes held by the program: two a parameter, and two more for the
+    # leaves it keeps in float32 (A_log, D, b_dt, the lam vectors)
+    wide = sum(
+        int(np.prod(shape)) for i in range(s["L"])
+        for name, shape, _ in weights.layer_leaves(s, i)
+        if name in weights.FLOAT32_IN_PROGRAM)
+    assert (2 * n + 2 * wide) / (2 * n) < 1.001
+
+
+def test_phi4flash_cell_reads_its_own_programs_and_kernel():
+    bench = harness.load_benchmark()
+    cell = PHI + ".serve.longgen"
+    mine = {m["name"] for m in bench["per_layer"]
+            if m.get("workloads") == [cell]}
+    assert mine == {"model.phi4flash_decode_device_ms", "model.ssm_share"}
+    # the chunk programs' device time and the scan kernel's roofline are
+    # not metrics of the cell: its traced 5 s hold ticks alone (PERF.md
+    # section 7 has the two files a benchmark PR would add); the
+    # kernel's cost function is there for them, under the kernel's name
+    costs = harness.family({"family": "phi4flash"}, "costs")
+    assert callable(costs.ssm_scan_call)
+    from mxnet_tpu.ops import ssm
+    share = harness.load_json("layer_metrics", "model.ssm_share.json")
+    assert ssm.KERNEL_NAME in share["args"]["pattern"]
+    tick = harness.load_json(
+        "layer_metrics", "model.phi4flash_decode_device_ms.json")
+    rx = re.compile(tick["args"]["module"])
+    assert rx.search("jit_phi4flash_paged_decode(6)") \
+        and not rx.search("jit_phi4flash_paged_chunk(123)") \
+        and not rx.search("jit_phi4flash_paged_chunk_last(45)")

@@ -622,7 +622,7 @@ class _SlowToFetch:
 
 
 @pytest.mark.parametrize("mode,program,kw", [
-    ("plain", "decode_step", {}),
+    ("plain", None, {}),
     ("multi-tick", "decode_multi", {"decode_ticks": 3}),
     ("speculative", "verify_commit", {"spec_k": 2}),
 ], ids=["plain", "multi-tick", "speculative"])
@@ -630,21 +630,27 @@ def test_decode_histogram_closes_after_the_host_sync(net, monkeypatch,
                                                      mode, program, kw):
     """``serving.generate.decode`` (the SLO tracker's time per output
     token) is the tick as a caller feels it in every engine mode: with
-    the tick's result 20 ms late (in plain mode: ``_pick_step_tokens``
-    delayed by 20 ms) no sample is shorter. Closed before the sync, as
-    the plain tick did, it timed the enqueue."""
+    the tick's result 20 ms late (in plain mode: the tokens picked on
+    the device from its logits) no sample is shorter. Closed before the
+    sync, as the plain tick did, it timed the enqueue."""
     if mode == "speculative":
         kw = dict(kw, draft_model=gpt_small(
             vocab_size=VOCAB, units=16, num_layers=1, num_heads=4,
             max_length=128))
         kw["draft_model"].initialize(mx.init.Xavier())
     eng = GenerationEngine(net, max_slots=2, max_length=64, **kw).warmup()
-    real = getattr(net, program)
+    if program is None:
+        samplers = eng._ensure_samplers()
+        pick = samplers["greedy"]
+        monkeypatch.setitem(samplers, "greedy",
+                            lambda logits: _SlowToFetch(pick(logits)))
+    else:
+        real = getattr(net, program)
 
-    def slow(*args, **kwargs):
-        first, *rest = real(*args, **kwargs)
-        return (_SlowToFetch(first), *rest)
-    monkeypatch.setattr(net, program, slow)
+        def slow(*args, **kwargs):
+            first, *rest = real(*args, **kwargs)
+            return (_SlowToFetch(first), *rest)
+        monkeypatch.setattr(net, program, slow)
     telemetry.reset()
     try:
         eng.submit(_prompt(6), max_new_tokens=7).result(timeout=60)

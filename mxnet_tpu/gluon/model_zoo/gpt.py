@@ -37,7 +37,15 @@ writes are REDIRECTED to the reserved scrap page 0, because a freed
 slot's stale table row may alias pages owned by another slot),
 ``peek_logits_paged`` (first token of a fully-cached prompt, zero
 prefill, no donation), and the ``bind_slot_paged``/``copy_page_paged``
-table/COW helpers. Page ownership (refcounts, prefix index, COW
+table/COW helpers. Two readers attend a gathered view, and
+``paged_decode_attention`` chooses by what the trace can see: the
+one-query programs (``decode_paged``, the multi-tick scan's tick,
+``peek_paged``) contract the pool's ROWS as they lie
+(``ops.attention.rows_decode_attention``: no re-tiling of a view into
+heads; to float32 rounding the dense cache's arithmetic, not bit for
+bit), while ``prefill_chunk`` and ``verify_paged`` (several queries a
+slot: ``_gathered_attention``) and every program traced for a tp mesh
+attend the view split into heads, as the dense cache's reader does. Page ownership (refcounts, prefix index, COW
 arming) is the engine's job — serving/paging.py; the model layer only
 guarantees fixed shapes and donated in-place pool updates.
 
@@ -70,9 +78,11 @@ mlp dim, embeddings/lm_head by vocab) that
 generation closures are TP-aware by construction: parameters and the
 KV cache (sharded over the HEADS axis) enter as COMMITTED sharded
 arrays, so the same jitted closures compile SPMD over the mesh —
-no second code path, and greedy output stays token-identical to the
-unsharded engine (the ``tp`` partial-sum reduction order is the only
-numeric difference).
+no second set of closures, and greedy output stays token-identical to
+the unsharded engine (the ``tp`` partial-sum reduction order, and the
+paged tick's reader, are the only numeric differences: traced under
+``ops.attention.jnp_only()`` the tick keeps the view split into heads,
+which a pool sharded by heads attends without a collective).
 """
 from __future__ import annotations
 

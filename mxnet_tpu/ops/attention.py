@@ -25,7 +25,11 @@ here because they shape the core design on TPU:
   all heads, row-major on the chip: "the paged pool's layout" below)
   and a per-slot page table. Each slot's view is gathered from the
   pool and attended on the jnp path, on every backend: no Pallas
-  kernel (PERF.md §6, PRs 25 and 28).
+  kernel (PERF.md §6, PRs 25 and 28). One query a slot (the decode
+  tick) attends the gathered ROWS as they lie
+  (`rows_decode_attention`); several queries a slot, and every program
+  traced over a tp mesh, attend the view split into heads
+  (`gather_pages`).
 
 All shapes are (batch, heads, seq, head_dim). `kv_len` arguments mean
 "only the first kv_len entries of the key/value buffer are real" —
@@ -46,6 +50,8 @@ import threading
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+from .. import telemetry
 
 NEG_INF = -1e30
 
@@ -264,8 +270,11 @@ def jnp_only():
     it per shard, which the decode kernels do not have — so a
     mesh-sharded engine traces its closures under this context and the
     kernels stay on the (numerically identical) jnp paths, partitioned
-    by GSPMD like any other op. Scoped per thread (trace-time only):
-    an unsharded engine tracing concurrently still takes Pallas."""
+    by GSPMD like any other op. Under it ``paged_decode_attention``
+    also keeps the view split into heads for a one-query tick: a tp
+    pool shards a row's width by heads, and the rows reader would
+    contract over it. Scoped per thread (trace-time only): an unsharded
+    engine tracing concurrently still takes Pallas."""
     prev = getattr(_JNP_ONLY, "on", False)
     _JNP_ONLY.on = True
     try:
@@ -607,12 +616,21 @@ def write_rows(pool, page, offset, rows):
 
 
 def gather_pages(pool, table, num_heads):
-    """Materialize each slot's logical KV view from a paged pool:
-    ``pool`` (n_pages, page_size, H * D) + ``table`` (B, P_max) int32
-    -> (B, H, P_max * page_size, D). Logical position ``t`` of slot
-    ``b`` lives at ``pool[table[b, t // ps], t % ps, h * D:(h + 1) * D]``.
-    Free table entries point at the reserved scrap page (id 0) — their
-    rows are garbage that per-row length masking must exclude."""
+    """Materialize each slot's logical KV view from a paged pool, SPLIT
+    INTO HEADS: ``pool`` (n_pages, page_size, H * D) + ``table``
+    (B, P_max) int32 -> (B, H, P_max * page_size, D). Logical position
+    ``t`` of slot ``b`` lives at
+    ``pool[table[b, t // ps], t % ps, h * D:(h + 1) * D]``. Free table
+    entries point at the reserved scrap page (id 0) — their rows are
+    garbage that per-row length masking must exclude.
+
+    On the chip the split is a re-tiling the compiler materializes
+    (``H * D``-wide rows to ``D``-wide heads, half of every 128-lane
+    tile empty at D = 64), which costs more than the attention after
+    it. Who pays it: several queries a slot (a chunk, a speculative
+    verify) and programs traced over a tp mesh, whose pools are sharded
+    by heads; a one-query tick reads ``gather_rows`` instead
+    (``paged_decode_attention``)."""
     g = gather_rows(pool, table)
     b, s, hd = g.shape
     return g.reshape(b, s, num_heads, hd // num_heads).transpose(0, 2, 1, 3)
@@ -653,12 +671,15 @@ def gather_kv(k_pool, v_pool, table, num_heads, k_scale=None,
     return k, v
 
 
-def rows_decode_attention(q, k_rows, v_rows, valid, kv_heads, scale=None):
+def rows_decode_attention(q, k_rows, v_rows, valid, kv_heads, scale=None,
+                          k_scale=None, v_scale=None):
     """One query a row against K/V ROWS AS THEY LIE in a pool or a ring:
     ``q`` (B, Hq, D); ``k_rows`` (B, S, Hk * D) and ``v_rows`` (B, S, Hv *
     Dv), a position's heads side by side; ``valid`` (B, S); ``kv_heads``
     ``(Hk, Hv)``, the rows' head counts (heads grouped as in
-    ``masked_attention``). Returns (B, Hq, Dv) float32.
+    ``masked_attention``). Returns (B, Hq, Dv) float32. The one reader
+    of every family's one-query tick: ``paged_decode_attention`` (GPT)
+    and ``Phi4FlashModel`` call it.
 
     The rows are never split into heads: the query is laid out
     block-diagonally, ``(B, Hq, Hk * D)`` with head ``h`` in the columns
@@ -668,18 +689,36 @@ def rows_decode_attention(q, k_rows, v_rows, valid, kv_heads, scale=None):
     times the multiply-adds of the split form, which a decode tick has to
     spare (one query a row: the rows' bytes bound it), and saves the
     re-tiling of every view from ``H * D``-wide rows to ``D``-wide heads,
-    which cost more than the attention itself (PERF.md section 5)."""
+    which cost more than the attention itself (PERF.md section 5). Where
+    ``valid`` is false K may hold anything, NaN too: its score is masked
+    before the softmax, and the query's zeros only ever meet other
+    heads' columns of positions that ARE valid. V has to be finite
+    everywhere (0 * NaN is NaN).
+
+    ``k_scale`` (B, Hk, S) and ``v_scale`` (B, Hv, S) mark INT8 rows, a
+    position's scale a head (``expand_page_scales``). No dequantized view
+    is built: the integers are contracted as they lie, K's scale
+    multiplies the scores and V's the probabilities, which is the
+    arithmetic of dequantizing each head's columns first."""
     hq, d = q.shape[1], q.shape[2]
     hk, hv = kv_heads
     scale = scale if scale is not None else 1.0 / math.sqrt(d)
+    if k_scale is not None:
+        # every int8 value is a bf16 value: the product reads the pool's
+        # bytes and converts on the way
+        k_rows, v_rows = k_rows.astype(q.dtype), v_rows.astype(q.dtype)
     own_k = jnp.arange(hq)[:, None] // (hq // hk) == jnp.arange(hk)[None]
     own_v = jnp.arange(hq)[:, None] // (hq // hv) == jnp.arange(hv)[None]
     q_rows = jnp.where(own_k[None, :, :, None], q[:, :, None, :], 0) \
         .reshape(q.shape[0], hq, hk * d)
     s = jnp.einsum("bhc,bsc->bhs", q_rows, k_rows,
                    preferred_element_type=jnp.float32) * scale
-    p = _masked_softmax(s, valid[:, None, :]).astype(v_rows.dtype)
-    o = jnp.einsum("bhs,bsc->bhc", p, v_rows,
+    if k_scale is not None:
+        s = s * jnp.repeat(k_scale, hq // hk, axis=1)
+    p = _masked_softmax(s, valid[:, None, :])
+    if v_scale is not None:
+        p = p * jnp.repeat(v_scale, hq // hv, axis=1)
+    o = jnp.einsum("bhs,bsc->bhc", p.astype(v_rows.dtype), v_rows,
                    preferred_element_type=jnp.float32)
     o = o.reshape(q.shape[0], hq, hv, -1)
     return jnp.sum(jnp.where(own_v[None, :, :, None], o, 0.0), axis=2)
@@ -695,31 +734,67 @@ def paged_decode_attention(q, k_pool, v_pool, table, lengths,
     (B,) int32 marks each slot's valid token prefix: every query row
     attends keys ``[0, length)`` (``Sq > 1`` is a speculative verify).
     Past a length K may hold anything (its scores are masked); V has to
-    be finite there, as for the chunk programs: 0 * NaN is NaN.
+    be finite there, as for the chunk programs: 0 * NaN is NaN. A slot
+    of length 0 returns zeros. The result has ``q``'s shape and dtype.
 
-    It IS ``decode_attention``'s jnp path over the gathered per-slot
-    view (gather + the same masked softmax), on every backend, so a
-    paged cache holding the same values produces bit-identical logits
-    to the dense cache on that path. The gather moves all ``P_max``
-    pages of every slot whatever its length; a page is one contiguous
-    block of ``page_size`` rows of all heads, read from the pool as the
-    write left it: row-major, with no copy of a pool on either side
-    (tests/test_chip_compile.py counts them). PR 25's Pallas kernel,
-    which moved only held pages, lost to this path because the pool
-    then was ``(n_pages, H, page_size, D)``, which the TPU keeps pages
-    minor-most, and a kernel's operand has to be row-major: a copy of
-    each whole pool a layer cost more than the kernel saved (PERF.md
-    §6). This pool is row-major already, so that reason is gone; a
-    kernel is an issue of its own (ROADMAP S4).
+    The gather moves all ``P_max`` pages of every slot whatever its
+    length; a page is one contiguous block of ``page_size`` rows of all
+    heads, read from the pool as the write left it: row-major, with no
+    copy of a pool on either side (tests/test_chip_compile.py counts
+    them). What attends the gathered view is chosen by what the trace
+    can see, and counted where it is chosen (trace-time counters
+    ``ops.attention.paged_decode.rows`` / ``.gathered``,
+    docs/OBSERVABILITY.md):
+
+    - **``Sq == 1``** (the decode tick, a multi-tick scan's tick,
+      ``peek_paged``): ``rows_decode_attention`` over ``gather_rows``,
+      the rows as they lie. No view is split into heads, so the program
+      holds no ``[B, S, H, D]`` re-tiling, which was the largest device
+      operation of a tick (PERF.md §6, PR 32). Scores are float32
+      products; the dense cache's ``decode_attention`` rounds a bf16
+      product to bf16 first, so the two agree to rounding, not bit for
+      bit (float32: a few ulp).
+    - **``Sq > 1``**, and **every program traced under ``jnp_only()``**
+      (an engine over a tp mesh): ``gather_kv`` + the masked softmax of
+      ``decode_attention``'s jnp path over the view split into heads.
+      With ``Sq`` queries a row the block-diagonal form spends
+      ``H * Sq`` rows of MXU work, and a tp pool shards a row's width by
+      heads, so a contraction over whole rows would add an all-reduce of
+      the scores a layer that the split form does not need.
+
+    PR 25's Pallas kernel, which moved only held pages, lost to the
+    gather because the pool then was ``(n_pages, H, page_size, D)``,
+    which the TPU keeps pages minor-most, and a kernel's operand has to
+    be row-major: a copy of each whole pool a layer cost more than the
+    kernel saved (PERF.md §6). This pool is row-major already, so that
+    reason is gone; a kernel over held pages that contracts rows as
+    they lie is an issue of its own (ROADMAP S4).
 
     ``k_scale``/``v_scale`` (n_pages, H) fp32 mark an INT8 pool (half
-    the HBM per cached token vs bf16, a quarter vs fp32), dequantized
-    after the gather with each page's per-head scale."""
+    the HBM per cached token vs bf16, a quarter vs fp32): the rows
+    reader scales scores and probabilities by each page's per-head
+    scale and builds no dequantized view; the gathered reader
+    dequantizes the view to float32 after the gather."""
     scale_v = scale if scale is not None else 1.0 / math.sqrt(q.shape[-1])
-    k, v = gather_kv(k_pool, v_pool, jnp.asarray(table, jnp.int32),
-                     q.shape[1], k_scale, v_scale)
-    return _decode_fwd_jnp(q, k, v, jnp.asarray(lengths, jnp.int32),
-                           scale_v)
+    table = jnp.asarray(table, jnp.int32)
+    lengths = jnp.asarray(lengths, jnp.int32)
+    h = q.shape[1]
+    # the counters are bumped as a program traces, never in steady state
+    if q.shape[2] != 1 or getattr(_JNP_ONLY, "on", False):
+        telemetry.counter("ops.attention.paged_decode.gathered")
+        k, v = gather_kv(k_pool, v_pool, table, h, k_scale, v_scale)
+        return _decode_fwd_jnp(q, k, v, lengths, scale_v)
+    telemetry.counter("ops.attention.paged_decode.rows")
+    k_rows, v_rows = gather_rows(k_pool, table), gather_rows(v_pool, table)
+    col = lax.broadcasted_iota(jnp.int32, k_rows.shape[:2], 1)
+    if k_scale is not None:
+        ps = pool_page_size(k_pool)
+        k_scale = expand_page_scales(k_scale, table, ps)
+        v_scale = expand_page_scales(v_scale, table, ps)
+    o = rows_decode_attention(q[:, :, 0], k_rows, v_rows,
+                              col < lengths[:, None], (h, h), scale_v,
+                              k_scale, v_scale)
+    return o[:, :, None, :].astype(q.dtype)
 
 
 def chunked_prefill_attention(q, k, v, start, scale=None):

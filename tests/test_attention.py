@@ -246,16 +246,37 @@ def test_transformer_cell_trains_sequence_parallel():
     assert losses[-1] < losses[0] * 0.7, losses[:3] + losses[-3:]
 
 
-def test_paged_decode_attention_matches_gathered_reference():
-    """Paged decode over a (pool, table) cache == dense decode over the
-    gathered per-slot view, bit for bit on the jnp path (the paged
-    engine's token-identity to the dense engine rests on this), with
-    empty slots returning zeros."""
+def _ulps(out, ref, dtype):
+    """Largest gap between two (B, ...) results, a slot at a time, in
+    units of ``dtype``'s last place at the slot's largest magnitude (a
+    weighted sum rounds at the scale of its summands, not of an element
+    that cancelled)."""
+    out, ref = (onp.asarray(x, "f8") for x in (out, ref))
+    worst = 0.0
+    for o, r in zip(out, ref):
+        mag = onp.abs(r).max()
+        if mag == 0:
+            assert (o == 0).all()
+            continue
+        ulp = 2.0 ** (onp.floor(onp.log2(mag)) - jnp.finfo(dtype).nmant)
+        worst = max(worst, float(onp.abs(o - r).max() / ulp))
+    return worst
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_paged_decode_attention_matches_gathered_reference(dtype):
+    """The paged tick over a (pool, table) cache agrees with the dense
+    ``decode_attention`` over the gathered per-slot view to the
+    rounding of the dtype (the paged engine's greedy identity with the
+    dense engine rests on this), with empty slots returning exactly
+    zeros. The tick reads the rows as they lie
+    (``rows_decode_attention``: float32 products summed over a whole row
+    in another order), so the two are no longer equal bit for bit."""
     onp.random.seed(6)
     B, H, D, PS, NP = 4, 2, 32, 16, 40
     P_MAX = 8                                      # capacity 128
     mk = lambda *s: jnp.asarray(  # noqa: E731
-        onp.random.randn(*s).astype("float32") * 0.5)
+        onp.random.randn(*s).astype("float32") * 0.5).astype(dtype)
     kpool, vpool = mk(NP, PS, H * D), mk(NP, PS, H * D)
     rng = onp.random.RandomState(7)
     table = jnp.asarray(rng.permutation(onp.arange(1, NP))
@@ -267,8 +288,10 @@ def test_paged_decode_attention_matches_gathered_reference():
     assert kg.shape == (B, H, P_MAX * PS, D)
     ref = at.decode_attention(q, kg, vg, lengths)
     out = at.paged_decode_attention(q, kpool, vpool, table, lengths)
-    assert (onp.asarray(out) == onp.asarray(ref)).all()
-    assert onp.abs(onp.asarray(out[0])).max() == 0.0   # empty slot
+    assert out.dtype == ref.dtype == q.dtype
+    # float32: a few ulp; bfloat16: identical or one bf16 ulp
+    assert _ulps(out, ref, dtype) <= (4 if dtype == "float32" else 1)
+    assert onp.abs(onp.asarray(out[0], "f4")).max() == 0.0   # empty slot
 
 
 # every length the masks treat apart, in one batch: an empty slot, one
@@ -350,6 +373,37 @@ def test_paged_decode_attention_parity(heads, sq, dtype):
         else dict(rtol=2e-2, atol=4e-3)
     onp.testing.assert_allclose(
         out, _paged_reference(q, kpool, vpool, table, lengths), **tol)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("heads", [2, 16, 20])
+def test_paged_tick_rows_reader_matches_gathered_reader(heads, dtype):
+    """The two readers of one paged cache say the same: the one-query
+    tick (``rows_decode_attention`` over ``gather_rows``, the rows as
+    they lie) against the view split into heads (what ``Sq > 1`` and
+    tp-mesh programs attend), on ``_paged_case``: every length in
+    ``_PAGED_LENGTHS``, the shared prefix, NaN K and large V past the
+    lengths. The trace-time counters say which reader a call took."""
+    from mxnet_tpu import telemetry
+    q, kpool, vpool, table, lengths = _paged_case(heads, 1, dtype)
+    telemetry.reset()
+    rows = at.paged_decode_attention(q, kpool, vpool, table, lengths)
+    assert telemetry.counter_value("ops.attention.paged_decode.rows") == 1
+    assert telemetry.counter_value(
+        "ops.attention.paged_decode.gathered") == 0
+    with at.jnp_only():               # as an engine over a tp mesh traces
+        gathered = at.paged_decode_attention(q, kpool, vpool, table,
+                                             lengths)
+    assert telemetry.counter_value(
+        "ops.attention.paged_decode.gathered") == 1
+    assert rows.dtype == gathered.dtype == q.dtype
+    assert rows.shape == gathered.shape == q.shape
+    assert (onp.asarray(rows[0], "f4") == 0).all()   # the empty slot
+    assert onp.isfinite(onp.asarray(rows, "f4")).all()
+    tol = dict(rtol=2e-5, atol=2e-6) if dtype == "float32" \
+        else dict(rtol=2e-2, atol=4e-3)
+    onp.testing.assert_allclose(onp.asarray(rows, "f4"),
+                                onp.asarray(gathered, "f4"), **tol)
 
 
 def test_paged_decode_attention_under_jit_follows_lengths():

@@ -3,8 +3,8 @@ has none), compiled for a DESCRIBED v5e at GPT-2 large shapes (20
 heads x 64, 1280 units, MLP 5120, vocabulary 50257, 1024 positions,
 page 16, 8 slots); and the paged decode and chunk PROGRAMS of a
 two-layer ``GPTModel`` at GPT-2 large and medium widths, whose text
-says which layout the chip gives the KV pools and whether a program
-copies one.
+says which layout the chip gives the KV pools, whether a program
+copies one, and whether the tick splits a gathered view into heads.
 
 Interpret-mode parity tests cannot see what the chip's compiler
 refuses: a block shape off the (8, 128) tiling, a scalar operand in
@@ -107,7 +107,11 @@ def test_dense_decode(one_chip, no_compile_cache, kind, sq):
 def test_paged_decode(one_chip, no_compile_cache, kind, sq, heads):
     """GPT-2 large's 20 heads and GPT-2 medium's 16. Paged decode is
     the compiler's own gather + masked softmax: no kernel of ours is
-    in the program."""
+    in the program. ``sq == 1`` (the tick) takes the rows reader,
+    ``rows_decode_attention`` over ``gather_rows``, an int8 pool with
+    its scales on scores and probabilities; ``sq > 1`` (a verify) takes
+    the gathered reader, ``gather_kv`` split into heads, an int8 pool
+    dequantized to a float32 view."""
     qdt = jnp.float32 if kind == "int8" else _KV[kind]
     pool = ((N_PAGES, PAGE, heads * D), _KV[kind])
 
@@ -116,9 +120,11 @@ def test_paged_decode(one_chip, no_compile_cache, kind, sq, heads):
             q, k, v, table, lens, k_scale=sc[0] if sc else None,
             v_scale=sc[1] if sc else None)
 
-    _compile(fn, one_chip, ((B, heads, sq, D), qdt), pool, pool,
-             ((B, P_MAX), jnp.int32), ((B,), jnp.int32),
-             *_scales(kind, (N_PAGES, heads)), kernel=False)
+    text = _compile(fn, one_chip, ((B, heads, sq, D), qdt), pool, pool,
+                    ((B, P_MAX), jnp.int32), ((B,), jnp.int32),
+                    *_scales(kind, (N_PAGES, heads)), kernel=False)
+    # the tick never splits a view into heads; a verify does
+    assert (f"[{B},{S_MAX},{heads},{D}]" in text) == (sq > 1)
 
 
 # -- the paged programs themselves: where the pools lie ------------------
@@ -126,12 +132,23 @@ _HLO_DTYPE = {"bf16": "bf16", "fp32": "f32", "int8": "s8"}
 CHUNK = 32
 
 
+_PAGED_PROGRAMS = {}
+
+
 def _paged_program(sharding, heads, kind, role):
     """``gpt_paged_<role>`` of a two-layer GPTModel ``heads`` x 64 wide
     as the benchmark's engine runs it (bf16 compute, page 16, 8 slots,
     1024 positions, chunks of 32; a small vocabulary, which no pool
     sees), compiled for the described chip. Returns the compiled text
-    and the pool's shape as that text prints it."""
+    and the pool's shape as that text prints it. Several tests read one
+    program's text: each is compiled once a process."""
+    if (heads, kind, role) not in _PAGED_PROGRAMS:
+        _PAGED_PROGRAMS[heads, kind, role] = _compile_paged_program(
+            sharding, heads, kind, role)
+    return _PAGED_PROGRAMS[heads, kind, role]
+
+
+def _compile_paged_program(sharding, heads, kind, role):
     from mxnet_tpu.gluon.model_zoo.gpt import GPTModel
     from mxnet_tpu.random_state import next_key
     net = GPTModel(512, units=heads * D, num_layers=2, num_heads=heads,
@@ -179,6 +196,31 @@ def test_paged_program_copies_no_pool(one_chip, no_compile_cache, heads,
     assert text.count("may-alias") >= 4
     copies = re.findall(r"= (\w+\[[\d,]*\])\S* copy\(", text)
     assert pool not in copies, copies
+
+
+@pytest.mark.parametrize("kind", list(_KV))
+@pytest.mark.parametrize("heads", [H, 16], ids=["large", "medium"])
+def test_paged_tick_splits_no_view_into_heads(one_chip, no_compile_cache,
+                                              heads, kind):
+    """The decode tick attends the gathered rows as they lie
+    (``rows_decode_attention``): the compiled program holds no operation
+    (reshape, copy or fusion) whose result is ``[8,1024,H,64]``, the
+    re-tiling of a view from ``H * 64``-wide rows to 64-wide heads that
+    was the largest device operation of a tick (5.06 of 12.3 ms at 20
+    heads, 4.05 of 9.5 at 16: PERF.md §6, PR 32), nor its transpose, and
+    an int8 pool no float32 view. The chunk program still splits its
+    one slot's view (``[1,1024,H,64]``). Metadata of a compile, not a
+    chip reading."""
+    text, _pool = _paged_program(one_chip, heads, kind, "decode")
+    results = re.findall(r"= \(?(\w+\[[\d,]*\])", text)
+    split = [r for r in results
+             if re.search(rf"\[{B},({S_MAX},{heads}|{heads},{S_MAX}),{D}\]",
+                          r)]
+    assert not split, sorted(set(split))
+    if kind == "int8":
+        assert f"f32[{B},{S_MAX},{heads * D}]" not in results
+    chunk, _pool = _paged_program(one_chip, heads, kind, "chunk")
+    assert f"[1,{S_MAX},{heads},{D}]" in chunk
 
 
 @pytest.mark.parametrize("role", ["decode", "chunk"])

@@ -166,7 +166,11 @@ def test_prefix_index_lru_bound():
 def test_paged_fresh_prefill_bitwise_matches_dense(net):
     """The fresh (single-chunk, unshared) paged prefill runs the dense
     prefill's exact computation: logits and cached K/V values are
-    bitwise identical — the foundation of engine token-identity."""
+    bitwise identical — the foundation of engine token-identity. The
+    decode ticks after it agree to float32 rounding and pick the same
+    greedy token: the paged tick attends the pool's rows as they lie
+    (``ops.attention.rows_decode_attention``), which sums the same
+    products in another order than the dense cache's reader."""
     rng = onp.random.RandomState(2)
     prompt = _prompt(rng, 11)
     padded = onp.zeros((1, 16), "i4")
@@ -179,7 +183,7 @@ def test_paged_fresh_prefill_bitwise_matches_dense(net):
     lg_p, paged = net.prefill_paged(padded, 11, 2, row, paged,
                                     fresh=True)
     assert (onp.asarray(lg_d) == onp.asarray(lg_p)).all()
-    # decode stays bitwise identical step for step
+    # decode stays identical to rounding, and in its token, step for step
     tok = int(onp.asarray(lg_d)[0].argmax())
     active = onp.zeros(SLOTS, "i4")
     active[2] = 1
@@ -188,8 +192,11 @@ def test_paged_fresh_prefill_bitwise_matches_dense(net):
         step[2] = tok
         lgd, dense = net.decode_step(step, dense)
         lgp, paged = net.decode_step_paged(step, active, paged)
-        assert (onp.asarray(lgd)[2] == onp.asarray(lgp)[2]).all()
+        onp.testing.assert_allclose(onp.asarray(lgp)[2],
+                                    onp.asarray(lgd)[2],
+                                    rtol=2e-5, atol=2e-6)
         tok = int(onp.asarray(lgd)[2].argmax())
+        assert int(onp.asarray(lgp)[2].argmax()) == tok
 
 
 @pytest.mark.parametrize("write", ["fresh", "chunk"])
@@ -199,7 +206,9 @@ def test_paged_pool_holds_each_position_where_the_layout_says(net, write):
     h * Dh:(h + 1) * Dh]`` — held against the dense cache's
     ``k[b, h, t]`` after a prefill (one fresh chunk: bitwise; two
     chunks over the gathered view: to rounding) and four decode
-    steps, for K and V of every layer."""
+    steps (to float32 rounding past layer 0: the paged tick's reader
+    sums in another order than the dense one), for K and V of every
+    layer."""
     rng = onp.random.RandomState(7)
     n, slot, heads = 21, 2, 4
     prompt = _prompt(rng, n)
@@ -237,8 +246,11 @@ def test_paged_pool_holds_each_position_where_the_layout_says(net, write):
             for t in range(length):
                 got = pool[table[slot, t // PS], t % PS] \
                     .reshape(heads, dh)
-                if write == "fresh":
+                if write == "fresh" and t < n:
                     assert (got == cache[slot, :, t]).all(), (key, t)
+                elif write == "fresh":
+                    onp.testing.assert_allclose(
+                        got, cache[slot, :, t], rtol=2e-5, atol=2e-6)
                 else:
                     onp.testing.assert_allclose(
                         got, cache[slot, :, t], rtol=2e-3, atol=2e-4)

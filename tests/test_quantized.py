@@ -202,16 +202,15 @@ def test_int8_kv_decode_attention_pallas_parity():
     assert onp.abs(pg_jnp - ref).max() < 0.05
 
 
-@pytest.mark.parametrize("sq", [1, 5], ids=["decode", "verify"])
-@pytest.mark.parametrize("heads", [16, 20])
-def test_int8_paged_decode_attention_parity(heads, sq):
-    """Paged decode over an int8 pool matches the same attention over
-    the pool dequantized by hand, at the served head counts: lengths
-    0, 1, a page, a page + 1, six pages, one more, ragged, the full
-    table; a page two slots share; on the scrap page garbage values
-    under a NaN scale for K and a large one for V (finite: 0 * NaN is
-    NaN)."""
-    from mxnet_tpu.ops import attention as att
+def _int8_paged_case(heads, sq, dtype="float32"):
+    """An int8 paged cache at a served head count: lengths 0, 1, a page,
+    a page + 1, six pages, one more, ragged, the full table; a page two
+    slots share; on the scrap page garbage values under a NaN scale for
+    K and a large one for V (finite: 0 * NaN is NaN). Returns ``q``
+    (``dtype``), the two int8 pools, their scale tables, the table, the
+    lengths and the two pools dequantized by hand with the scrap page's
+    scale still finite."""
+    import jax.numpy as jnp
     rng = onp.random.RandomState(5)
     ps, d, p_max = 16, 64, 8
     lengths = onp.asarray([0, 1, 16, 17, 96, 97, 77, 128], "i4")
@@ -226,18 +225,63 @@ def test_int8_paged_decode_attention_parity(heads, sq):
         held = -(-int(n) // ps)
         table[i, :held] = [free.pop() for _ in range(held)]
     table[6, :2] = table[4, :2]                    # a shared prefix
-    q = rng.randn(b, heads, sq, d).astype("f4")
+    q = jnp.asarray(rng.randn(b, heads, sq, d).astype("f4")).astype(dtype)
     # (n_pages, H, ps, D) -> the pool's (n_pages, ps, H * D)
     pool = lambda x: x.transpose(0, 2, 1, 3).reshape(  # noqa: E731
         n_pages, ps, heads * d)
-    ref = onp.asarray(att.paged_decode_attention(
-        q, pool(kq * ks[:, :, None, None]),
-        pool(vq * vs[:, :, None, None]), table, lengths))
+    by_hand = (pool(kq * ks[:, :, None, None]),
+               pool(vq * vs[:, :, None, None]))
     ks[0], vs[0] = onp.nan, 1e4
+    return q, pool(kq), pool(vq), ks, vs, table, lengths, by_hand
+
+
+@pytest.mark.parametrize("sq", [1, 5], ids=["decode", "verify"])
+@pytest.mark.parametrize("heads", [16, 20])
+def test_int8_paged_decode_attention_parity(heads, sq):
+    """Paged decode over an int8 pool matches the same attention over
+    the pool dequantized by hand, at the served head counts, on
+    ``_int8_paged_case``. The one-query tick scales scores and
+    probabilities and builds no dequantized view; ``sq > 1`` dequantizes
+    the gathered view."""
+    from mxnet_tpu.ops import attention as att
+    q, kq, vq, ks, vs, table, lengths, (kd, vd) = _int8_paged_case(
+        heads, sq)
+    ref = onp.asarray(att.paged_decode_attention(q, kd, vd, table,
+                                                 lengths))
     out = onp.asarray(att.paged_decode_attention(
-        q, pool(kq), pool(vq), table, lengths, k_scale=ks, v_scale=vs))
+        q, kq, vq, table, lengths, k_scale=ks, v_scale=vs))
     assert (out[0] == 0).all()                     # the empty slot
     onp.testing.assert_allclose(out, ref, rtol=2e-4, atol=2e-5)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("heads", [2, 16, 20])
+def test_int8_paged_tick_rows_reader_matches_gathered_reader(heads, dtype):
+    """The int8 twin of ``tests/test_attention.py``'s reader test: the
+    one-query tick over int8 rows as they lie (the page's scale on the
+    scores and on the probabilities) against the gathered reader's
+    dequantized float32 view (what a tp-mesh program attends), on
+    ``_int8_paged_case``. With bfloat16 queries the tick rounds scaled
+    probabilities to bfloat16 before P x V, as over a bfloat16 pool."""
+    from mxnet_tpu.ops import attention as att
+    q, kq, vq, ks, vs, table, lengths, _ = _int8_paged_case(heads, 1,
+                                                            dtype)
+    telemetry.reset()
+    rows = att.paged_decode_attention(q, kq, vq, table, lengths,
+                                      k_scale=ks, v_scale=vs)
+    assert telemetry.counter_value("ops.attention.paged_decode.rows") == 1
+    with att.jnp_only():
+        gathered = att.paged_decode_attention(q, kq, vq, table, lengths,
+                                              k_scale=ks, v_scale=vs)
+    assert telemetry.counter_value(
+        "ops.attention.paged_decode.gathered") == 1
+    assert rows.dtype == q.dtype and rows.shape == q.shape
+    rows, gathered = (onp.asarray(x, "f4") for x in (rows, gathered))
+    assert (rows[0] == 0).all()                    # the empty slot
+    assert onp.isfinite(rows).all()
+    tol = dict(rtol=2e-4, atol=2e-5) if dtype == "float32" \
+        else dict(rtol=2e-2, atol=8e-3)
+    onp.testing.assert_allclose(rows, gathered, **tol)
 
 
 # -- model-level bounded divergence ------------------------------------

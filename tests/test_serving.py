@@ -368,6 +368,8 @@ def test_batcher_death_surfaces_replica_failed():
         eng.submit(x)
     assert ei.value.cause is boom
     assert isinstance(ei.value, EngineClosedError)  # old handlers work
+    # the futures fail before the dying thread has left its frame
+    eng._batcher.join(timeout=30)
     assert not eng._batcher.is_alive()
 
     # a DELIBERATE close stays a plain EngineClosedError

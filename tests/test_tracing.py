@@ -523,6 +523,10 @@ def test_serving_phases_land_in_the_profiler_trace(net, tmp_path):
                        eng.submit(_prompt(9, 2), max_new_tokens=5)]
             for s in streams:
                 s.result(timeout=60)
+            # the worker ends its last pass inside the session: the
+            # profiler keeps a span that ENDS in it, so a session that
+            # stops mid-pass holds a serve.admit without its serve.iter
+            eng.close()
         lines = _profiled(tmp_path, serve)
     finally:
         eng.close()

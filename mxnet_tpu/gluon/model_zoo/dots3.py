@@ -66,8 +66,14 @@ from jax import lax
 
 from ... import telemetry, tracing
 from ...ndarray.ndarray import NDArray
+from ...ops import latent_attention as _la
 from ...ops import moe as _moe
-from ...ops.attention import NEG_INF
+from ...ops.latent_attention import dot32 as _dot32
+from ...ops.latent_attention import pad128 as _pad128
+from ...ops.latent_attention import rms as _rms
+from ...ops.latent_attention import rms32 as _rms32
+from ...ops.latent_attention import rope as _rope
+from ...ops.latent_attention import swiglu as _swiglu
 from ..block import HybridBlock
 from ..parameter import Parameter
 
@@ -80,158 +86,12 @@ _F32 = jnp.float32
 TRACE_COUNTER = "model.dots3.trace"
 
 
-def _pad128(n):
-    return -(-n // 128) * 128
-
-
-class _Geom:
-    """One attention geometry (the full layers', or the window layers')."""
-
-    def __init__(self, heads, nope, rope, v, q_rank, kv_rank, theta):
-        self.h, self.dn, self.dr, self.dv = heads, nope, rope, v
-        self.rq, self.rkv, self.theta = q_rank, kv_rank, float(theta)
-        self.row = kv_rank + rope            # what one position caches
-        self.row_pad = _pad128(self.row)     # minor dimension of a pool
-        self.scale = 1.0 / math.sqrt(nope + rope)
-
-
-# ---------------------------------------------------------------------------
-# pure pieces: each takes the layer's arrays ``p`` (short name -> array)
-# ---------------------------------------------------------------------------
-def _rms32(x, g, eps):
-    """RMSNorm in float32, left in float32."""
-    x32 = x.astype(_F32)
-    y = x32 * lax.rsqrt(jnp.mean(jnp.square(x32), -1, keepdims=True) + eps)
-    return y * g.astype(_F32)
-
-
-def _rms(x, g, eps):
-    return _rms32(x, g, eps).astype(x.dtype)
-
-
 def _layer_norm32(x, g, b, eps=1e-6):
     x32 = x.astype(_F32)
     mu = jnp.mean(x32, -1, keepdims=True)
     var = jnp.mean(jnp.square(x32 - mu), -1, keepdims=True)
     y = (x32 - mu) * lax.rsqrt(var + eps)
     return y * g.astype(_F32) + b.astype(_F32)
-
-
-def _dot32(a, w):
-    """A float32 product of float32 operands, at full precision: the
-    indexer's branch, whose scores decide a discrete choice. Both
-    operands have to BE float32: the TPU compiler folds a bfloat16
-    array's conversion into the product, and a product with one bfloat16
-    operand rounds the other to bfloat16 too, whatever precision it is
-    asked for (my chip run, PR 27: a query latent off by 0.8 %). So the
-    leaves this branch multiplies are float32 leaves."""
-    assert a.dtype == _F32 and w.dtype == _F32, (a.dtype, w.dtype)
-    return jnp.dot(a, w, precision=lax.Precision.HIGHEST)
-
-
-def _rope(x, pos, theta, dims=None):
-    """Rotary positions in the half-split convention on the first
-    ``dims`` of the last axis (all of it by default). ``x`` (T, ..., d),
-    ``pos`` (T,)."""
-    d = x.shape[-1] if dims is None else dims
-    half = d // 2
-    inv = jnp.exp(-math.log(theta)
-                  * (jnp.arange(half, dtype=_F32) * 2.0 / d))
-    ang = pos.astype(_F32)[:, None] * inv[None, :]           # (T, half)
-    shape = (x.shape[0],) + (1,) * (x.ndim - 2) + (half,)
-    cos, sin = jnp.cos(ang).reshape(shape), jnp.sin(ang).reshape(shape)
-    x32 = x.astype(_F32)
-    a, b, rest = x32[..., :half], x32[..., half:d], x32[..., d:]
-    out = jnp.concatenate([a * cos - b * sin, b * cos + a * sin, rest], -1)
-    return out.astype(x.dtype)
-
-
-def _swiglu(z, w_gate, w_up, w_down):
-    g = jnp.dot(z, w_gate, preferred_element_type=_F32)
-    u = jnp.dot(z, w_up, preferred_element_type=_F32)
-    h = (jax.nn.silu(g) * u).astype(z.dtype)
-    return jnp.dot(h, w_down, preferred_element_type=_F32)
-
-
-def _queries(p, u32, pos, g, hidden, eps, exact):
-    """The query latent in float32 (``exact``: a full-attention layer,
-    whose indexer reads it; else ``None``) and the per-head queries
-    ``q_n`` (T, H, dn), ``q_r`` (T, H, dr) rotated, in the input's dtype.
-    ``u32`` is the layer's normed input in float32."""
-    u = u32.astype(p["w_uq"].dtype)
-    a_q = math.sqrt(hidden / g.rq)
-    if exact:
-        c_q32 = a_q * _rms32(_dot32(u32, p["w_dq"]), p["q_norm"], eps)
-        c_q = c_q32.astype(u.dtype)
-    else:
-        c_q32 = None
-        c_q = (a_q * _rms32(jnp.dot(u, p["w_dq"]), p["q_norm"],
-                            eps)).astype(u.dtype)
-    q = jnp.dot(c_q, p["w_uq"]).reshape(-1, g.h, g.dn + g.dr)
-    return c_q32, q[..., :g.dn], _rope(q[..., g.dn:], pos, g.theta)
-
-
-def _latent_rows(p, u, pos, g, hidden, eps):
-    """What a position caches, (T, row_pad): the normed, rescaled
-    key/value latent, the rotary key, zeros up to the pool's width."""
-    ckr = jnp.dot(u, p["w_dkv"])
-    c = _rms(ckr[:, :g.rkv], p["kv_norm"], eps)
-    c = (c.astype(_F32) * math.sqrt(hidden / g.rkv)).astype(u.dtype)
-    k_r = _rope(ckr[:, g.rkv:], pos, g.theta)
-    pad = jnp.zeros((u.shape[0], g.row_pad - g.row), u.dtype)
-    return jnp.concatenate([c, k_r, pad], -1)
-
-
-def _softmax_masked(s, valid):
-    s = jnp.where(valid, s, NEG_INF)
-    m = s.max(-1, keepdims=True)
-    e = jnp.where(valid, jnp.exp(s - m), 0.0)
-    l = e.sum(-1, keepdims=True)
-    return e / jnp.where(l > 0, l, 1.0)
-
-
-def _attend_plain(p, q_n, q_r, rows, valid, g, head_block):
-    """Plain (non-absorbed) attention of T queries over S cached rows
-    under ``valid`` (T, S): keys and values are made from the latent,
-    ``head_block`` heads at a time so that no (H, T, S) tensor is ever
-    whole. Returns (T, H, dv)."""
-    t = q_n.shape[0]
-    c, k_r = rows[:, :g.rkv], rows[:, g.rkv:g.row]
-    nb = g.h // head_block
-    w_uk = p["w_uk"].reshape(g.rkv, nb, head_block, g.dn)
-    w_uv = p["w_uv"].reshape(g.rkv, nb, head_block, g.dv)
-
-    def block(args):
-        wk, wv, qn, qr = args
-        k_n = jnp.einsum("sr,rhd->shd", c, wk)
-        v = jnp.einsum("sr,rhd->shd", c, wv)
-        s = jnp.einsum("thd,shd->hts", qn, k_n,
-                       preferred_element_type=_F32)
-        s += jnp.einsum("thd,sd->hts", qr, k_r,
-                        preferred_element_type=_F32)
-        pr = _softmax_masked(s * g.scale, valid[None]).astype(v.dtype)
-        return jnp.einsum("hts,shd->thd", pr, v)
-
-    out = lax.map(block, (
-        jnp.moveaxis(w_uk, 1, 0), jnp.moveaxis(w_uv, 1, 0),
-        jnp.moveaxis(q_n.reshape(t, nb, head_block, g.dn), 1, 0),
-        jnp.moveaxis(q_r.reshape(t, nb, head_block, g.dr), 1, 0)))
-    return jnp.moveaxis(out, 0, 1).reshape(t, g.h, g.dv)
-
-
-def _attend_absorbed(p, q_n, q_r, rows, valid, g):
-    """Absorbed attention of one query a row over its own K cached rows:
-    ``q_n`` (B, H, dn), ``rows`` (B, K, row_pad), ``valid`` (B, K).
-    Returns (B, H, dv)."""
-    c, k_r = rows[..., :g.rkv], rows[..., g.rkv:g.row]
-    q_abs = jnp.einsum("bhd,rhd->bhr", q_n,
-                       p["w_uk"].reshape(g.rkv, g.h, g.dn))
-    s = jnp.einsum("bhr,bkr->bhk", q_abs, c, preferred_element_type=_F32)
-    s += jnp.einsum("bhd,bkd->bhk", q_r, k_r, preferred_element_type=_F32)
-    pr = _softmax_masked(s * g.scale, valid[:, None, :]).astype(c.dtype)
-    ctx = jnp.einsum("bhk,bkr->bhr", pr, c)
-    return jnp.einsum("bhr,rhd->bhd", ctx,
-                      p["w_uv"].reshape(g.rkv, g.h, g.dv))
 
 
 def _gate_out(p, u, o, g):
@@ -243,7 +103,7 @@ def _gate_out(p, u, o, g):
                    preferred_element_type=_F32)
 
 
-def _index_queries(p, c_q32, u32, pos, idx, rope_dims, theta):
+def _index_queries(p, c_q32, u32, pos, idx, rope_dims, inv_freq):
     """The indexer's per-head queries (T, H_I, d_I), rotated on their
     first ``rope_dims``, and head weights (T, H_I) with both ``H_I^-1/2``
     and ``d_I^-1/2`` folded in. Float32 from float32 inputs: the
@@ -251,14 +111,14 @@ def _index_queries(p, c_q32, u32, pos, idx, rope_dims, theta):
     selection whatever the dtype of the rest."""
     hi, di = idx
     q = _dot32(c_q32, p["wi_q"]).reshape(-1, hi, di)
-    q = _rope(q, pos, theta, dims=rope_dims)
+    q = _rope(q, pos, inv_freq, dims=rope_dims)
     return q, _dot32(u32, p["wi_w"]) * (hi ** -0.5) * (di ** -0.5)
 
 
-def _index_keys(p, u32, pos, rope_dims, theta):
+def _index_keys(p, u32, pos, rope_dims, inv_freq):
     """The indexer's key of each position, float32 (cached as such)."""
     k = _layer_norm32(_dot32(u32, p["wi_k"]), p["wi_k_g"], p["wi_k_b"])
-    return _rope(k, pos, theta, dims=rope_dims)
+    return _rope(k, pos, inv_freq, dims=rope_dims)
 
 
 def _index_scores(q, w, keys, head_block=8):
@@ -351,12 +211,19 @@ class Dots3Model(HybridBlock):
         bad = set(self._kinds) - {"full_attention", "sliding_attention"}
         if bad:
             raise ValueError(f"unknown layer_types {sorted(bad)}")
-        self._full = _Geom(num_attention_heads, qk_nope_head_dim,
-                           qk_rope_head_dim, v_head_dim, q_lora_rank,
-                           kv_lora_rank, rope_theta)
-        self._swa = _Geom(swa_num_attention_heads, swa_qk_nope_head_dim,
-                          swa_qk_rope_head_dim, swa_v_head_dim,
-                          swa_q_lora_rank, swa_kv_lora_rank, swa_rope_theta)
+        # ``apply_mla_qkv_lora_rescale``: the two normed latents times
+        # sqrt(hidden / rank)
+        self._full = _la.Geom(
+            num_attention_heads, qk_nope_head_dim, qk_rope_head_dim,
+            v_head_dim, q_lora_rank, kv_lora_rank, rope_theta,
+            a_q=math.sqrt(self._d / q_lora_rank),
+            a_kv=math.sqrt(self._d / kv_lora_rank))
+        self._swa = _la.Geom(
+            swa_num_attention_heads, swa_qk_nope_head_dim,
+            swa_qk_rope_head_dim, swa_v_head_dim, swa_q_lora_rank,
+            swa_kv_lora_rank, swa_rope_theta,
+            a_q=math.sqrt(self._d / swa_q_lora_rank),
+            a_kv=math.sqrt(self._d / swa_kv_lora_rank))
         self._idx = (int(index_n_heads), int(index_head_dim))
         self._topk = int(index_topk)
         self._window = int(sliding_window_size)
@@ -477,22 +344,10 @@ class Dots3Model(HybridBlock):
                 return _swiglu(z, p["w_gate"], p["w_up"], p["w_down"])
         with _scope("moe"):
             # the router reads the float32 input: see ``_dot32``
-            ids, gates = _moe.route_sigmoid_topk(
-                z32, p["router"], p["router_bias"], self._k)
-            tm = _moe.tile_rows(z.shape[0] * self._k)
-            routed, n_hit = _moe.expert_layer(
-                z, p["e_gate"], p["e_up"], p["e_down"], ids, gates,
-                self._held.start, tm)
+            routed, n_hit = _moe.routed_layer(z32, z, p, self._k,
+                                              self._held.start)
             hit.append(n_hit)
             return routed + _swiglu(z, p["s_gate"], p["s_up"], p["s_down"])
-
-    def _head_block(self, g, t, s):
-        """Heads a step of the plain attention: the (hb, T, S) float32
-        scores stay near 256 MB."""
-        hb = g.h
-        while hb > 1 and hb * t * s * 4 > (1 << 28) and hb % 2 == 0:
-            hb //= 2
-        return hb
 
     def _layer_prefill(self, li, p, x, pos, keys, hit):
         """One layer over a chunk of T tokens at positions ``pos``.
@@ -505,29 +360,28 @@ class Dots3Model(HybridBlock):
         u32 = _rms32(x, p["attn_norm"], self._eps)
         u = u32.astype(x.dtype)
         with _scope("mla" if full else "swa"):
-            c_q32, q_n, q_r = _queries(p, u32, pos, g, self._d, self._eps,
-                                       full)
-            rows = _latent_rows(p, u, pos, g, self._d, self._eps)
+            c_q32, q_n, q_r = _la.queries(p, u32, pos, g, self._eps, full)
+            rows = _la.latent_rows(p, u, pos, g, self._eps)
         ik = None
         if full:
             with _scope("indexer"):
-                ik = _index_keys(p, u32, pos, g.dr, g.theta)
+                ik = _index_keys(p, u32, pos, g.dr, g.inv_freq)
         with _scope("kv_write"):
             rows_s, ik_s, key_pos = keys(rows, ik)
         causal = (key_pos[None, :] <= pos[:, None]) & (key_pos[None, :] >= 0)
         if full:
             with _scope("indexer"):
                 qi, wi = _index_queries(p, c_q32, u32, pos, self._idx,
-                                        g.dr, g.theta)
+                                        g.dr, g.inv_freq)
                 valid = _select(_index_scores(qi, wi, ik_s), causal,
                                 self._topk)
         else:
             valid = causal & (pos[:, None] - key_pos[None, :]
                               < self._window)
         with _scope("mla" if full else "swa"):
-            o = _attend_plain(p, q_n, q_r, rows_s, valid, g,
-                              self._head_block(g, x.shape[0],
-                                               rows_s.shape[0]))
+            o = _la.attend_plain(p, q_n, q_r, rows_s, valid, g,
+                                 _la.head_block(g, x.shape[0],
+                                                rows_s.shape[0]))
             h = (x.astype(_F32) + _gate_out(p, u, o, g)).astype(x.dtype)
         return (h.astype(_F32) + self._ffn(li, p, h, hit)).astype(x.dtype)
 
@@ -655,12 +509,12 @@ class Dots3Model(HybridBlock):
             u32 = _rms32(x, p["attn_norm"], self._eps)
             u = u32.astype(x.dtype)
             with _scope("mla" if full else "swa"):
-                c_q32, q_n, q_r = _queries(p, u32, t, g, self._d,
-                                           self._eps, full)
-                rows = _latent_rows(p, u, t, g, self._d, self._eps)
+                c_q32, q_n, q_r = _la.queries(p, u32, t, g, self._eps,
+                                              full)
+                rows = _la.latent_rows(p, u, t, g, self._eps)
             if full:
                 with _scope("indexer"):
-                    ik = _index_keys(p, u32, t, g.dr, g.theta)
+                    ik = _index_keys(p, u32, t, g.dr, g.inv_freq)
                 with _scope("kv_write"):
                     pool = lat[ci].reshape(-1, g.row_pad).at[flat].set(rows)
                     lat[ci] = pool.reshape(lat[ci].shape)
@@ -672,7 +526,7 @@ class Dots3Model(HybridBlock):
                     keys = idx[ci][cache["table"]].reshape(
                         b, -1, wi_)[..., :di]
                     qi, wi = _index_queries(p, c_q32, u32, t, self._idx,
-                                            g.dr, g.theta)
+                                            g.dr, g.inv_freq)
                     sc = _index_scores(qi[:, None], wi[:, None],
                                        keys)[:, 0]          # (B, S)
                     s_all = sc.shape[-1]
@@ -685,7 +539,7 @@ class Dots3Model(HybridBlock):
                         cache["table"], sel // ps, axis=1) * ps + sel % ps)
                 with _scope("mla"):
                     got = pool[jnp.where(valid, sel_flat, 0)]
-                    o = _attend_absorbed(p, q_n, q_r, got, valid, g)
+                    o = _la.attend_absorbed(p, q_n, q_r, got, valid, g)
             else:
                 with _scope("kv_write"):
                     at = jnp.mod(t, self._ring)
@@ -695,7 +549,8 @@ class Dots3Model(HybridBlock):
                 with _scope("swa"):
                     kp = _ring_positions(t, self._ring)
                     valid = (kp >= 0) & (t[:, None] - kp < self._window)
-                    o = _attend_absorbed(p, q_n, q_r, ring[ci], valid, g)
+                    o = _la.attend_absorbed(p, q_n, q_r, ring[ci], valid,
+                                            g)
             with _scope("mla" if full else "swa"):
                 h = (x.astype(_F32) + _gate_out(p, u, o, g)).astype(x.dtype)
             x = (h.astype(_F32) + self._ffn(li, p, h, hit)).astype(x.dtype)

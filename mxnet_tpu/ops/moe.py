@@ -1,13 +1,15 @@
 """Dropless top-k expert layer for serving, told which experts it holds.
 
 A serving replica of an expert-parallel deployment holds a contiguous
-range of the routed experts. It routes every token over ALL of them at
-the published router width (``route_sigmoid_topk``), keeps the picks that
-fall on the experts it holds, and computes their part of the result
-(``expert_layer``): sort the kept picks by expert, three grouped products
-over the held experts, unsort, combine with the gates. No capacity, no
-dropped token; what the absent experts would add is left out (their
-chips add it), and no code stands in for them or their traffic.
+range of the routed experts; a model that no chip shares a layer of holds
+them all (``lo = 0``, ``n_held = E_all``: every pick is kept). Either
+routes every token over ALL experts at the published router width
+(``route_sigmoid_topk``), keeps the picks that fall on the experts it
+holds, and computes their part of the result (``expert_layer``): sort the
+kept picks by expert, three grouped products over the held experts,
+unsort, combine with the gates. No capacity, no dropped token; what
+absent experts would add is left out (their chips add it), and no code
+stands in for them or their traffic.
 ``parallel/moe.py`` is the training-side top-1 operator over an ``ep``
 mesh axis; nothing of it is used here.
 
@@ -38,13 +40,14 @@ from . import attention as _att
 KERNEL_NAME = "moe_grouped_matmul"
 
 
-def route_sigmoid_topk(z, w_router, bias, top_k):
+def route_sigmoid_topk(z, w_router, bias, top_k, scaling_factor=1.0):
     """Sigmoid ``noaux_tc`` routing with one group: scores
     ``s = sigmoid(z W_r)`` in float32, the ``top_k`` experts by ``s +
-    bias``, gates ``s_i / sum_chosen s_j`` (``norm_topk_prob``, scaling
-    factor 1). ``z`` (T, D) float32 (the normed input before it is cast
-    to the model's dtype); ``w_router`` (D, E_all) and ``bias`` (E_all,)
-    float32. Returns ``ids`` (T, k) int32 and ``gates`` (T, k)
+    bias``, gates ``scaling_factor * s_i / sum_chosen s_j``
+    (``norm_topk_prob``; ``routed_scaling_factor``, 1 unless the model
+    publishes another). ``z`` (T, D) float32 (the normed input before it
+    is cast to the model's dtype); ``w_router`` (D, E_all) and ``bias``
+    (E_all,) float32. Returns ``ids`` (T, k) int32 and ``gates`` (T, k)
     float32."""
     # both operands float32 arrays: a product with one bfloat16 operand
     # rounds the other on a TPU, whatever precision it is asked for
@@ -54,6 +57,8 @@ def route_sigmoid_topk(z, w_router, bias, top_k):
     _, ids = lax.top_k(s + bias.astype(jnp.float32), top_k)
     chosen = jnp.take_along_axis(s, ids, axis=-1)
     gates = chosen / jnp.sum(chosen, axis=-1, keepdims=True)
+    if scaling_factor != 1:
+        gates = gates * jnp.float32(scaling_factor)
     return ids.astype(jnp.int32), gates
 
 
@@ -65,7 +70,10 @@ def rows_for(picks, n_held, tm):
 
 def tile_rows(picks):
     """Rows a tile of the grouped product: 128 where a call's picks fill
-    them (a prefill chunk), 32 for a decode tick's few rows an expert;
+    them (a prefill chunk), 32 for a decode tick's few rows an expert
+    (one of 32 slots x 8 picks over 256 experts of which 32 are held; two
+    of 32 x 4 picks over 64 experts all held: either way a tile is mostly
+    padding and what a tick pays for is the weights of the experts hit);
     the ``jnp`` path needs no more than the sublane's 8."""
     if not _att._use_pallas():
         return 8
@@ -181,6 +189,18 @@ def grouped_matmul(x, w, tile_expert, n_active, tm):
     row_expert = jnp.repeat(tile_expert, tm)
     return jnp.einsum("mk,mkn->mn", x, w[row_expert],
                       preferred_element_type=jnp.float32).astype(x.dtype)
+
+
+def routed_layer(z32, z, p, top_k, lo, scaling_factor=1.0):
+    """A routed layer's held part on the normed input: ``z32`` (T, D)
+    float32 for the router, ``z`` the same in the model's dtype for the
+    experts; ``p`` the layer's arrays (``router``, ``router_bias``,
+    ``e_gate``, ``e_up``, ``e_down``); ``lo`` the first expert held.
+    Returns (T, D) float32 and the number of held experts hit."""
+    ids, gates = route_sigmoid_topk(z32, p["router"], p["router_bias"],
+                                    top_k, scaling_factor)
+    return expert_layer(z, p["e_gate"], p["e_up"], p["e_down"], ids, gates,
+                        lo, tile_rows(z.shape[0] * top_k))
 
 
 def expert_layer(z, w_gate, w_up, w_down, ids, gates, lo, tm):

@@ -436,3 +436,66 @@ def test_phi4flash_tick_has_the_operations_ssm_share_reads(
     hits = [n for n in names
             if n.startswith("fusion ") and pattern.search(n)]
     assert hits, sorted({n for n in names if "5120]" in n})[:20]
+
+
+# -- the xing4 family at its published widths: the decode program of the
+# -- cell's 32 slots, whose hyper-connections model.mhc_share finds by shape
+def test_xing4_tick_has_the_operations_mhc_share_reads(
+        one_chip, no_compile_cache, monkeypatch):
+    """``model.mhc_share`` finds the hyper-connections' operations by the
+    SHAPE of their results (and the Sinkhorn rounds and the read mix by
+    the fusion's name too), which are the compiler's to choose: the decode
+    program of ``Xing4Model`` at the published widths and the cell's 32
+    slots, compiled for the described v5e with the expert kernel as the
+    chip runs it, holds operations that each alternative of the metric's
+    pattern matches, and the expert kernel under the name
+    ``model.moe_share`` reads. Should a compiler fuse them otherwise, this
+    fails before the metric falls silent."""
+    import json
+    import os
+    from chipbench import trace_reduce
+    from chipbench.families.xing4 import program
+    from mxnet_tpu.gluon.model_zoo.xing4 import Xing4Model
+    from mxnet_tpu.ops import moe
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "chipbench", "configs",
+                           "xing4-29b-a4b.json")) as f:
+        cfg = json.load(f)
+    with open(os.path.join(root, "chipbench", "layer_metrics",
+                           "model.mhc_share.json")) as f:
+        pattern = json.load(f)["args"]["pattern"]
+    model, serve = cfg["model"], cfg["serve"]
+    net = Xing4Model(**{k: model[k] for k in program._KEYS},
+                     max_length=serve["max_length"])
+    # the program asks which backend runs it and would take the CPU's
+    # ``jnp`` product: the test steers it to the chip's kernel
+    monkeypatch.setattr(att, "_use_pallas", lambda: True)
+
+    def on_chip(p):
+        return jax.ShapeDtypeStruct(p.shape, jnp.dtype(p.dtype),
+                                    sharding=one_chip)
+
+    layers = [{k: on_chip(p) for k, p in net._params[li].items()}
+              for li in range(model["num_hidden_layers"])]
+    top = {k: on_chip(p) for k, p in net._params[None].items()}
+    slots, ps = serve["max_slots"], serve["page_size"]
+    cache = jax.tree.map(on_chip, jax.eval_shape(
+        lambda: net.init_paged_cache(
+            slots, slots * serve["max_length"] // ps + 1, ps,
+            serve["max_length"])))
+    rows = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one_chip)
+    text = jax.jit(net._decode_body).lower(
+        layers, top, rows, rows, cache).compile().as_text()
+    names = [trace_reduce.short(line.strip().removeprefix("ROOT "))[0]
+             for line in text.splitlines() if " = " in line]
+    # a fusion is an operation the device runs and the trace times
+    ran = [n for n in names if n.startswith(("fusion ", "custom-call "))]
+    for part in pattern.split("|^"):
+        rx = re.compile(part if part.startswith("^") else "^" + part)
+        hits = [n for n in ran if rx.search(n)]
+        # twelve sublayers: at least one such operation in each
+        assert len(hits) >= 12, (part, sorted(set(ran))[:40])
+    assert sum(n.startswith(f"custom-call {moe.KERNEL_NAME} ")
+               for n in ran) == 3 * 4
+    # no pool is copied and no view is split into heads
+    assert not [n for n in ran if n.startswith("copy ") and "4609" in n]

@@ -12,8 +12,8 @@ chipbench/tests`` on this tree; here they are left out of the cases taken
 over and superseded by the two cases marked ``SUPERSEDES`` below, which
 say the same for every family by whole words. The ``benchmark`` PR that
 edits the two in place deletes the two marked here (``PERF.md`` section 7).
-The ``dots3`` and ``phi4flash`` families are held to the same interface,
-and their configurations to the catalog's published keys.
+The ``dots3``, ``phi4flash`` and ``xing4`` families are held to the same
+interface, and their configurations to the catalog's published keys.
 """
 import importlib.util
 import json
@@ -60,12 +60,17 @@ FAMILIES = sorted(
 NAMES = {
     "gpt2": ("n_embd", "n_head", "n_positions", "layer_norm_epsilon",
              "GPTModel", "model.gpt.trace", "families.gpt2"),
+    # ``kv_lora_rank`` and ``n_routed_experts`` are no one family's since
+    # a second one has latent attention and routed experts
     "dots3": ("Dots3Model", "model.dots3.trace", "families.dots3",
-              "kv_lora_rank", "index_topk", "swa_kv_lora_rank",
-              "router_experts", "n_routed_experts"),
+              "index_topk", "swa_kv_lora_rank", "router_experts",
+              "expert_rank"),
     "phi4flash": ("Phi4FlashModel", "model.phi4flash.trace",
                   "families.phi4flash", "mb_per_layer", "sliding_window",
                   "d_state", "dt_rank", "ssm_scan_call"),
+    "xing4": ("Xing4Model", "model.xing4.trace", "families.xing4",
+              "hc_mult", "hc_sinkhorn_iters", "mhc_h_res_clamp_min",
+              "num_nextn_predict_layers", "rope_scaling"),
 }
 
 
@@ -295,3 +300,137 @@ def test_phi4flash_cell_reads_its_own_programs_and_kernel():
     assert rx.search("jit_phi4flash_paged_decode(6)") \
         and not rx.search("jit_phi4flash_paged_chunk(123)") \
         and not rx.search("jit_phi4flash_paged_chunk_last(45)")
+
+
+# -- the xing4 configuration: the catalog's row, one pipeline stage -----------
+XING = "xing4-29b-a4b"
+XING_CELL = XING + ".serve.longctx-decode"
+
+
+@pytest.fixture(scope="module")
+def xing_config():
+    return harness.load_json("configs", XING + ".json")
+
+
+def test_xing4_configuration_is_the_catalog_entry_but_for_reduced(
+        xing_config):
+    """Every width, all 64 experts, both dense layers and the whole
+    vocabulary as published: only the depth and the next-token module
+    are cut, and both stand in ``reduced`` with the published values."""
+    assert sorted(xing_config["reduced"]) == [
+        "num_hidden_layers", "num_nextn_predict_layers"]
+    assert xing_config["published"] == {"num_hidden_layers": 40,
+                                        "num_nextn_predict_layers": 1}
+    assert xing_config["num_hidden_layers"] == 6
+    assert xing_config["num_nextn_predict_layers"] == 0
+    assert "one pipeline stage on one chip" in xing_config["deployment"]
+    assert xing_config["ep_size"] == 1
+    bench = harness.load_benchmark()
+    listed = next(c for c in bench["configs"] if c["name"] == XING)
+    assert sorted(listed["reduced"]) == sorted(xing_config["reduced"])
+    if not os.path.isfile(CATALOG):
+        pytest.skip("no catalog beside the model-configs guide here")
+    with open(CATALOG) as f:
+        rows = [json.loads(line) for line in f]
+    entry = next(r for r in rows if r["name"] == "Xing4.0-29B-A4B")
+    assert xing_config["source"] == entry["source_url"] == listed["source"]
+    for key, value in entry["config"].items():
+        if key in xing_config["reduced"]:
+            assert xing_config["published"][key] == value, key
+        else:
+            assert xing_config[key] == value, key
+            assert xing_config["model"][key] == value, key
+
+
+def test_xing4_configuration_states_its_keys_twice_alike(xing_config):
+    model = xing_config["model"]
+    for key, value in model.items():
+        if key != "initializer_range":
+            assert xing_config[key] == value, key
+    assert model["initializer_range"] == 0.006
+    serve = xing_config["serve"]
+    assert serve["prefix_cache"] is False and serve["max_slots"] == 32
+    # the cache holds the mix's longest prompt and its longest answer
+    mix = harness.load_json("traffic", "closed32-longctx-reasoning.json")
+    assert serve["max_length"] == mix["prompt_tokens"]["max"] \
+        + mix["new_tokens"]["max"] == 9216
+    for entry in xing_config["assumed"].values():
+        assert len(entry) > 20
+    assert len(xing_config["precision"]) > 20
+
+
+def test_xing4_parameter_count_is_the_stage_the_configuration_states(
+        xing_config):
+    """ISSUE 33's count: attention 28.41 M a layer, a dense layer 127.5 M
+    and 0.69 M of hyper-connections, a routed layer 745.0 M, embedding
+    and head 939.5 M: 4.176 B, 8.35 GB in bfloat16."""
+    weights = harness.family(xing_config, "weights")
+    s = weights.sizes(xing_config["model"])
+    by_layer = [sum(int(np.prod(sh)) for _, sh, _ in
+                    weights.layer_leaves(s, i)) for i in range(s["L"])]
+    hc = 2 * (24 * 4 * 3584 + 3 + 24)
+    assert by_layer[0] == by_layer[1] and len(set(by_layer[2:])) == 1
+    assert abs(by_layer[0] - hc - 127.5e6) < 0.05e6
+    assert abs(by_layer[2] - 745.0e6) < 0.05e6
+    n = weights.parameter_count(s)
+    assert n == sum(by_layer) + 2 * 131072 * 3584 + 3584
+    assert abs(n - 4.176e9) < 0.001e9
+    # bytes held by the program: two a parameter, and two more for the
+    # leaves it keeps in float32 (the maps' phi, alpha, b; the router)
+    wide = sum(
+        int(np.prod(shape)) for i in range(s["L"])
+        for name, shape, _ in weights.layer_leaves(s, i)
+        if name in weights.FLOAT32_IN_PROGRAM)
+    assert (2 * n + 2 * wide) / (2 * n) < 1.002
+
+
+def test_the_new_cells_are_the_traffic_the_issue_gave():
+    bench = harness.load_benchmark()
+    cells = {c["name"]: c for c in bench["workloads"]}
+    assert cells[XING_CELL]["traffic"] == "closed32-longctx-reasoning"
+    mix = harness.load_json("traffic", "closed32-longctx-reasoning.json")
+    assert (mix["kind"], mix["callers"], mix["distinct_sizes"],
+            mix["check_requests"], mix["trace_seconds"]) == (
+        "closed_loop", 32, 48, 6, 5)
+    assert mix["prompt_tokens"] == {"dist": "lognormal", "median": 3072,
+                                    "sigma": 0.6, "min": 1024, "max": 8192}
+    assert mix["new_tokens"] == {"dist": "lognormal", "median": 640,
+                                 "sigma": 0.35, "min": 256, "max": 1024}
+    others = [harness.load_json("traffic", f)["pairing_seed"]
+              for f in os.listdir(os.path.join(BENCH, "traffic"))
+              if f != "closed32-longctx-reasoning.json"
+              and "pairing_seed" in harness.load_json("traffic", f)]
+    assert mix["pairing_seed"] not in others
+    queued = cells["gpt2-medium.serve.decode-heavy"]
+    assert (queued["config"], queued["traffic"], queued["chips"]) == (
+        "gpt2-medium", "closed8-chat", 1)
+    for cell in (XING_CELL, "gpt2-medium.serve.decode-heavy"):
+        judged = {m["name"] for m in bench["end_to_end"]
+                  if harness.applies(m, cell)}
+        assert judged == {"serve_tokens_per_s", "ttft_mean_ms", "setup_s"}
+        limits = harness.load_json("limits", cell + ".json")
+        assert 0 < limits["limits"]["served_logit_gap"] < 1
+
+
+def test_xing4_cell_reads_its_own_programs_and_the_shared_kernel():
+    bench = harness.load_benchmark()
+    mine = {m["name"] for m in bench["per_layer"]
+            if m.get("workloads") == [XING_CELL]}
+    assert {"model.xing4_decode_device_ms", "model.mhc_share"} <= mine \
+        <= {"model.xing4_decode_device_ms", "model.xing4_chunk_device_ms",
+            "model.mhc_share"}
+    shared = {m["name"] for m in bench["per_layer"]
+              if XING_CELL in m.get("workloads", ()) and m["name"]
+              not in mine}
+    assert {"model.moe_share", "model.pool_copy_share",
+            "sched.itl_p50_ms.prefill-heavy",
+            "sched.itl_p95_ms.prefill-heavy"} <= shared
+    from mxnet_tpu.ops import moe
+    share = harness.load_json("layer_metrics", "model.moe_share.json")
+    assert moe.KERNEL_NAME in share["args"]["pattern"]
+    tick = harness.load_json("layer_metrics",
+                             "model.xing4_decode_device_ms.json")
+    rx = re.compile(tick["args"]["module"])
+    assert rx.search("jit_xing4_paged_decode(6)") \
+        and not rx.search("jit_xing4_paged_chunk(123)") \
+        and not rx.search("jit_dots3_paged_decode(45)")

@@ -50,7 +50,7 @@ import jax.numpy as jnp
 NEG_INF = -1e30
 
 __all__ = ["warp_logits", "sample_tokens", "greedy_tokens",
-           "sample_with_probs", "greedy_accept", "speculative_accept"]
+           "carry_tokens", "sample_with_probs", "greedy_accept", "speculative_accept"]
 
 
 def warp_logits(logits, temperature, top_k, top_p):
@@ -118,6 +118,14 @@ def greedy_tokens(logits):
     argmax takes it. What an all-greedy engine's tick runs, so that
     (B,) ints and not (B, V) floats come to the host."""
     return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+
+def carry_tokens(picked, fresh, use_fresh):
+    """A tick's input tokens (B,) int32 when the tick before it is still
+    in flight: row ``i`` is ``fresh[i]`` (from the host: a row that
+    entered decode since) where ``use_fresh[i]``, else ``picked[i]``, the
+    earlier tick's pick where it lies on the device."""
+    return jnp.where(use_fresh, fresh, picked).astype(jnp.int32)
 
 
 def sample_with_probs(keys, logits, temperature, top_k, top_p):

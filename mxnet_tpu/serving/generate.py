@@ -363,6 +363,24 @@ class _PagedSlot:
         self.t_submit = t_submit
 
 
+class _Tick:
+    """A plain decode tick between its dispatch and its commit. ``rows``
+    are ``(slot, slot state)`` pairs, the state object being the identity
+    of the request the row served: a slot that is empty or holds another
+    request by commit time was served a stale row-tick, and its output is
+    dropped. A tick dispatched ahead holds its greedy ``pick``, a ``(B,)``
+    array still on the device; a synchronous tick its ``logits``, from
+    which it picks at its sync."""
+
+    __slots__ = ("rows", "logits", "pick", "t0", "tt0")
+
+    def __init__(self, rows, t0, tt0):
+        self.rows = rows
+        self.logits = self.pick = None
+        self.t0 = t0       # telemetry clock at dispatch
+        self.tt0 = tt0     # perf_counter at dispatch, if any row traces
+
+
 class _GenWorker(BoundedQueueWorker):
     """Consumer side of the request queue: the admit/step loop.
 
@@ -1020,6 +1038,10 @@ class GenerationEngine:
         self._keys = onp.zeros((self.max_slots, 2), "u4")
         self._n_sampling = 0   # active slots with temperature > 0
         self._samplers = None  # jitted ops/sampling.py programs (lazy)
+        #: the decode tick in flight (docs/SERVING.md "The tick in
+        #: flight"): dispatched, its pick not yet on the host. Touched
+        #: under ``_gen_lock`` only
+        self._ahead: _Tick | None = None
         #: per-slot LoRA bank indices, threaded as a runtime (B,)
         #: vector through every fixed-shape generation program — a
         #: batch mixing any tenants (base rows included) runs ONE
@@ -1346,21 +1368,25 @@ class GenerationEngine:
                                           "sampling_sample")),
                 "greedy": jax.jit(counted(_smp.greedy_tokens,
                                           "sampling_greedy")),
+                "carry": jax.jit(counted(_smp.carry_tokens,
+                                         "sampling_carry")),
             }
         return self._samplers
 
     def _warm_samplers(self, logits):
         """Compile every engine-level sampler shape the steady state
         can hit: the (1, V) first-token pick, the (B, V) decode-step
-        pick and an all-greedy tick's (B, V) argmax, the last on the
-        warm-up tick's own ``logits`` (as the live tick's are placed: a
-        committed and an uncommitted input compile apart). The
-        speculative draft/accept math lives inside the model's fused
-        closures (``_warmup_spec``)."""
+        pick, an all-greedy tick's (B, V) argmax and the merge of that
+        pick with the host's tokens for the next tick, the last two on
+        the warm-up tick's own ``logits`` (as the live tick's are
+        placed: a committed and an uncommitted input compile apart).
+        The speculative draft/accept math lives inside the model's
+        fused closures (``_warmup_spec``)."""
         smp = self._ensure_samplers()
         b, vocab = self.max_slots, int(logits.shape[-1])
         if self._part is None:
-            smp["greedy"](logits)
+            smp["carry"](smp["greedy"](logits), onp.zeros((b,), "i4"),
+                         onp.zeros((b,), "?"))
         smp["sample"](onp.zeros((1, 2), "u4"),
                       onp.zeros((1, vocab), "f4"),
                       onp.zeros((1,), "f4"),
@@ -1442,6 +1468,10 @@ class GenerationEngine:
             self._gen_waiters += 1
         try:
             with self._gen_lock:
+                # a step boundary has nothing in flight: whoever holds
+                # the lock swaps weights, traces or kills on a quiet
+                # device queue
+                self._drain_ahead()
                 yield
         finally:
             with self._lock:
@@ -1481,7 +1511,8 @@ class GenerationEngine:
                     # sharding signature the live path will feed it
                     cache = self._recommit(cache)
             lg, cache = self.model.decode_step(
-                onp.zeros((self.max_slots,), "i4"), cache)
+                self._tick_tokens(onp.zeros((self.max_slots,), "i4")),
+                cache)
             cache = self._recommit(cache)
             if self.decode_ticks > 1:
                 cache = self._warmup_multi(cache)
@@ -1620,7 +1651,7 @@ class GenerationEngine:
                     **kw)
                 cache = self._recommit(cache)
         lg, cache = self.model.decode_step_paged(
-            onp.zeros((self.max_slots,), "i4"),
+            self._tick_tokens(onp.zeros((self.max_slots,), "i4")),
             onp.ones((self.max_slots,), "i4"), cache)
         cache = self._recommit(cache)
         if self.decode_ticks > 1:
@@ -2462,6 +2493,28 @@ class GenerationEngine:
             telemetry.gauge("serving.generate.slots", self._n_active)
         return n_emitted
 
+    def _tick_tokens(self, toks):
+        """A plain tick's host tokens as the decode program takes them
+        on every path: committed to the device, as the pick of a tick in
+        flight is (a committed and an uncommitted input compile apart,
+        so warm-up, the synchronous tick and the tick dispatched ahead
+        all feed the one placement). A tp-mesh engine hands over host
+        arrays as before."""
+        if self._part is not None:
+            return toks
+        import jax
+        return jax.device_put(toks, jax.devices()[0])
+
+    def _ahead_ok(self) -> bool:
+        """Whether this tick may be left in flight (docs/SERVING.md "The
+        tick in flight"): the plain single-step tick on one device, every
+        live row greedy (a sampled row's key lives on the host and comes
+        back from the sampler each tick), nobody waiting for a step
+        boundary, the engine open and run by its worker."""
+        return (self.decode_ticks == 1 and self._part is None
+                and not self._n_sampling and not self._gen_waiters
+                and not self._closed and not self._sync)
+
     def _decode_tick(self):
         """One decode tick over all DECODING slots — dense and paged
         (prefilling paged slots ride along masked out: their writes
@@ -2469,52 +2522,138 @@ class GenerationEngine:
         still). With ``decode_ticks > 1`` the tick runs the fused
         multi-tick scan instead of the single-step program
         (docs/SERVING.md "Multi-tick decode"): one host sync commits
-        up to k tokens per slot."""
+        up to k tokens per slot.
+
+        The plain tick is dispatched AHEAD where ``_ahead_ok``: this
+        tick is queued on the device before the tick before it is
+        synced and committed, and takes each continuing row's token from
+        that tick's pick where it lies, so the host's commit, admission
+        and preparation run while the device works. A row is served if
+        it will still have budget and capacity once the tick in flight
+        commits (length evictions are predicted here); an end the host
+        cannot predict (eos, a deadline) finds its row already in the
+        next tick, whose output for it ``_finish_tick`` drops. Where
+        ``_ahead_ok`` stops holding the tick in flight is drained first
+        and the tick runs synchronously."""
+        ahead = self._ahead_ok()
+        if not ahead:
+            self._drain_ahead()
         if self.paged:
+            # a row with a tick in flight has no copy pending: the sweep
+            # before its first tick took it
             self._cow_sweep()
-        idxs = self._decode_idxs()
-        if not idxs:
-            return
         if self.decode_ticks > 1:
-            self._decode_tick_multi(idxs)
+            idxs = self._decode_idxs()
+            if idxs:
+                self._decode_tick_multi(idxs)
             return
-        with tracing.phase("serve.decode.dispatch"):
-            toks = onp.zeros((self.max_slots,), "i4")
-            active = onp.zeros((self.max_slots,), "i4")
-            any_trace = False
-            for i in idxs:
-                s = self._slots[i]
+        prev, self._ahead = self._ahead, None
+        pending = dict(prev.rows) if prev is not None else {}
+        toks = onp.zeros((self.max_slots,), "i4")
+        active = onp.zeros((self.max_slots,), "i4")
+        fresh = onp.zeros((self.max_slots,), "?")
+        rows = []
+        any_trace = False
+        for i in self._decode_idxs():
+            s = self._slots[i]
+            if pending.get(i) is s:
+                # its token is row i of the pick in flight, whose commit
+                # takes one of ``left`` and adds one to ``n_ctx``
+                if s.left <= 1 or s.n_ctx + 1 >= self._s_cap:
+                    continue
+            else:
                 toks[i] = s.last
-                active[i] = 1
-                if s.stream._trace is not None:
-                    any_trace = True
+                fresh[i] = True
+            active[i] = 1
+            rows.append((i, s))
+            if s.stream._trace is not None:
+                any_trace = True
+        tick = self._dispatch_tick(prev, rows, toks, active, fresh,
+                                   any_trace, ahead) if rows else None
+        if prev is not None:
+            self._finish_tick(prev)
+            if tick is not None and any_trace:
+                # a traced row's "decode" span is its wait for this
+                # token: from the commit of the one before it
+                tick.tt0 = time.perf_counter()
+        if tick is None:
+            return
+        if not ahead:
+            self._finish_tick(tick)
+        elif not any(self._slots[i] is s for i, s in rows):
+            # every row it serves ended at the commit above: nothing
+            # waits for this tick
+            self._drain_ahead()
+
+    def _dispatch_tick(self, prev, rows, toks, active, fresh, any_trace,
+                       ahead) -> _Tick:
+        """Queue one plain tick on the device. Its tokens: the host's
+        ``toks`` where no tick is in flight, else ``prev``'s pick where
+        it lies, with the ``fresh`` rows (in decode since ``prev`` was
+        dispatched) merged in from ``toks`` on the device."""
+        with tracing.phase("serve.decode.dispatch"):
+            if prev is None:
+                tokens = self._tick_tokens(toks)
+            elif fresh.any():
+                tokens = self._ensure_samplers()["carry"](
+                    prev.pick, toks, fresh)
+            else:
+                tokens = prev.pick
             tt0 = time.perf_counter() if any_trace else 0.0
             t0 = telemetry.clock()
             if self.paged:
                 logits, self._cache = self.model.decode_step_paged(
-                    toks, active, self._cache,
+                    tokens, active, self._cache,
                     **self._akw(self._adapter_idx))
                 self._cache = self._recommit(self._cache)
             else:
                 logits, self._cache = self.model.decode_step(
-                    toks, self._cache, **self._akw(self._adapter_idx))
+                    tokens, self._cache, **self._akw(self._adapter_idx))
                 if self._part is not None:
                     self._cache = self._recommit(self._cache)
             self._emit_collectives()
+            tick = _Tick(rows, t0, tt0)
+            if ahead:
+                tick.pick = self._ensure_samplers()["greedy"](logits)
+                self._ahead = tick
+                if prev is not None:
+                    telemetry.counter("serving.generate.ticks_ahead")
+            else:
+                tick.logits = logits
+        return tick
+
+    def _finish_tick(self, tick: _Tick):
+        """Sync a plain tick's pick and commit it for the rows whose
+        request is still the one the tick served."""
+        if tick.pick is not None and self._ahead is None:
+            # dispatched ahead, and nothing was queued behind it
+            telemetry.counter("serving.generate.ahead_drains")
         with tracing.phase("serve.decode.sync"):
-            step_toks = self._pick_step_tokens(logits)
+            step_toks = self._pick_step_tokens(tick.logits) \
+                if tick.pick is None else onp.asarray(tick.pick)
         # closed AFTER the tick's host sync, as the multi-tick and
         # speculative ticks close it: the tick as a caller feels it
         # (before it, on an asynchronous device, it timed the enqueue)
-        telemetry.hist_since("serving.generate.decode", t0)
+        telemetry.hist_since("serving.generate.decode", tick.t0)
         self._tick_counters(1, 1)
+        idxs = [i for i, s in tick.rows if self._slots[i] is s]
+        if len(idxs) < len(tick.rows):
+            telemetry.counter("serving.generate.stale_row_ticks",
+                              len(tick.rows) - len(idxs))
         outs = {i: [int(step_toks[i])] for i in idxs}
 
         def span(i, s, out):
             if s.stream._trace is not None:
-                s.stream._trace.add("decode", tt0, slot=i,
+                s.stream._trace.add("decode", tick.tt0, slot=i,
                                     token=out[-1])
         self._commit_outputs(idxs, outs, span)
+
+    def _drain_ahead(self):
+        """Sync and commit the tick in flight, if any: after it the
+        engine is where the synchronous loop is between two ticks."""
+        tick, self._ahead = self._ahead, None
+        if tick is not None:
+            self._finish_tick(tick)
 
     def _decode_tick_multi(self, idxs):
         """One MULTI-TICK decode tick: ``decode_ticks`` fused decode
@@ -2765,6 +2904,8 @@ class GenerationEngine:
         rejected with :class:`EngineClosedError` like a queued request,
         never handed an empty 'successful' result. Paged mode also
         rejects page-starved blocked requests."""
+        self._ahead = None  # its rows end here: nothing is emitted
+        # after a finish, so the pick is left where it lies
         for i, s in enumerate(self._slots):
             if s is not None:
                 if self.paged and s.state == "prefill":
@@ -2809,6 +2950,7 @@ class GenerationEngine:
         self._closed = True
         tracing.flight.dump("engine.fail_all",
                             error=f"{type(exc).__name__}: {exc}")
+        self._ahead = None
         for i, s in enumerate(self._slots):
             if s is not None:
                 s.stream._finish(exc=failure)

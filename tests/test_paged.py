@@ -17,6 +17,8 @@ Guarantees under test:
   (decode-stall bound, asserted via the step telemetry gauge);
 - the steady state compiles nothing (``model.gpt.trace`` flat).
 """
+import time
+
 import numpy as onp
 import pytest
 
@@ -619,3 +621,350 @@ def test_engine_paged_constructor_validation(net):
                          paged=True, page_size=16)
     with pytest.raises(ValueError, match="prefill_chunk"):
         _paged_engine(net, prefill_chunk=12)
+
+
+# -- the tick in flight (docs/SERVING.md "The tick in flight") ----------
+
+_KINDS = {
+    "dense": _dense_engine,
+    "paged": _paged_engine,
+    "paged-no-prefix": lambda net, **kw: _paged_engine(
+        net, prefix_cache=False, **kw),
+}
+
+
+def _ctr(name):
+    return telemetry.counter_value("serving.generate." + name)
+
+
+def _quiet(eng):
+    """Between two iterations of the worker: is a tick in flight?"""
+    with eng._gen_lock:
+        return eng._ahead is None
+
+
+def _sync_results(net, kind, monkeypatch, jobs, **kw):
+    """What the synchronous engine (``MXTPU_SERVING=0``: no worker, no
+    tick in flight) serves for ``jobs``, one request at a time."""
+    monkeypatch.setenv("MXTPU_SERVING", "0")
+    eng = _KINDS[kind](net, **kw)
+    telemetry.reset()
+    out = []
+    for p, n, extra in jobs:
+        s = eng.submit(p, max_new_tokens=n, **extra)
+        assert s.done()            # whole results, inline
+        out.append(s.result())
+    assert _ctr("ticks_ahead") == 0 and eng._ahead is None
+    eng.close()
+    monkeypatch.delenv("MXTPU_SERVING")
+    return out
+
+
+def _wait_tokens(stream, n, timeout=60.0):
+    t_end = time.monotonic() + timeout
+    while len(stream.tokens) < n and not stream.done():
+        assert time.monotonic() < t_end, "stream made no progress"
+        time.sleep(0.0005)
+
+
+@pytest.mark.parametrize("kind", list(_KINDS))
+def test_tick_in_flight_serves_the_synchronous_engines_tokens(
+        net, kind, monkeypatch):
+    """Greedy streams under dispatch-ahead are the synchronous engine's,
+    token for token: staggered admissions (rows enter decode while a tick
+    is in flight and are merged in on the device), mixed lengths, long
+    prompts that finish prefill while others decode, an end at the
+    cache's capacity, an exact repeat. Every end here is the host's to
+    predict, so no row-tick is wasted; the merge is no dispatch."""
+    rng = onp.random.RandomState(21)
+    lens = (3, 40, 7, 25, 12, 50, 5, 33, 18, 9, 45, 4, 50)
+    news = (20, 6, 14, 9, 12, 5, 18, 11, 8, 13, 7, 16, 30)
+    jobs = [(_prompt(rng, n), m, {}) for n, m in zip(lens, news)]
+    jobs.append((jobs[0][0].copy(), 9, {}))      # an exact repeat
+    want = _sync_results(net, kind, monkeypatch, jobs)
+    assert want[12].finish_reason == "length" \
+        and len(want[12].tokens) == SMAX - 50 + 1   # capacity, not budget
+
+    eng = _KINDS[kind](net).warmup()
+    telemetry.reset()
+    streams = []
+    for p, n, extra in jobs:
+        streams.append(eng.submit(p, max_new_tokens=n, **extra))
+        time.sleep(0.002)
+    got = [s.result(timeout=300) for s in streams]
+    for i, (g, w) in enumerate(zip(got, want)):
+        assert g.tokens == w.tokens, f"request {i} diverged"
+        assert g.finish_reason == w.finish_reason
+    assert _quiet(eng)
+    syncs = _ctr("host_syncs")
+    assert _ctr("ticks_ahead") > 0.8 * syncs > 0
+    assert _ctr("stale_row_ticks") == 0
+    assert _ctr("dispatches") == syncs     # one program a tick, as before
+    eng.close()
+
+
+class _DecodeSpy:
+    """Runs ``hook()`` in the worker, under the engine's lock, right
+    before each paged decode tick is dispatched."""
+
+    def __init__(self, net, hook):
+        self.net, self.hook = net, hook
+        self._decode = net.decode_step_paged
+        net.decode_step_paged = self.decode
+
+    def decode(self, tokens, active, cache, **kw):
+        self.hook()
+        return self._decode(tokens, active, cache, **kw)
+
+    def undo(self):
+        del self.net.decode_step_paged   # the class's method again
+
+
+def _admitted_slot(stream):
+    return next(s["attrs"]["slot"] for s in stream.trace()
+                if s["name"] == "admission")
+
+
+@pytest.mark.parametrize("tenant", ["fresh", "prefix-hit"])
+@pytest.mark.parametrize("end", ["eos", "deadline"])
+def test_unpredicted_end_costs_one_row_tick_and_nothing_else(
+        net, end, tenant):
+    """A request that ends where the host cannot predict it (its eos
+    token, its deadline) while the next tick is in flight: nothing is
+    emitted after its end, the row-tick computed for it is counted and
+    dropped, and the slot's next tenant (a fresh prompt, and an exact
+    prefix hit that binds shared pages) decodes what it decodes alone,
+    as does the co-tenant that rode both ticks."""
+    eng = _paged_engine(net, max_slots=2, max_new_tokens=40,
+                        prefix_cache=tenant == "prefix-hit").warmup()
+    rng = onp.random.RandomState(22)
+    pa, pb, pc = _prompt(rng, 5), _prompt(rng, 2 * PS + 3), _prompt(rng, 6)
+    alone = {k: eng.generate(p, max_new_tokens=n, timeout=300).tokens
+             for k, p, n in (("a", pa, 40), ("b", pb, 10), ("c", pc, 50))}
+    j = next(i for i in range(2, 30)
+             if alone["a"][i] not in alone["a"][:i])
+    telemetry.reset()
+    sc = eng.submit(pc, max_new_tokens=50)           # rides every tick
+    sa = eng.submit(pa, max_new_tokens=40, trace=True,
+                    eos_id=alone["a"][j] if end == "eos" else None)
+
+    def expire():
+        # the commit that follows this dispatch finds A past its deadline
+        if end == "deadline" and len(sa.tokens) >= 3:
+            for s in eng._slots:
+                if s is not None and s.stream is sa:
+                    s.deadline = 0.0
+    spy = _DecodeSpy(net, expire)
+    try:
+        sb = eng.submit(pb, max_new_tokens=10, trace=True)  # waits for a slot
+        ra, rb, rc = (s.result(timeout=300) for s in (sa, sb, sc))
+    finally:
+        spy.undo()
+    if end == "eos":
+        assert ra.finish_reason == "eos" and ra.tokens == alone["a"][:j + 1]
+    else:
+        assert ra.finish_reason == "timeout" and 3 <= len(ra.tokens) < 40
+        assert ra.tokens == alone["a"][:len(ra.tokens)]
+    assert _ctr("stale_row_ticks") == 1
+    assert _admitted_slot(sb) == _admitted_slot(sa)   # the next tenant
+    if tenant == "prefix-hit":
+        assert _ctr("prefix_hits") >= 1
+    assert rb.tokens == alone["b"] and rc.tokens == alone["c"]
+    assert _quiet(eng)
+    assert sa.tokens == ra.tokens      # nothing after its end
+    eng.close()
+    assert eng._pool.free_count == eng._pool.n_pages - 1
+
+
+@pytest.mark.parametrize("kind", ["dense", "paged"])
+def test_sampled_request_drains_the_tick_in_flight_and_falls_back(
+        net, kind, monkeypatch):
+    """A sampled row's key lives on the host: admitting one drains the
+    tick in flight and the engine ticks synchronously while it lives.
+    Its stream is the synchronous engine's for the same seed, its greedy
+    co-tenants' are theirs, and the engine dispatches ahead again once
+    it has gone."""
+    rng = onp.random.RandomState(23)
+    sampled = dict(temperature=0.8, top_k=20, seed=7)
+    jobs = [(_prompt(rng, 6), 40, {}), (_prompt(rng, 11), 30, {}),
+            (_prompt(rng, 9), 12, sampled), (_prompt(rng, 4), 10, {})]
+    want = _sync_results(net, kind, monkeypatch, jobs)
+    eng = _KINDS[kind](net).warmup()
+    telemetry.reset()
+    streams = [eng.submit(p, max_new_tokens=n, **extra)
+               for p, n, extra in jobs[:2]]
+    _wait_tokens(streams[0], 3)
+    ahead_before = _ctr("ticks_ahead")
+    assert ahead_before > 0
+    p, n, extra = jobs[2]
+    streams.append(eng.submit(p, max_new_tokens=n, **extra))
+    streams[2].result(timeout=300)
+    assert _ctr("ahead_drains") >= 1
+    ahead_mid = _ctr("ticks_ahead")
+    p, n, extra = jobs[3]
+    streams.append(eng.submit(p, max_new_tokens=n, **extra))
+    got = [s.result(timeout=300) for s in streams]
+    for i, (g, w) in enumerate(zip(got, want)):
+        assert g.tokens == w.tokens, f"request {i} diverged"
+    assert _ctr("ticks_ahead") > ahead_mid     # ahead again
+    assert _ctr("stale_row_ticks") == 0
+    assert _quiet(eng)
+    eng.close()
+
+
+@pytest.mark.parametrize("event", ["close", "hard-close", "load_weights",
+                                   "warmup", "crash", "step-failure"])
+def test_no_tick_in_flight_and_no_hung_stream_across(net, event,
+                                                     monkeypatch):
+    """``close()``, a rollover, a ``warmup()`` under load and a worker
+    failure all meet a tick in flight: every stream still finishes
+    exactly once, whoever holds a step boundary sees nothing in flight,
+    and where the engine lives on its tokens are the undisturbed
+    run's."""
+    from mxnet_tpu import checkpoint
+    from mxnet_tpu.serving import ReplicaFailedError
+    from mxnet_tpu.serving.faults import FaultInjector
+    eng = _paged_engine(net, max_new_tokens=40).warmup()
+    rng = onp.random.RandomState(24)
+    prompts = [_prompt(rng, n) for n in (5, 9, 20, 3, 7, 12)]
+    want = [eng.generate(p, timeout=300).tokens for p in prompts]
+    seen = []
+
+    def boundary(fn):
+        def at_boundary(*a, **kw):
+            seen.append(eng._ahead)
+            return fn(*a, **kw)
+        return at_boundary
+    telemetry.reset()
+    streams = [eng.submit(p) for p in prompts]
+    _wait_tokens(streams[0], 3)
+    assert _ctr("ticks_ahead") > 0
+    if event == "close":
+        eng.close(timeout=300)
+    elif event == "hard-close":
+        eng.close(timeout=0.0)
+    elif event == "load_weights":
+        monkeypatch.setattr(checkpoint, "swap_param_buffers",
+                            boundary(checkpoint.swap_param_buffers))
+        eng.load_weights({k: onp.asarray(p.data()._data) for k, p in
+                          net.collect_params().items()})
+    elif event == "warmup":
+        monkeypatch.setattr(eng, "_warmup_paged",
+                            boundary(eng._warmup_paged))
+        eng.warmup()
+        assert telemetry.counter_value("model.gpt.trace") == 0
+    elif event == "crash":
+        monkeypatch.setattr(eng, "_fail_all", boundary(eng._fail_all))
+        FaultInjector().crash(eng)
+    else:
+        boom = RuntimeError("tick exploded")
+
+        def explode():
+            raise boom
+        spy = _DecodeSpy(net, explode)
+    outcomes = []
+    try:
+        for s, w in zip(streams, want):
+            try:
+                r = s.result(timeout=300)
+            except (EngineClosedError, ReplicaFailedError) as e:
+                outcomes.append(type(e).__name__)
+                assert s.tokens == w[:len(s.tokens)]
+                continue
+            outcomes.append(r.finish_reason)
+            assert r.tokens == w[:len(r.tokens)]
+            assert r.finish_reason == "closed" or r.tokens == w
+            assert s.tokens == r.tokens
+    finally:
+        if event == "step-failure":
+            spy.undo()
+    if event in ("close", "load_weights", "warmup"):
+        assert outcomes == ["length"] * len(prompts), outcomes
+    if event in ("crash", "step-failure"):
+        assert "ReplicaFailedError" in outcomes
+    if event in ("load_weights", "warmup", "crash"):
+        assert seen == [None]      # a step boundary has nothing in flight
+    assert _quiet(eng)
+    eng.close()
+    assert eng._pool.free_count == eng._pool.n_pages - 1
+
+
+@pytest.mark.parametrize("kind", list(_KINDS))
+def test_tick_in_flight_compiles_nothing_after_warmup(net, kind):
+    """Fifty and more ticks with admissions, prefix hits and evictions
+    after ``warmup()``: no trace of a model or sampler program, and no
+    compile the trace counters cannot see (the decode program takes a
+    committed device array as its tokens on every path, the merge
+    program was warmed on the pick as it is placed)."""
+    import jax
+    eng = _KINDS[kind](net, queue_limit=128).warmup()
+    rng = onp.random.RandomState(25)
+    shared = _prompt(rng, 2 * PS)
+    eng.generate(shared, max_new_tokens=3, timeout=300)
+    compiles = []
+
+    def listener(name, _secs, **_kw):
+        if name == "/jax/core/compile/backend_compile_duration":
+            compiles.append(name)
+    jax.monitoring.register_event_duration_secs_listener(listener)
+    try:
+        telemetry.reset()
+        wave = []
+        for i in range(24):
+            p = shared if i % 5 == 4 else \
+                onp.concatenate([shared, _prompt(rng, 1 + i % 7)]) \
+                if i % 3 == 0 else _prompt(rng, 3 + (7 * i) % 40)
+            wave.append(eng.submit(p, max_new_tokens=10 + i % 9))
+            time.sleep(0.001)
+        for s in wave:
+            assert len(s.result(timeout=300).tokens) >= 1
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listener)
+    assert _ctr("host_syncs") >= 50 and _ctr("ticks_ahead") >= 40
+    assert telemetry.counter_value("model.gpt.trace") == 0
+    assert telemetry.counter_value("ops.sampling.trace") == 0
+    assert not compiles, "a program compiled after warm-up"
+    eng.close()
+
+
+def test_tick_in_flight_under_more_callers_than_cores(net):
+    """Sixteen closed-loop callers on a shortened switch interval, every
+    commit waking one of them while the worker builds the next tick:
+    each request still gets the tokens it gets alone, every stream
+    finishes, and nothing is left in flight."""
+    import sys
+    import threading
+    eng = _paged_engine(net, queue_limit=256).warmup()
+    rng = onp.random.RandomState(26)
+    jobs = [(_prompt(rng, 3 + (5 * i) % 40), 4 + i % 9) for i in range(12)]
+    want = [eng.generate(p, max_new_tokens=n, timeout=300).tokens
+            for p, n in jobs]
+    telemetry.reset()
+    bad, t_end = [], time.monotonic() + 6.0
+
+    def caller(seed):
+        r = onp.random.RandomState(seed)
+        while time.monotonic() < t_end and len(bad) < 3:
+            i = int(r.randint(len(jobs)))
+            got = eng.generate(jobs[i][0], max_new_tokens=jobs[i][1],
+                               timeout=300).tokens
+            if got != want[i]:
+                bad.append((i, got))
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-4)
+    try:
+        threads = [threading.Thread(target=caller, args=(s,))
+                   for s in range(16)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=120)
+    finally:
+        sys.setswitchinterval(old)
+    assert not any(t.is_alive() for t in threads)
+    assert not bad, bad[:3]
+    assert _ctr("ticks_ahead") > 0.5 * _ctr("host_syncs") > 0
+    assert _ctr("stale_row_ticks") == 0
+    assert _quiet(eng)
+    eng.close()
+    assert eng._pool.free_count == eng._pool.n_pages - 1

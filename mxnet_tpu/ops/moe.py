@@ -6,10 +6,10 @@ them all (``lo = 0``, ``n_held = E_all``: every pick is kept). Either
 routes every token over ALL experts at the published router width
 (``route_sigmoid_topk``), keeps the picks that fall on the experts it
 holds, and computes their part of the result (``expert_layer``): sort the
-kept picks by expert, three grouped products over the held experts,
-unsort, combine with the gates. No capacity, no dropped token; what
-absent experts would add is left out (their chips add it), and no code
-stands in for them or their traffic.
+kept picks by expert, three grouped products over the held experts (two
+for ungated ones), unsort, combine with the gates. No capacity, no
+dropped token; what absent experts would add is left out (their chips
+add it), and no code stands in for them or their traffic.
 ``parallel/moe.py`` is the training-side top-1 operator over an ``ep``
 mesh axis; nothing of it is used here.
 
@@ -130,7 +130,7 @@ def _fit(total, want):
     return best
 
 
-def _gmm_kernel(te_ref, x_ref, w_ref, o_ref, acc_ref, *, nk):
+def _gmm_kernel(te_ref, x_ref, w_ref, o_ref, acc_ref, *, nk, transposed):
     del te_ref
     k = pl.program_id(2)
 
@@ -138,8 +138,13 @@ def _gmm_kernel(te_ref, x_ref, w_ref, o_ref, acc_ref, *, nk):
     def _zero():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc_ref[...] += jnp.dot(x_ref[...], w_ref[...],
-                            preferred_element_type=jnp.float32)
+    if transposed:                  # both blocks contract their minor dim
+        acc_ref[...] += lax.dot_general(
+            x_ref[...], w_ref[...], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+    else:
+        acc_ref[...] += jnp.dot(x_ref[...], w_ref[...],
+                                preferred_element_type=jnp.float32)
 
     @pl.when(k == nk - 1)
     def _store():
@@ -150,27 +155,39 @@ def _gmm_kernel(te_ref, x_ref, w_ref, o_ref, acc_ref, *, nk):
 BLOCK_K, BLOCK_N = 1024, 768
 
 
-def grouped_matmul_pallas(x, w, tile_expert, n_active, tm):
+def grouped_matmul_pallas(x, w, tile_expert, n_active, tm,
+                          transposed=False):
     """``out[r] = x[r] @ w[tile_expert[r // tm]]`` for the rows of the
     first ``n_active`` tiles; the other rows are left as they come.
-    ``x`` (M, K), ``w`` (E, K, N). The grid is ``(N tiles, active tiles,
-    K tiles)``: a tile's expert is read from the scalar-prefetched
-    ``tile_expert``, so each active tile streams one expert's weights
-    once, and a tile that holds no rows costs no step."""
+    ``x`` (M, K), ``w`` (E, K, N), or (E, N, K) held output-major with
+    ``transposed``. The grid is ``(N tiles, active tiles, K tiles)``: a
+    tile's expert is read from the scalar-prefetched ``tile_expert``, so
+    each active tile streams one expert's weights once, and a tile that
+    holds no rows costs no step."""
     from jax.experimental.pallas import tpu as pltpu
     m, kk = x.shape
-    _, _, n = w.shape
-    tk, tn = _fit(kk, BLOCK_K), _fit(n, BLOCK_N)
+    if transposed:
+        n = w.shape[1]
+        # a whole row of a block is ``tn`` wide: at float32 half as much
+        # of ``K`` fits the scoped VMEM
+        tk = _fit(kk, BLOCK_K * 2 // w.dtype.itemsize)
+        w_spec = pl.BlockSpec((None, _fit(n, BLOCK_N), tk),
+                              lambda j, i, k, te: (te[i], j, k))
+    else:
+        n = w.shape[2]
+        tk = _fit(kk, BLOCK_K)
+        w_spec = pl.BlockSpec((None, tk, _fit(n, BLOCK_N)),
+                              lambda j, i, k, te: (te[i], k, j))
+    tn = _fit(n, BLOCK_N)
     nk = kk // tk
     return pl.pallas_call(
-        functools.partial(_gmm_kernel, nk=nk),
+        functools.partial(_gmm_kernel, nk=nk, transposed=transposed),
         out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             in_specs=[
                 pl.BlockSpec((tm, tk), lambda j, i, k, te: (i, k)),
-                pl.BlockSpec((None, tk, tn),
-                             lambda j, i, k, te: (te[i], k, j)),
+                w_spec,
             ],
             out_specs=pl.BlockSpec((tm, tn), lambda j, i, k, te: (i, j)),
             grid=(n // tn, n_active, nk),
@@ -181,44 +198,64 @@ def grouped_matmul_pallas(x, w, tile_expert, n_active, tm):
     )(tile_expert, x, w)
 
 
-def grouped_matmul(x, w, tile_expert, n_active, tm):
+def grouped_matmul(x, w, tile_expert, n_active, tm, transposed=False):
     """The grouped product of ``dispatch``'s buffer: the Pallas kernel on
-    a TPU, else every row against its tile's expert in ``jnp``."""
+    a TPU, else every row against its tile's expert in ``jnp``. With
+    ``transposed`` each expert's matrix is held output-major, ``w`` (E,
+    N, K): a leaf ``(E, K, N)`` whose minor width ``N`` is no multiple of
+    128 is laid out by the chip with ``K`` minor-most, and a kernel that
+    asks for it row-major gets a copy of the whole leaf before every
+    call."""
     if _att._use_pallas():
-        return grouped_matmul_pallas(x, w, tile_expert, n_active, tm)
+        return grouped_matmul_pallas(x, w, tile_expert, n_active, tm,
+                                     transposed)
     row_expert = jnp.repeat(tile_expert, tm)
-    return jnp.einsum("mk,mkn->mn", x, w[row_expert],
+    return jnp.einsum("mk,mnk->mn" if transposed else "mk,mkn->mn",
+                      x, w[row_expert],
                       preferred_element_type=jnp.float32).astype(x.dtype)
 
 
-def routed_layer(z32, z, p, top_k, lo, scaling_factor=1.0):
+def routed_layer(z32, z, p, top_k, lo, scaling_factor=1.0, up_t=False):
     """A routed layer's held part on the normed input: ``z32`` (T, D)
     float32 for the router, ``z`` the same in the model's dtype for the
     experts; ``p`` the layer's arrays (``router``, ``router_bias``,
-    ``e_gate``, ``e_up``, ``e_down``); ``lo`` the first expert held.
+    ``e_up``, ``e_down``, and ``e_gate`` where the experts are gated);
+    ``lo`` the first expert held; ``up_t`` as ``expert_layer``'s.
     Returns (T, D) float32 and the number of held experts hit."""
     ids, gates = route_sigmoid_topk(z32, p["router"], p["router_bias"],
                                     top_k, scaling_factor)
-    return expert_layer(z, p["e_gate"], p["e_up"], p["e_down"], ids, gates,
-                        lo, tile_rows(z.shape[0] * top_k))
+    return expert_layer(z, p.get("e_gate"), p["e_up"], p["e_down"], ids,
+                        gates, lo, tile_rows(z.shape[0] * top_k), up_t)
 
 
-def expert_layer(z, w_gate, w_up, w_down, ids, gates, lo, tm):
-    """``sum_{i chosen, held} g_i E_i(z)`` with ``E(z; W) = (silu(z
-    W_gate) * (z W_up)) W_down``: the held experts' part of a routed
-    layer. ``z`` (T, D); ``w_gate`` / ``w_up`` (E_held, D, F), ``w_down``
-    (E_held, F, D); ``ids`` / ``gates`` (T, k) from the router over all
-    experts; ``lo`` the first expert held. Returns (T, D) float32 and
+def relu2(x):
+    """``relu(x)^2``, the activation of ungated experts."""
+    return jnp.square(jax.nn.relu(x))
+
+
+def expert_layer(z, w_gate, w_up, w_down, ids, gates, lo, tm, up_t=False):
+    """``sum_{i chosen, held} g_i E_i(z)``: the held experts' part of a
+    routed layer, with ``E(z; W) = (silu(z W_gate) * (z W_up)) W_down``,
+    or, where ``w_gate`` is None, the ungated ``E(z; W) = relu(z
+    W_up)^2 W_down`` (two grouped products). ``z`` (T, D); ``w_gate`` /
+    ``w_up`` (E_held, D, F), ``w_up`` (E_held, F, D) output-major with
+    ``up_t`` (``grouped_matmul``'s ``transposed``); ``w_down`` (E_held,
+    F, D); ``ids`` / ``gates`` (T, k) from the router over all experts;
+    ``lo`` the first expert held. Returns (T, D) float32 and
     ``dispatch``'s ``n_hit``."""
-    n_held = w_gate.shape[0]
+    n_held = w_up.shape[0]
     row_token, pick_row, tile_expert, n_active, n_hit = dispatch(
         ids, lo, n_held, tm)
     m = row_token.shape[0]
     xb = z[row_token]
-    g = grouped_matmul(xb, w_gate, tile_expert, n_active, tm)
-    u = grouped_matmul(xb, w_up, tile_expert, n_active, tm)
-    h = (jax.nn.silu(g.astype(jnp.float32))
-         * u.astype(jnp.float32)).astype(z.dtype)
+    if w_gate is None:
+        u = grouped_matmul(xb, w_up, tile_expert, n_active, tm, up_t)
+        h = relu2(u.astype(jnp.float32)).astype(z.dtype)
+    else:
+        g = grouped_matmul(xb, w_gate, tile_expert, n_active, tm)
+        u = grouped_matmul(xb, w_up, tile_expert, n_active, tm, up_t)
+        h = (jax.nn.silu(g.astype(jnp.float32))
+             * u.astype(jnp.float32)).astype(z.dtype)
     yb = grouped_matmul(h, w_down, tile_expert, n_active, tm)
     held = pick_row < m
     rows = yb[jnp.minimum(pick_row, m - 1)]              # (T, k, D)

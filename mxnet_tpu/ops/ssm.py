@@ -1,4 +1,4 @@
-"""The selective state-space recurrence (Mamba-1), for serving.
+"""State-space recurrences for serving: Mamba-1, then Mamba-2 (below).
 
 One token ``t`` of one sequence, ``C`` channels each with an ``N``-wide
 state: ``h_t = exp(dt_t (x) A) * h_{t-1} + (dt_t * x_t) (x) B_t``,
@@ -193,3 +193,172 @@ def selective_step(x, dt, a, b, c, d, h, active):
     m = jnp.sum(new * c.astype(_F32)[:, :, None], axis=1) \
         + d.astype(_F32)[None, :] * x
     return m, jnp.where(active[:, None, None], new, h)
+
+
+# ---------------------------------------------------------------------------
+# Mamba-2: a scalar decay a head, B and C shared by a group of heads
+#
+# ``H`` heads of ``P`` channels, each with a state ``S`` (P, N); head ``h``
+# reads group ``h // (H / G)`` of ``B_t`` and ``C_t`` (G, N):
+# ``S_t = exp(dt_t a_h) S_{t-1} + dt_t x_t B_t^T``, ``y_t = S_t C_t + d_h
+# x_t``. Float32, the same rules as above (a padded position's step is 0).
+# Over a chunk the kernel ``ssd_chunk_scan`` takes the state-space-duality
+# form: within a block of ``chunk`` positions ``y = ((C B^T) o L) (dt x) +
+# exp(cs) C S_prev^T + d x`` with ``L[t, s] = exp(cs_t - cs_s)`` for ``s <=
+# t`` (``cs`` the block's inclusive cumulative sum of ``dt a``), products
+# for the MXU; between blocks the state, carried in VMEM. A tick's one
+# position is ``ssd_step``, ``jnp``.
+# ---------------------------------------------------------------------------
+#: the Mamba-2 chunk kernel's name in a device trace (docs/OBSERVABILITY.md)
+SSD_KERNEL_NAME = "ssd_chunk_scan"
+_HI = lax.Precision.HIGHEST
+
+
+def _ssd_jnp(x, dt, a, b, c, d, h0):
+    """The recurrence stepped position by position: ``x`` (T, H, P),
+    ``dt`` (T, H), ``b``/``c`` (T, G, N), ``h0`` (H, P, N)."""
+    g = b.shape[1]
+
+    def step(h, args):
+        x_t, dt_t, b_t, c_t = args
+        hh = h.reshape(g, -1, *h.shape[1:])             # (G, H/G, P, N)
+        decay = jnp.exp(dt_t * a).reshape(g, -1)[:, :, None, None]
+        dtx = (dt_t[:, None] * x_t).reshape(g, -1, x_t.shape[1])
+        hh = decay * hh + dtx[..., None] * b_t[:, None, None, :]
+        y = jnp.sum(hh * c_t[:, None, None, :], axis=-1)
+        return hh.reshape(h.shape), y.reshape(x_t.shape) + d[:, None] * x_t
+
+    h_end, y = lax.scan(step, h0, (x, dt, b, c))
+    return y, h_end
+
+
+def _ssd_kernel(a_ref, d_ref, dt_ref, xt_ref, b_ref, ct_ref, h0_ref,
+                yt_ref, h_ref, *, heads, p, q):
+    """One (group, block) grid step: the ``heads`` heads of one group
+    over ``q`` positions, in the state-space-duality form. Time runs
+    along lanes: ``dt`` (heads, Q), ``x`` and ``y`` transposed (heads P,
+    Q), ``C`` transposed (N, Q), ``B`` (Q, N). ``h_ref`` (the output's
+    block, which stays in VMEM over the block axis) carries the group's
+    states (heads, P, N) from one block to the next."""
+    g = pl.program_id(0)
+
+    @pl.when(pl.program_id(1) == 0)
+    def _take_up():
+        h_ref[...] = h0_ref[...]
+
+    s_i = lax.broadcasted_iota(jnp.int32, (q, q), 0)
+    t_i = lax.broadcasted_iota(jnp.int32, (q, q), 1)
+    eye = (s_i == t_i).astype(_F32)
+    upto = s_i <= t_i                                  # source s, target t
+    b, ct = b_ref[...], ct_ref[...]
+    # (B C^T)[s, t]: what position t reads of what position s wrote,
+    # shared by the group's heads
+    bc = jnp.dot(b, ct, precision=_HI, preferred_element_type=_F32)
+    dts = dt_ref[...]
+    for i in range(heads):
+        h = g * heads + i
+        dt_row = dts[i:i + 1, :]                       # (1, Q)
+        la_row = dt_row * a_ref[h]
+        # rows to columns through the identity: exact, no transpose
+        la_col = jnp.sum(eye * la_row, axis=1, keepdims=True)
+        dt_col = jnp.sum(eye * dt_row, axis=1, keepdims=True)
+        cs_row = jnp.sum(jnp.where(upto, la_col, 0.0), axis=0,
+                         keepdims=True)                # (1, Q) inclusive
+        cs_col = jnp.sum(eye * cs_row, axis=1, keepdims=True)
+        cs_end = jnp.sum(la_row, axis=1, keepdims=True)  # (1, 1)
+        decay = jnp.exp(jnp.where(upto, cs_row - cs_col, -1e30))
+        m_t = bc * decay * dt_col                      # (Q_s, Q_t)
+        xt = xt_ref[i * p:(i + 1) * p, :]              # (P, Q)
+        state = h_ref[i]                               # (P, N)
+        yt = jnp.dot(xt, m_t, precision=_HI, preferred_element_type=_F32)
+        yt += jnp.dot(state, ct, precision=_HI,
+                      preferred_element_type=_F32) * jnp.exp(cs_row)
+        yt_ref[i * p:(i + 1) * p, :] = yt + d_ref[h] * xt
+        w_col = dt_col * jnp.exp(cs_end - cs_col)      # (Q, 1)
+        h_ref[i] = jnp.exp(cs_end) * state + jnp.dot(
+            xt, b * w_col, precision=_HI, preferred_element_type=_F32)
+
+
+def ssd_block(t, chunk):
+    """Positions a block of the kernel: ``chunk`` where it divides
+    ``t``, else the whole of ``t``."""
+    return chunk if t % chunk == 0 else t
+
+
+def ssd_kernel_takes(heads, groups, p, n, t, chunk):
+    """The kernel tiles a group's heads by whole (8, 128) registers."""
+    hg, q = heads // groups, ssd_block(t, chunk)
+    return (n % _LANES == 0 and (hg * p) % 8 == 0 and hg % 8 == 0
+            and (q % _LANES == 0 or q == t))
+
+
+def ssd_scan_pallas(x, dt, a, b, c, d, h0, chunk, interpret=False):
+    """The kernel on ``x`` (T, H, P), ``dt`` (T, H) (already 0 past the
+    valid positions), ``a``/``d`` (H,), ``b``/``c`` (T, G, N), ``h0``
+    (H, P, N), float32. The grid is (groups, blocks of ``chunk``)."""
+    from jax.experimental.pallas import tpu as pltpu
+    t, hh, p = x.shape
+    g, n = b.shape[1:]
+    hg, q = hh // g, ssd_block(t, chunk)
+    xt = x.astype(_F32).reshape(t, hh * p).T
+    ct = c.astype(_F32).reshape(t, g * n).T
+    rows = pl.BlockSpec((hg * p, q), lambda gi, j: (gi, j))
+    state = pl.BlockSpec((hg, p, n), lambda gi, j: (gi, 0, 0))
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    yt, h_end = pl.pallas_call(
+        functools.partial(_ssd_kernel, heads=hg, p=p, q=q),
+        out_shape=(jax.ShapeDtypeStruct((hh * p, t), _F32),
+                   jax.ShapeDtypeStruct((hh, p, n), _F32)),
+        grid=(g, t // q),
+        in_specs=[smem, smem,
+                  pl.BlockSpec((hg, q), lambda gi, j: (gi, j)), rows,
+                  pl.BlockSpec((q, n), lambda gi, j: (j, gi)),
+                  pl.BlockSpec((n, q), lambda gi, j: (gi, j)), state],
+        out_specs=(rows, state),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+        name=SSD_KERNEL_NAME,
+    )(a.astype(_F32), d.astype(_F32), dt.astype(_F32).T, xt,
+      b.astype(_F32).reshape(t, g * n), ct, h0.astype(_F32))
+    return yt.T.reshape(t, hh, p), h_end
+
+
+def ssd_scan(x, dt, a, b, c, d, h0, n_valid, chunk=128):
+    """``T`` positions of one sequence from state ``h0`` (H, P, N):
+    ``x`` (T, H, P), ``dt`` (T, H) after its softplus, ``a`` (H,)
+    negative, ``b``/``c`` (T, G, N), head ``h`` reading group ``h //
+    (H / G)``, ``d`` (H,), ``n_valid`` an int32 scalar. ``S_t =
+    exp(dt_t a) S_{t-1} + dt_t x_t B_t^T``, ``y_t = S_t C_t + d x_t``.
+    Returns ``y`` (T, H, P) and the state after position ``n_valid -
+    1``, float32; rows of ``y`` at or past ``n_valid`` mean nothing. On a
+    TPU the Pallas kernel ``ssd_chunk_scan`` over blocks of ``chunk``
+    positions, elsewhere a ``lax.scan`` of the recurrence."""
+    t = x.shape[0]
+    live = jnp.arange(t, dtype=jnp.int32) < n_valid
+    dt = jnp.where(live[:, None], dt.astype(_F32), 0.0)
+    args = (x.astype(_F32), dt, a.astype(_F32), b.astype(_F32),
+            c.astype(_F32), d.astype(_F32), h0.astype(_F32))
+    if _att._use_pallas() and ssd_kernel_takes(
+            x.shape[1], b.shape[1], x.shape[2], b.shape[2], t, chunk):
+        return ssd_scan_pallas(*args, chunk)
+    return _ssd_jnp(*args)
+
+
+def ssd_step(x, dt, a, b, c, d, h, active):
+    """One position of every slot: ``x`` (B, H, P), ``dt`` (B, H), ``b``,
+    ``c`` (B, G, N), ``h`` (B, H, P, N), ``active`` (B,) bool. Returns
+    ``y`` (B, H, P) and the states, those of inactive rows as they
+    came. ``jnp``, float32."""
+    bb, hh, p = x.shape
+    g = b.shape[1]
+    x, dt, h = x.astype(_F32), dt.astype(_F32), h.astype(_F32)
+    hg = h.reshape(bb, g, hh // g, p, -1)
+    decay = jnp.exp(dt * a.astype(_F32)).reshape(bb, g, -1)
+    dtx = (dt[..., None] * x).reshape(bb, g, hh // g, p)
+    new = decay[..., None, None] * hg \
+        + dtx[..., None] * b.astype(_F32)[:, :, None, None, :]
+    y = jnp.sum(new * c.astype(_F32)[:, :, None, None, :], axis=-1)
+    y = y.reshape(bb, hh, p) + d.astype(_F32)[None, :, None] * x
+    new = new.reshape(h.shape)
+    return y, jnp.where(active[:, None, None, None], new, h)

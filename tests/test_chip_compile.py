@@ -499,3 +499,94 @@ def test_xing4_tick_has_the_operations_mhc_share_reads(
                for n in ran) == 3 * 4
     # no pool is copied and no view is split into heads
     assert not [n for n in ran if n.startswith("copy ") and "4609" in n]
+
+
+# -- the nemotronh family at its published widths: the Mamba-2 chunk kernel
+# -- (64 heads x 64 channels, 8 groups of 128 states, blocks of 128) and the
+# -- decode program of the cell's 64 slots, whose state update
+# -- model.ssd_share finds by shape
+@pytest.mark.parametrize("t", [512, 64], ids=["chunk", "bucket"])
+def test_ssd_chunk_scan(one_chip, no_compile_cache, t):
+    from mxnet_tpu.ops import ssm
+    h, p, g, n = 64, 64, 8, 128
+    f32 = jnp.float32
+
+    def fn(x, dt, a, b, c, d, h0):
+        with jax.named_scope("ssd"):
+            return ssm.ssd_scan_pallas(x, dt, a, b, c, d, h0, 128)
+
+    text = _compile(fn, one_chip, ((t, h, p), f32), ((t, h), f32),
+                    ((h,), f32), ((t, g, n), f32), ((t, g, n), f32),
+                    ((h,), f32), ((h, p, n), f32))
+    calls = [line.strip() for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 1, calls
+    inst = calls[0].removeprefix("ROOT ")
+    assert inst.split(" = ")[0].lstrip("%").split(".")[0] \
+        == ssm.SSD_KERNEL_NAME, inst[:120]
+    assert f"/ssd/{ssm.SSD_KERNEL_NAME}" in \
+        inst.split("op_name=")[1].split('"')[1]
+
+
+def test_nemotronh_tick_has_the_operations_ssd_share_reads(
+        one_chip, no_compile_cache, monkeypatch):
+    """``model.ssd_share`` finds the tick's state update by the SHAPE of
+    its result (a layer's float32 states of all 64 slots), which is the
+    compiler's to choose: the decode program of ``NemotronHModel`` at the
+    published widths and the cell's 64 slots, compiled for the described
+    v5e with the expert kernel as the chip runs it, holds one such
+    operation a Mamba-2 layer, and two expert kernels a routed layer
+    under the name ``model.moe_share`` reads, neither handed a copy of
+    its leaf."""
+    import json
+    import os
+    from chipbench import trace_reduce
+    from chipbench.families.nemotronh import program, weights
+    from mxnet_tpu.gluon.model_zoo.nemotronh import NemotronHModel
+    from mxnet_tpu.ops import moe
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "chipbench", "configs",
+                           "nemotron3-nano-30b-a3b.json")) as f:
+        cfg = json.load(f)
+    with open(os.path.join(root, "chipbench", "layer_metrics",
+                           "model.ssd_share.json")) as f:
+        pattern = re.compile(json.load(f)["args"]["pattern"])
+    model, serve = cfg["model"], cfg["serve"]
+    s = weights.sizes(model)
+    net = NemotronHModel(
+        n_routed_experts=s["E_all"],
+        experts_held=range(s["E_lo"], s["E_lo"] + s["E_held"]),
+        **{k: model[k] for k in program._KEYS},
+        max_length=serve["max_length"])
+    # the program asks which backend runs it and would take the CPU's
+    # ``jnp`` product: the test steers it to the chip's kernel
+    monkeypatch.setattr(att, "_use_pallas", lambda: True)
+
+    def on_chip(p):
+        return jax.ShapeDtypeStruct(p.shape, jnp.dtype(p.dtype),
+                                    sharding=one_chip)
+
+    layers = [{k: on_chip(p) for k, p in net._params[li].items()}
+              for li in range(s["L"])]
+    top = {k: on_chip(p) for k, p in net._params[None].items()}
+    slots, ps = serve["max_slots"], serve["page_size"]
+    cache = jax.tree.map(on_chip, jax.eval_shape(
+        lambda: net.init_paged_cache(
+            slots, slots * serve["max_length"] // ps + 1, ps,
+            serve["max_length"])))
+    rows = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one_chip)
+    text = jax.jit(net._decode_body).lower(
+        layers, top, rows, rows, cache).compile().as_text()
+    names = [trace_reduce.short(line.strip().removeprefix("ROOT "))[0]
+             for line in text.splitlines() if " = " in line]
+    # a fusion is an operation the device runs and the trace times
+    ran = [n for n in names if n.startswith(("fusion ", "custom-call "))]
+    hits = [n for n in ran if pattern.search(n)]
+    assert len(hits) >= s["pattern"].count("M"), sorted(set(ran))[:40]
+    assert sum(n.startswith(f"custom-call {moe.KERNEL_NAME} ")
+               for n in ran) == 2 * s["pattern"].count("E")
+    # no expert leaf is copied into another layout before its kernel
+    leaves = (f"bf16[{s['E_held']},{s['F']},{s['D']}]",
+              f"bf16[{s['E_held']},{s['D']},{s['F']}]")
+    assert not [n for n in names
+                if n.startswith("copy ") and n.endswith(leaves)]

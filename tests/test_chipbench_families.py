@@ -12,8 +12,9 @@ chipbench/tests`` on this tree; here they are left out of the cases taken
 over and superseded by the two cases marked ``SUPERSEDES`` below, which
 say the same for every family by whole words. The ``benchmark`` PR that
 edits the two in place deletes the two marked here (``PERF.md`` section 7).
-The ``dots3``, ``phi4flash`` and ``xing4`` families are held to the same
-interface, and their configurations to the catalog's published keys.
+The ``dots3``, ``phi4flash``, ``xing4`` and ``nemotronh`` families are
+held to the same interface, and their configurations to the catalog's
+published keys.
 """
 import importlib.util
 import json
@@ -71,6 +72,9 @@ NAMES = {
     "xing4": ("Xing4Model", "model.xing4.trace", "families.xing4",
               "hc_mult", "hc_sinkhorn_iters", "mhc_h_res_clamp_min",
               "num_nextn_predict_layers", "rope_scaling"),
+    "nemotronh": ("NemotronHModel", "model.nemotronh.trace",
+                  "families.nemotronh", "hybrid_override_pattern",
+                  "mamba_num_heads", "ssd_chunk_scan_call"),
 }
 
 
@@ -434,3 +438,139 @@ def test_xing4_cell_reads_its_own_programs_and_the_shared_kernel():
     assert rx.search("jit_xing4_paged_decode(6)") \
         and not rx.search("jit_xing4_paged_chunk(123)") \
         and not rx.search("jit_dots3_paged_decode(45)")
+
+
+# -- the nemotronh configuration: the catalog's row, rank 0 of a two-chip stage
+NEMO = "nemotron3-nano-30b-a3b"
+NEMO_CELL = NEMO + ".serve.chat-highrate"
+
+
+@pytest.fixture(scope="module")
+def nemo_config():
+    return harness.load_json("configs", NEMO + ".json")
+
+
+def test_nemotronh_configuration_is_the_catalog_entry_but_for_reduced(
+        nemo_config):
+    """Every width as published; the depth (one period, layers 0-8), the
+    experts held (64 of 128: rank 0 of two) and the vocabulary (half)
+    are cut, and each stands in ``reduced`` with the published value."""
+    assert nemo_config["reduced"] == [
+        "num_hidden_layers", "hybrid_override_pattern", "n_routed_experts",
+        "vocab_size"]
+    pub = nemo_config["published"]
+    assert (pub["num_hidden_layers"], pub["n_routed_experts"],
+            pub["vocab_size"]) == (52, 128, 131072)
+    assert pub["hybrid_override_pattern"][:9] == "MEMEM*EME" \
+        == nemo_config["hybrid_override_pattern"]
+    model = nemo_config["model"]
+    assert (model["ep_size"], model["ep_rank"]) == (2, 0)
+    assert model["n_routed_experts"] * model["ep_size"] == 128
+    assert model["vocab_size"] * model["ep_size"] == 131072
+    assert "two chips share each layer" in nemo_config["deployment"]
+    bench = harness.load_benchmark()
+    listed = next(c for c in bench["configs"] if c["name"] == NEMO)
+    assert listed["reduced"] == nemo_config["reduced"]
+    if not os.path.isfile(CATALOG):
+        pytest.skip("no catalog beside the model-configs guide here")
+    with open(CATALOG) as f:
+        rows = [json.loads(line) for line in f]
+    entry = next(r for r in rows
+                 if r["name"] == "NVIDIA-Nemotron-3-Nano-30B-A3B-BF16")
+    assert nemo_config["source"] == entry["source_url"] == listed["source"]
+    for key, value in entry["config"].items():
+        if key in nemo_config["reduced"]:
+            assert pub[key] == value, key
+        else:
+            assert nemo_config[key] == value, key
+            assert model[key] == value, key
+
+
+def test_nemotronh_configuration_states_its_keys_twice_alike(nemo_config):
+    model = nemo_config["model"]
+    extra = {"ep_size", "ep_rank", "initializer_range"}
+    for key, value in model.items():
+        if key not in extra:
+            assert nemo_config[key] == value, key
+    assert extra <= set(model)
+    serve = nemo_config["serve"]
+    assert serve["prefix_cache"] is False and serve["max_slots"] == 64
+    mix = harness.load_json("traffic", "closed64-chat.json")
+    assert serve["max_length"] == mix["prompt_tokens"]["max"] \
+        + mix["new_tokens"]["max"] == 3072
+    for key in ("d_inner", "no_rotary", "initializer_range", "mamba2_init",
+                "e_score_correction_bias"):
+        assert len(nemo_config["assumed"][key]) > 20, key
+
+
+def test_nemotronh_parameter_count_is_the_share_the_configuration_states(
+        nemo_config):
+    """The stage's count: a Mamba-2 layer 38,744,896, the attention layer
+    23,399,040, a routed layer with 64 of its 128 experts 658,885,376, the
+    embedding and head over half the vocabulary 352,324,224: 3,166,244,352
+    parameters, 6.33 GB in bfloat16."""
+    weights = harness.family(nemo_config, "weights")
+    s = weights.sizes(nemo_config["model"])
+    by_kind = {s["pattern"][i]: sum(int(np.prod(sh)) for _, sh, _ in
+                                    weights.layer_leaves(s, i))
+               for i in range(s["L"])}
+    assert by_kind == {"M": 38_744_896, "*": 23_399_040,
+                       "E": 658_885_376}
+    n = weights.parameter_count(s)
+    assert n == 3_166_244_352
+    assert n - sum(by_kind[k] * s["pattern"].count(k) for k in by_kind) \
+        == 352_324_224
+    # bytes held by the program: two a parameter, and two more for the
+    # leaves it keeps in float32 (the router, A_log, D, dt_bias)
+    wide = sum(
+        int(np.prod(shape)) for i in range(s["L"])
+        for name, shape, _ in weights.layer_leaves(s, i)
+        if name in weights.FLOAT32_IN_PROGRAM)
+    assert (2 * n + 2 * wide) / (2 * n) < 1.001
+
+
+def test_nemotronh_cell_is_its_traffic_and_reads_its_own_metrics():
+    bench = harness.load_benchmark()
+    cells = {c["name"]: c for c in bench["workloads"]}
+    cell = cells[NEMO_CELL]
+    assert (cell["config"], cell["traffic"], cell["chips"]) == (
+        NEMO, "closed64-chat", 1)
+    mix = harness.load_json("traffic", "closed64-chat.json")
+    assert (mix["kind"], mix["callers"], mix["trace_seconds"]) == (
+        "closed_loop", 64, 5)
+    assert mix["prompt_tokens"] == {"dist": "lognormal", "median": 256,
+                                    "sigma": 0.8, "min": 32, "max": 2048}
+    assert mix["new_tokens"] == {"dist": "lognormal", "median": 256,
+                                 "sigma": 0.6, "min": 32, "max": 1024}
+    others = [harness.load_json("traffic", f).get("pairing_seed")
+              for f in os.listdir(os.path.join(BENCH, "traffic"))
+              if f != "closed64-chat.json"]
+    assert mix["pairing_seed"] not in others
+    judged = {m["name"] for m in bench["end_to_end"]
+              if harness.applies(m, NEMO_CELL)}
+    assert judged == {"serve_tokens_per_s", "ttft_mean_ms", "setup_s"}
+    mine = {m["name"] for m in bench["per_layer"]
+            if m.get("workloads") == [NEMO_CELL]}
+    assert mine == {"model.nemotronh_decode_device_ms",
+                    "model.nemotronh_chunk_device_ms", "model.ssd_share",
+                    "ssd_scan_roofline"}
+    shared = {m["name"] for m in bench["per_layer"]
+              if NEMO_CELL in m.get("workloads", ()) and m["name"]
+              not in mine}
+    assert shared == {"model.moe_share", "model.pool_copy_share",
+                      "sched.itl_p95_ms.prefill-heavy",
+                      "sched.itl_p50_ms.prefill-heavy"}
+    from mxnet_tpu.ops import ssm
+    for name in ("model.ssd_share", "ssd_scan_roofline"):
+        spec = harness.load_json("layer_metrics", name + ".json")
+        assert ssm.SSD_KERNEL_NAME in spec["args"]["pattern"]
+    costs = harness.family({"family": "nemotronh"}, "costs")
+    assert callable(costs.ssd_chunk_scan_call)
+    for prog in ("decode", "chunk"):
+        spec = harness.load_json(
+            "layer_metrics", f"model.nemotronh_{prog}_device_ms.json")
+        rx = re.compile(spec["args"]["module"])
+        assert rx.search(f"jit_nemotronh_paged_{prog}(6)")
+        assert not rx.search("jit_nemotronh_paged_fresh(7)")
+    limits = harness.load_json("limits", NEMO_CELL + ".json")
+    assert 0 < limits["limits"]["served_logit_gap"] < 1

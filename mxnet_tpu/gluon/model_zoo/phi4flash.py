@@ -629,6 +629,17 @@ class Phi4FlashModel(HybridBlock):
         x = _mlp(pa, _add(x, out), self._eps)
         return self._cross_decoder(params, x, memory, attend), new
 
+    def _attend_held(self, p, q, k_pool, v_pool, table, n_valid, lam0):
+        """``_attend_rows`` over each slot's held pages of the pool, read
+        in place: the first ``n_valid`` (B,) positions under ``table``
+        (B, P_max) (``ops.attention.paged_rows_attention``, a kernel)."""
+        o = _att.paged_rows_attention(
+            _diff_queries(q[:, None], self._hq, self._hkv)[:, :, 0],
+            k_pool, v_pool, table, n_valid, (self._hkv, self._hkv // 2),
+            1.0 / math.sqrt(self._dh))
+        return _diff_combine(p, o[:, :, None], lam0, self._hq,
+                             self._hkv)[:, 0]
+
     def _decode_body(self, params, tokens, active, cache):
         b = tokens.shape[0]
         ps = _att.pool_page_size(cache["k"])
@@ -687,14 +698,21 @@ class Phi4FlashModel(HybridBlock):
                     t // ps, cache["table"].shape[1] - 1)], 0)
             k_pool = _att.write_rows(cache["k"], page, t % ps, k)
             v_pool = _att.write_rows(cache["v"], page, t % ps, v)
-            # one gather a tick: eight layers read these rows
+
+        if _att._use_pallas():
+            # eight layers read each slot's held pages where they lie
+            def attend(p, q, lam0):
+                return self._attend_held(p, q, k_pool, v_pool,
+                                         cache["table"], t + 1, lam0)
+        else:
+            # one gather a tick of every slot's whole table
             k_rows = _att.gather_rows(k_pool, cache["table"])
             v_rows = _att.gather_rows(v_pool, cache["table"])
             seen = jnp.arange(k_rows.shape[1],
                               dtype=jnp.int32)[None, :] <= t[:, None]
 
-        def attend(p, q, lam0):
-            return self._attend_rows(p, q, k_rows, v_rows, seen, lam0)
+            def attend(p, q, lam0):
+                return self._attend_rows(p, q, k_rows, v_rows, seen, lam0)
 
         with _scope("full_kv"):
             out = attend(pa, q, self._lam0_mid)

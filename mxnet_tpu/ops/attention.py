@@ -724,6 +724,172 @@ def rows_decode_attention(q, k_rows, v_rows, valid, kv_heads, scale=None,
     return jnp.sum(jnp.where(own_v[None, :, :, None], o, 0.0), axis=2)
 
 
+#: the ``name=`` of the held-pages reader's ``pallas_call``: the name of its
+#: HLO instruction, which the device trace shows
+PAGED_ROWS_KERNEL = "paged_rows_attention"
+#: bytes of K (and as many of V) a step of the held-pages reader copies:
+#: as many whole pages as fit, at least one
+_HELD_STEP_BYTES = 1 << 20
+
+
+def _paged_rows_kernel(table_ref, nv_ref, q_ref, k_hbm, v_hbm, o_ref,
+                       k_buf, v_buf, k_sem, v_sem, cur_ref, m_ref, l_ref,
+                       acc_ref, *, scale, pages, page_size, p_max, hv):
+    """One slot a grid step. Its held pages are copied ``pages`` at a
+    time into one of two buffers while the other is contracted; the last
+    block of a slot starts the copy of the next slot's first, so the
+    copies run on across steps (the grid axis is sequential). A page at
+    or past the slot's last held page is never copied: its rows in the
+    buffer are whatever an earlier copy left there, or never written,
+    and are masked like the rows past ``n_valid`` in the last held page."""
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    b, n_slots = pl.program_id(0), pl.num_programs(0)
+    rows = pages * page_size
+
+    def n_valid(slot):
+        return jnp.minimum(nv_ref[slot], p_max * page_size)
+
+    def each_page(slot, blk, buf, act):
+        held = (n_valid(slot) + page_size - 1) // page_size
+        for j in range(pages):
+            at = blk * pages + j
+            page = table_ref[slot * p_max + jnp.minimum(at, p_max - 1)]
+            dst = pl.ds(j * page_size, page_size)
+
+            @pl.when(at < held)
+            def _(page=page, dst=dst):
+                act(pltpu.make_async_copy(k_hbm.at[page], k_buf.at[buf, dst],
+                                          k_sem.at[buf]))
+                act(pltpu.make_async_copy(v_hbm.at[page], v_buf.at[buf, dst],
+                                          v_sem.at[buf]))
+
+    def start(slot, blk, buf):
+        each_page(slot, blk, buf, lambda c: c.start())
+
+    @pl.when(b == 0)
+    def _first():
+        cur_ref[0] = 0
+        start(0, 0, 0)
+
+    n = n_valid(b)
+    n_blocks = (n + rows - 1) // rows
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    q = q_ref[0]
+
+    def block(i, carry):
+        buf = cur_ref[0]
+        nxt = 1 - buf
+        pl.when(i + 1 < n_blocks)(lambda: start(b, i + 1, nxt))
+        pl.when((i + 1 == n_blocks) & (b + 1 < n_slots))(
+            lambda: start(b + 1, 0, nxt))
+        each_page(b, i, buf, lambda c: c.wait())
+        s = jax.lax.dot_general(q, k_buf[buf], (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
+        col = lax.broadcasted_iota(jnp.int32, s.shape, 1) + i * rows
+        s = jnp.where(col < n, s, NEG_INF)
+        # V past n_valid may be stale or NaN: p is 0 there, 0 * NaN is not
+        row = lax.broadcasted_iota(jnp.int32, (rows, 1), 0) + i * rows
+        v = v_buf[buf]
+        v = jnp.where(row < n, v, jnp.zeros_like(v))
+        # m/l scratch is (Hq, 128) with all lanes equal
+        m_prev = m_ref[...]
+        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new[:, :1])
+        m_ref[...] = m_new
+        l_ref[...] = alpha * l_ref[...] + p.sum(axis=-1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * alpha[:, :1] + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        cur_ref[0] = nxt
+        return carry
+
+    lax.fori_loop(0, n_blocks, block, 0)
+    # a slot that holds nothing hands the free buffer to the next slot
+    pl.when((n_blocks == 0) & (b + 1 < n_slots))(
+        lambda: start(b + 1, 0, cur_ref[0]))
+
+    l = l_ref[...][:, :1]
+    inv = 1.0 / jnp.where(l > 0, l, 1.0)           # n_valid 0: zeros
+    hq, dv = acc_ref.shape[0], o_ref.shape[-1]
+    own = lax.broadcasted_iota(jnp.int32, (hq, 1), 0) // (hq // hv)
+    o = jnp.zeros((hq, dv), jnp.float32)
+    for j in range(hv):
+        o = o + jnp.where(own == j, acc_ref[:, j * dv:(j + 1) * dv], 0.0)
+    o_ref[0] = o * inv
+
+
+def paged_rows_attention(q, k_pool, v_pool, table, n_valid, kv_heads,
+                         scale=None, interpret=False):
+    """``rows_decode_attention`` over each slot's HELD pages of a paged
+    pool, read in place: ``q`` (B, Hq, D); ``k_pool`` (n_pages,
+    page_size, Hk * D) and ``v_pool`` (n_pages, page_size, Hv * Dv) as the
+    pool lies; ``table`` (B, P_max) int32; ``n_valid`` (B,) int32, each
+    slot's valid prefix; ``kv_heads`` ``(Hk, Hv)``. Returns (B, Hq, Dv)
+    float32, what ``rows_decode_attention(q, gather_rows(k_pool, table),
+    gather_rows(v_pool, table), col < n_valid, kv_heads, scale)`` returns,
+    to rounding: the softmax is an online one over blocks of pages, and
+    each block's probabilities are rounded to V's dtype before P x V.
+
+    A Pallas kernel (``PAGED_ROWS_KERNEL``) with ``table`` and ``n_valid``
+    scalar-prefetched: a slot's pages up to its last held one are copied
+    from the pool, several a step (``_HELD_STEP_BYTES``), and no view is
+    gathered; the pages past them are never read. The query is laid out
+    block-diagonally over whole rows as in ``rows_decode_attention``, in
+    the pool's dtype.
+    Rows past ``n_valid`` in the last held page may hold a former
+    tenant's values, NaN too: their scores are masked and their V rows
+    zeroed. A slot with ``n_valid`` 0 returns zeros."""
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    b, hq, d = q.shape
+    hk, hv = kv_heads
+    _, ps, wk = k_pool.shape
+    wv = v_pool.shape[2]
+    p_max = table.shape[1]
+    scale = scale if scale is not None else 1.0 / math.sqrt(d)
+    pages = min(max(1, _HELD_STEP_BYTES // (ps * wk * k_pool.dtype.itemsize)),
+                p_max)
+    telemetry.counter("ops.attention.paged_decode.held")
+    own_k = jnp.arange(hq)[:, None] // (hq // hk) == jnp.arange(hk)[None]
+    q_rows = jnp.where(own_k[None, :, :, None], q[:, :, None, :], 0) \
+        .astype(k_pool.dtype).reshape(b, hq, wk)
+    rows = pages * ps
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(b,),
+        in_specs=[pl.BlockSpec((1, hq, wk), lambda i, *_: (i, 0, 0)),
+                  pl.BlockSpec(memory_space=pl.ANY),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((1, hq, wv // hv), lambda i, *_: (i, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((2, rows, wk), k_pool.dtype),
+            pltpu.VMEM((2, rows, wv), v_pool.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SMEM((1,), jnp.int32),          # the buffer to contract
+            pltpu.VMEM((hq, 128), jnp.float32),   # running max
+            pltpu.VMEM((hq, 128), jnp.float32),   # running denominator
+            pltpu.VMEM((hq, wv), jnp.float32),    # running numerator
+        ],
+    )
+    kernel = functools.partial(_paged_rows_kernel, scale=scale, pages=pages,
+                               page_size=ps, p_max=p_max, hv=hv)
+    return pl.pallas_call(
+        kernel,
+        name=PAGED_ROWS_KERNEL,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, hq, wv // hv), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=pltpu.InterpretParams() if interpret else False,
+    )(jnp.asarray(table, jnp.int32).reshape(-1),
+      jnp.asarray(n_valid, jnp.int32), q_rows, k_pool, v_pool)
+
+
 def paged_decode_attention(q, k_pool, v_pool, table, lengths,
                            scale=None, k_scale=None, v_scale=None):
     """Decode attention against a PAGED KV cache.
@@ -767,8 +933,10 @@ def paged_decode_attention(q, k_pool, v_pool, table, lengths,
     which the TPU keeps pages minor-most, and a kernel's operand has to
     be row-major: a copy of each whole pool a layer cost more than the
     kernel saved (PERF.md §6). This pool is row-major already, so that
-    reason is gone; a kernel over held pages that contracts rows as
-    they lie is an issue of its own (ROADMAP S4).
+    reason is gone: ``paged_rows_attention`` is such a kernel, over held
+    pages and contracting rows as they lie. Its one caller is
+    ``Phi4FlashModel``'s decode tick on a TPU; this function, and so
+    ``GPTModel``'s tick, keeps the gather (ROADMAP S4).
 
     ``k_scale``/``v_scale`` (n_pages, H) fp32 mark an INT8 pool (half
     the HBM per cached token vs bf16, a quarter vs fp32): the rows

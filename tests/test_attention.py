@@ -418,6 +418,97 @@ def test_paged_decode_attention_under_jit_follows_lengths():
             rtol=2e-4, atol=2e-5)
 
 
+# the held-pages reader (``paged_rows_attention``): every length its
+# blocks treat apart, page 64 — none, one, a page less one, a page, a page
+# and one, a few hundred, the whole table (eight pages)
+_HELD_LENGTHS = [0, 1, 63, 64, 65, 300, 512]
+#: (Hq, (Hk, Hv), D): phi4flash's differential layers (40 query heads over
+#: K 20 x 64 and V read as 10 heads x 128) and plain heads
+_HELD_HEADS = {"differential": (40, (20, 10), 64),
+               "plain": (20, (20, 20), 64)}
+
+
+def _held_case(heads, dtype, stale, seed=9, ps=64, p_max=8):
+    """A pool holding ``_HELD_LENGTHS``, every slot's pages scattered over
+    it and its table's free entries on the scrap page 0. The scrap page
+    and the rows past each slot's length in its last held page hold
+    ``stale`` (a former tenant's values, or NaN). Returns the case and
+    the pools with those rows zeroed, for the reference, whose V has to
+    be finite everywhere."""
+    hq, (hk, hv), d = _HELD_HEADS[heads]
+    rng = onp.random.RandomState(seed)
+    lengths = onp.asarray(_HELD_LENGTHS, "i4")
+    b = len(lengths)
+    n_pages = 1 + b * p_max
+    mk = lambda *sh: (rng.randn(*sh) * 0.5).astype("f4")  # noqa: E731
+    kpool, vpool = mk(n_pages, ps, hk * d), mk(n_pages, ps, hk * d)
+    free = list(rng.permutation(onp.arange(1, n_pages)))
+    table = onp.zeros((b, p_max), "i4")
+    for i, n in enumerate(lengths):
+        held = -(-int(n) // ps)
+        table[i, :held] = [free.pop() for _ in range(held)]
+    # the scrap page whole, and the rows past each length in its last page
+    spots = [(0, 0)] + [(table[i, n // ps], n % ps)
+                        for i, n in enumerate(lengths) if n % ps]
+    clean = kpool.copy(), vpool.copy()
+    for pool, ref in zip((kpool, vpool), clean):
+        for page, row in spots:
+            pool[page, row:] = onp.nan if stale == "nan" \
+                else mk(ps - row, hk * d) * 50
+            ref[page, row:] = 0.0
+    cast = lambda x: jnp.asarray(x).astype(dtype)  # noqa: E731
+    return ((cast(mk(b, hq, d)), cast(kpool), cast(vpool),
+             jnp.asarray(table), jnp.asarray(lengths), (hk, hv)),
+            (cast(clean[0]), cast(clean[1])))
+
+
+@pytest.mark.parametrize("stale", ["stale", "nan"])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("heads", sorted(_HELD_HEADS))
+def test_held_pages_reader_matches_the_rows_reader(heads, dtype, stale):
+    """``paged_rows_attention`` (interpret mode) says what
+    ``rows_decode_attention`` over ``gather_rows`` says, for every length
+    in ``_HELD_LENGTHS`` (several pages a step, so blocks of one, several
+    and a part of one), with the scrap page and a former tenant's rows
+    (or NaN) past each length in its last held page: the kernel reads
+    none of them. An empty slot returns zeros."""
+    from mxnet_tpu import telemetry
+    (q, kpool, vpool, table, lengths, kv), (kref, vref) = _held_case(
+        heads, dtype, stale)
+    before = telemetry.counter_value("ops.attention.paged_decode.held")
+    out = at.paged_rows_attention(q, kpool, vpool, table, lengths, kv,
+                                  interpret=True)
+    assert telemetry.counter_value("ops.attention.paged_decode.held") \
+        == before + 1
+    kr, vr = at.gather_rows(kref, table), at.gather_rows(vref, table)
+    valid = jnp.arange(kr.shape[1])[None] < lengths[:, None]
+    want = at.rows_decode_attention(q, kr, vr, valid, kv)
+    assert out.dtype == want.dtype == jnp.float32
+    assert out.shape == want.shape == (q.shape[0], q.shape[1],
+                                       vpool.shape[2] // kv[1])
+    out = onp.asarray(out)
+    assert (out[0] == 0).all() and onp.isfinite(out).all()
+    # bfloat16: P is rounded before P x V at another scale (online softmax)
+    tol = dict(rtol=2e-5, atol=2e-6) if dtype == "float32" \
+        else dict(rtol=2e-2, atol=4e-3)
+    onp.testing.assert_allclose(out, onp.asarray(want), **tol)
+
+
+def test_held_pages_reader_under_jit_follows_lengths():
+    """One compiled program serves every set of lengths, the pages held
+    read again from the table and lengths of each call."""
+    (q, kpool, vpool, table, lengths, kv), (kref, vref) = _held_case(
+        "differential", "float32", "nan")
+    fn = jax.jit(lambda *a: at.paged_rows_attention(*a, kv, interpret=True))
+    kr, vr = at.gather_rows(kref, table), at.gather_rows(vref, table)
+    for lens in (lengths, jnp.minimum(lengths, 65)):
+        valid = jnp.arange(kr.shape[1])[None] < lens[:, None]
+        onp.testing.assert_allclose(
+            onp.asarray(fn(q, kpool, vpool, table, lens)),
+            onp.asarray(at.rows_decode_attention(q, kr, vr, valid, kv)),
+            rtol=2e-5, atol=2e-6)
+
+
 def test_chunked_prefill_attention_matches_reference():
     """A chunk's queries at global positions [start, start+C) against a
     cache buffer == the matching rows of full causal mha_reference over

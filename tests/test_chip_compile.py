@@ -387,6 +387,59 @@ def test_rows_decode_with_grouped_heads(one_chip, no_compile_cache,
     assert f"[{slots},{GS},{GKV},{D}]" not in text
 
 
+_PHI4FLASH_TICK = {}
+
+
+def _phi4flash_tick(sharding):
+    """The decode program of ``Phi4FlashModel`` at the published widths
+    and the cell's 32 slots (page 64, 5120 positions), compiled for the
+    described v5e as the chip runs it (the held-pages reader): the
+    short names of its operations (``trace_reduce.short``, as the device
+    trace shows them). Compiled once a process."""
+    if not _PHI4FLASH_TICK:
+        import json
+        import os
+        from chipbench import trace_reduce
+        from mxnet_tpu.gluon.model_zoo.phi4flash import Phi4FlashModel
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        with open(os.path.join(root, "chipbench", "configs",
+                               "phi4-mini-flash-reasoning.json")) as f:
+            cfg = json.load(f)
+        model, serve = cfg["model"], cfg["serve"]
+        net = Phi4FlashModel(**{k: model[k] for k in (
+            "vocab_size", "hidden_size", "num_hidden_layers",
+            "num_attention_heads", "num_key_value_heads",
+            "intermediate_size", "sliding_window", "mb_per_layer",
+            "layer_norm_eps", "d_state", "d_conv", "expand", "dt_rank",
+            "prefill_chunk")}, max_length=serve["max_length"])
+
+        def on_chip(tree):
+            return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+                x.shape, x.dtype, sharding=sharding), tree)
+
+        slots, ps = serve["max_slots"], serve["page_size"]
+        params = {("top" if g is None else g): {
+            k: jax.ShapeDtypeStruct(p.shape, jnp.dtype(p.dtype),
+                                    sharding=sharding)
+            for k, p in ps_.items()} for g, ps_ in net._params.items()}
+        cache = on_chip(jax.eval_shape(lambda: net.init_paged_cache(
+            slots, slots * serve["max_length"] // ps + 1, ps,
+            serve["max_length"])))
+        rows = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=sharding)
+        # the program asks which backend runs it and would take the CPU's
+        # ``jnp`` reader: the compile is steered to the chip's kernel. The
+        # program is the engine's, which takes the cache donated
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setattr(att, "_use_pallas", lambda: True)
+            text = net._ensure_programs()["decode"].lower(
+                params, rows, rows, cache).compile().as_text()
+        _PHI4FLASH_TICK["names"] = [
+            trace_reduce.short(line.strip().removeprefix("ROOT "))[0]
+            for line in text.splitlines() if " = " in line]
+        _PHI4FLASH_TICK["slots"] = slots
+    return _PHI4FLASH_TICK["names"]
+
+
 def test_phi4flash_tick_has_the_operations_ssm_share_reads(
         one_chip, no_compile_cache):
     """``model.ssm_share`` finds the tick's state update by the SHAPE of
@@ -398,44 +451,46 @@ def test_phi4flash_tick_has_the_operations_ssm_share_reads(
     this fails before the metric falls silent."""
     import json
     import os
-    from chipbench import trace_reduce
-    from mxnet_tpu.gluon.model_zoo.phi4flash import Phi4FlashModel
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(root, "chipbench", "configs",
-                           "phi4-mini-flash-reasoning.json")) as f:
-        cfg = json.load(f)
     with open(os.path.join(root, "chipbench", "layer_metrics",
                            "model.ssm_share.json")) as f:
         pattern = re.compile(json.load(f)["args"]["pattern"])
-    model, serve = cfg["model"], cfg["serve"]
-    net = Phi4FlashModel(**{k: model[k] for k in (
-        "vocab_size", "hidden_size", "num_hidden_layers",
-        "num_attention_heads", "num_key_value_heads", "intermediate_size",
-        "sliding_window", "mb_per_layer", "layer_norm_eps", "d_state",
-        "d_conv", "expand", "dt_rank", "prefill_chunk")},
-        max_length=serve["max_length"])
-
-    def on_chip(tree):
-        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
-            x.shape, x.dtype, sharding=one_chip), tree)
-
-    slots, ps = serve["max_slots"], serve["page_size"]
-    params = {("top" if g is None else g): {
-        k: jax.ShapeDtypeStruct(p.shape, jnp.dtype(p.dtype),
-                                sharding=one_chip) for k, p in ps_.items()}
-        for g, ps_ in net._params.items()}
-    cache = on_chip(jax.eval_shape(lambda: net.init_paged_cache(
-        slots, slots * serve["max_length"] // ps + 1, ps,
-        serve["max_length"])))
-    rows = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one_chip)
-    text = jax.jit(net._decode_body).lower(
-        params, rows, rows, cache).compile().as_text()
-    names = [trace_reduce.short(line.strip().removeprefix("ROOT "))[0]
-             for line in text.splitlines() if " = " in line]
+    names = _phi4flash_tick(one_chip)
     # a fusion is an operation the device runs and the trace times
     hits = [n for n in names
             if n.startswith("fusion ") and pattern.search(n)]
     assert hits, sorted({n for n in names if "5120]" in n})[:20]
+
+
+def test_phi4flash_tick_reads_held_pages_in_place(one_chip,
+                                                  no_compile_cache):
+    """The tick's eight reads of the one pool (layer 17's and the seven
+    cross-attention layers') are the held-pages kernel under the name
+    ``model.pool_attend_share`` reads: two calls in the program (layer
+    17's, and the cross-decoder's loop body). The program holds no
+    gather of every slot's whole table (``bf16[2560,64,1280]``, the view
+    ``bf16[32,5120,1280]``), which was 1.88 ms of a 24.9 ms tick on the
+    chip (PERF.md section 5), and no copy of a pool's pages. Metadata of
+    a compile, not a chip reading."""
+    import json
+    import os
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "chipbench", "layer_metrics",
+                           "model.pool_attend_share.json")) as f:
+        pattern = re.compile(json.load(f)["args"]["pattern"])
+    names = _phi4flash_tick(one_chip)
+    reads = [n for n in names if pattern.search(n)]
+    assert len(reads) == 2, sorted(n for n in names
+                                   if n.startswith("custom-call "))
+    assert all(n.startswith(f"custom-call {att.PAGED_ROWS_KERNEL} ")
+               for n in reads), reads
+    slots = _PHI4FLASH_TICK["slots"]
+    # the gather's pages, and the view they make
+    assert not [n for n in names
+                if f"[{slots * GS // GPAGE},{GPAGE},{GKV * D}]" in n
+                or f"[{slots},{GS},{GKV * D}]" in n]
+    assert not [n for n in names if n.startswith("copy ")
+                and re.search(rf"bf16\[\d+,{GPAGE},{GKV * D}\]", n)]
 
 
 # -- the xing4 family at its published widths: the decode program of the

@@ -288,7 +288,8 @@ def test_phi4flash_cell_reads_its_own_programs_and_kernel():
     cell = PHI + ".serve.longgen"
     mine = {m["name"] for m in bench["per_layer"]
             if m.get("workloads") == [cell]}
-    assert mine == {"model.phi4flash_decode_device_ms", "model.ssm_share"}
+    assert mine == {"model.phi4flash_decode_device_ms", "model.ssm_share",
+                    "model.pool_attend_share"}
     # the chunk programs' device time and the scan kernel's roofline are
     # not metrics of the cell: its traced 5 s hold ticks alone (PERF.md
     # section 7 has the two files a benchmark PR would add); the
@@ -298,6 +299,12 @@ def test_phi4flash_cell_reads_its_own_programs_and_kernel():
     from mxnet_tpu.ops import ssm
     share = harness.load_json("layer_metrics", "model.ssm_share.json")
     assert ssm.KERNEL_NAME in share["args"]["pattern"]
+    # the tick's reads of the pool: the held-pages kernel, by its name
+    from mxnet_tpu.ops import attention
+    attend = harness.load_json("layer_metrics",
+                               "model.pool_attend_share.json")
+    assert attend["args"]["pattern"] \
+        == "^custom-call " + attention.PAGED_ROWS_KERNEL
     tick = harness.load_json(
         "layer_metrics", "model.phi4flash_decode_device_ms.json")
     rx = re.compile(tick["args"]["module"])

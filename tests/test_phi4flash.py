@@ -12,6 +12,7 @@ compared with the plain float32 reference of
 ``chipbench/families/phi4flash/`` on the benchmark's seeded weights:
 logits, not tokens. CPU, about a minute together.
 """
+import functools
 import os
 import sys
 
@@ -287,6 +288,100 @@ def test_a_reused_slot_reads_nothing_of_the_last_tenant(net):
     # one request at a time: each takes the first free slot, slot 0
     ((_, alone),), _ = serve(net, [(b, 6)])
     (_, (_, after)), _ = serve(net, [(a, 12), (b, 6)])
+    np.testing.assert_array_equal(after, alone)
+
+
+# -- the tick as the chip runs it: eight layers read held pages in place ------
+def _prefill(net, toks, slot, cache):
+    """``toks`` into ``slot`` in chunks, as the engine hands them over."""
+    for start in range(0, len(toks), CHUNK):
+        part = toks[start:start + CHUNK]
+        row = np.zeros((1, CHUNK), np.int32)
+        row[0, :len(part)] = part
+        _, cache = net.prefill_paged(row, len(part), slot, _pages(slot),
+                                     cache, start=start,
+                                     last=start + CHUNK >= len(toks))
+    return cache
+
+
+def _tick(net, tokens, active, cache, held=False):
+    """The decode program's body, traced anew: the ``jnp`` reader, or with
+    ``held`` the chip's held-pages kernel, in interpret mode."""
+    from mxnet_tpu.ops import attention as att
+    with pytest.MonkeyPatch.context() as mp:
+        if held:
+            mp.setattr(att, "_use_pallas", lambda: True)
+            mp.setattr(att, "paged_rows_attention", functools.partial(
+                att.paged_rows_attention, interpret=True))
+        return jax.jit(lambda *a: net._decode_body(*a))(
+            net._datas(), jnp.asarray(tokens, jnp.int32),
+            jnp.asarray(active, jnp.int32), cache)
+
+
+def test_the_held_pages_tick_follows_the_jnp_tick(net32):
+    """Slot 0 held request A (40 positions and three ticks), then B (13)
+    in the same pages; slot 1 holds a request and rides along inactive.
+    The tick through ``paged_rows_attention`` (interpret mode) gives the
+    ``jnp`` tick's logits, every row, to float32 rounding, and the same
+    cache, though A's rows in the pages past B's last held page and the
+    rows past B's position in that page are NaN for it: it reads no
+    position past ``len + 1``."""
+    rng = np.random.default_rng(5)
+    a = rng.integers(0, 512, 40).astype(np.int32)
+    b = rng.integers(0, 512, 13).astype(np.int32)
+    cache = _prefill(net32, a, 0, _cache(net32))
+    for tok in (3, 4, 5):
+        _, cache = net32.decode_step_paged(np.array([tok, 0], np.int32),
+                                           np.array([1, 0], np.int32), cache)
+    cache = _prefill(net32, b, 0, cache)
+    cache = _prefill(net32, _prompt(6), 1, cache)
+    assert [int(x) for x in cache["len"]] == [13, 6]
+    tokens, active = [7, 9], [1, 0]
+    want, want_cache = _tick(net32, tokens, active, cache)
+    # B's tick reads positions 0-13: pages 0-3 of its row, of page 3 rows
+    # 0-1; A's 16-42 stay in pages 4-10
+    pages = _pages(0)
+    poisoned = dict(cache)
+    for key in ("k", "v"):
+        pool = cache[key].at[pages[3], 2:].set(jnp.nan)
+        poisoned[key] = pool.at[pages[4:]].set(jnp.nan)
+    got, got_cache = _tick(net32, tokens, active, poisoned, held=True)
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+    for key in want_cache:
+        if key in ("k", "v"):
+            continue
+        np.testing.assert_allclose(got_cache[key], want_cache[key],
+                                   rtol=1e-5, atol=1e-5, err_msg=key)
+    # the writes are the same writes: the poisoned rows aside, equal
+    keep = np.ones(want_cache["k"].shape[:2], bool)
+    keep[pages[3], 2:] = False
+    keep[pages[4:]] = False
+    for key in ("k", "v"):
+        np.testing.assert_array_equal(np.asarray(got_cache[key])[keep],
+                                      np.asarray(want_cache[key])[keep])
+
+
+def test_a_reused_slot_reads_nothing_of_the_last_tenant_held(monkeypatch):
+    """``test_a_reused_slot_reads_nothing_of_the_last_tenant`` with the
+    programs as the chip runs them (the tick's held-pages reader and the
+    chunks' scan kernel, in interpret mode), on a model of its own so
+    that its programs are traced with the kernels: B after A in the same
+    slot reads B alone's logits."""
+    from mxnet_tpu.ops import attention as att
+    monkeypatch.setattr(att, "_use_pallas", lambda: True)
+    monkeypatch.setattr(att, "paged_rows_attention", functools.partial(
+        att.paged_rows_attention, interpret=True))
+    monkeypatch.setattr(ssm, "selective_scan_pallas", functools.partial(
+        ssm.selective_scan_pallas, interpret=True))
+    rng = np.random.default_rng(5)
+    a = rng.integers(0, 512, 40).astype(np.int32)
+    b = rng.integers(0, 512, 13).astype(np.int32)
+    held = build()
+    before = telemetry.counter_value("ops.attention.paged_decode.held")
+    ((_, alone),), _ = serve(held, [(b, 6)])
+    assert telemetry.counter_value("ops.attention.paged_decode.held") \
+        > before
+    (_, (_, after)), _ = serve(held, [(a, 12), (b, 6)])
     np.testing.assert_array_equal(after, alone)
 
 

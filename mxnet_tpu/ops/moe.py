@@ -263,3 +263,20 @@ def expert_layer(z, w_gate, w_up, w_down, ids, gates, lo, tm, up_t=False):
     # multiply (0 x NaN)
     rows = jnp.where(held[..., None], rows.astype(jnp.float32), 0.0)
     return jnp.sum(rows * gates[..., None], axis=1), n_hit
+
+
+def route_softmax_topk(z, w_router, top_k, norm_topk_prob=True):
+    """Softmax routing (Qwen3-MoE's, which ``sdar_moe`` continues):
+    probabilities ``softmax(z W_r)`` over ALL experts in float32, the
+    ``top_k`` most probable kept, their probabilities renormalised to sum
+    to 1 with ``norm_topk_prob`` (else left as they are). ``z`` (T, D)
+    float32, the normed input before it is cast to the model's dtype;
+    ``w_router`` (D, E_all) float32. Returns ``ids`` (T, k) int32 and
+    ``gates`` (T, k) float32, as ``route_sigmoid_topk``."""
+    logits = jnp.dot(z.astype(jnp.float32), w_router.astype(jnp.float32),
+                     precision=lax.Precision.HIGHEST)
+    probs = jax.nn.softmax(logits, axis=-1)
+    gates, ids = lax.top_k(probs, top_k)
+    if norm_topk_prob:
+        gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
+    return ids.astype(jnp.int32), gates

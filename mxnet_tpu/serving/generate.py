@@ -338,7 +338,7 @@ class _PagedSlot:
     __slots__ = ("stream", "last", "left", "eos_id", "deadline", "n_ctx",
                  "state", "chunks", "row", "page_refs", "cow_pending",
                  "prompt", "seq", "t_submit", "draft_prompt", "key",
-                 "adapter_idx")
+                 "adapter_idx", "blk")
 
     def __init__(self, stream, left, eos_id, deadline, n_ctx, row,
                  page_refs, prompt, seq, t_submit):
@@ -361,6 +361,34 @@ class _PagedSlot:
         self.prompt = prompt   # kept until registered in the index
         self.seq = seq         # admission order (oldest prefills first)
         self.t_submit = t_submit
+        self.blk = None        # block-diffusion engines: the _Block
+
+
+def block_schedule(masked, steps):
+    """How many positions each denoising step of a block unmasks: LLaDA's
+    ``get_num_transfer_tokens``, ``masked`` positions split over
+    ``steps``, the remainder to the first steps. A step that would unmask
+    nothing is not run: the block is done when no mask is left."""
+    base, extra = divmod(int(masked), int(steps))
+    return [k for k in (base + (i < extra) for i in range(int(steps))) if k]
+
+
+class _Block:
+    """The host's mirror of a slot's block in a block-diffusion engine
+    (docs/SERVING.md "Block-diffusion steps"): the block's first position
+    ``start``, the prompt tokens it opens with (``tail``), the steps it
+    takes (``steps``, ``block_schedule``) and how many are dispatched
+    (``step``), the request's end ``end`` (budget or capacity), whether
+    the block is still to be handed to the device (``fresh``) and whether
+    the request's last step is dispatched (``last``). It follows what is
+    DISPATCHED: the device holds the block itself."""
+
+    __slots__ = ("start", "tail", "steps", "step", "end", "fresh", "last")
+
+    def __init__(self, start, tail, steps, end):
+        self.start, self.tail, self.steps, self.end = start, tail, steps, end
+        self.step = 0
+        self.fresh, self.last = True, False
 
 
 class _Tick:
@@ -692,6 +720,15 @@ class GenerationEngine:
             "compute_dtype": ("compute_dtype", True,
                               compute_dtype or "float32"),
         })
+        #: a model that generates by diffusion over blocks states it
+        #: (``block_diffusion``); the engine then runs the block-diffusion
+        #: step path, chosen here once
+        bd = (support or {}).get("block_diffusion")
+        self._blockdiff = dict(bd) if bd else None
+        if self._blockdiff is not None and not paged:
+            raise ValueError("a block-diffusion model is served paged")
+        self._finish_ahead = self._finish_tick if self._blockdiff is None \
+            else self._finish_denoise
         if speculative is None:
             speculative = draft_model is not None
         self.speculative = bool(speculative)
@@ -806,6 +843,8 @@ class GenerationEngine:
             self.max_adapters = 0
         api = ("init_paged_cache", "prefill_paged", "decode_step_paged") \
             if self.paged else ("init_cache", "prefill", "decode_step")
+        if self._blockdiff is not None:
+            api = ("init_paged_cache", "prefill_paged", "denoise_step_paged")
         if self.paged and prefix_cache:
             # a prefix hit peeks, binds a table row and copies on write
             api += ("peek_logits_paged", "bind_slot_paged",
@@ -1650,6 +1689,14 @@ class GenerationEngine:
                     onp.zeros((1, w), "i4"), w, 0, row, cache, start=0,
                     **kw)
                 cache = self._recommit(cache)
+        if self._blockdiff is not None:
+            b, blk = self.max_slots, self._blockdiff["block_length"]
+            self.model.denoise_step_paged(
+                onp.ones((b,), "i4"), onp.ones((b,), "i4"),
+                onp.zeros((b, blk), "i4"), onp.zeros((b, blk), "?"),
+                onp.zeros((b,), "i4"), onp.ones((b, self._p_max), "i4"),
+                cache)
+            return
         lg, cache = self.model.decode_step_paged(
             self._tick_tokens(onp.zeros((self.max_slots,), "i4")),
             onp.ones((self.max_slots,), "i4"), cache)
@@ -1872,6 +1919,10 @@ class GenerationEngine:
                                               eos_id)
         temp, tk, tp, seed = self._validate_sampling(
             temperature, top_k, top_p, seed)
+        if temp > 0 and self._blockdiff is not None:
+            raise self._submit_error(
+                "temperature", temperature, "a block-diffusion step "
+                "unmasks each block greedily")
         if adapter is not None and not self.lora_enabled:
             raise self._submit_error(
                 "adapter", adapter, "this engine has no LoRA bank "
@@ -2248,24 +2299,32 @@ class GenerationEngine:
             return True
         row[first_write:cap_pages] = private
         start0 = first_write * ps
-        fresh = (start0 == 0
-                 and self.policy.bucket(length) <= self._chunk)
+        # a block-diffusion prompt prefills its whole blocks only: the
+        # rest opens its first block
+        n_pre = length if self._blockdiff is None \
+            else length - length % self._blockdiff["block_length"]
+        fresh = (n_pre > 0 and start0 == 0
+                 and self.policy.bucket(n_pre) <= self._chunk)
         if fresh:
-            w = self.policy.bucket(length)
+            w = self.policy.bucket(n_pre)
             toks = onp.zeros((1, w), "i4")
-            toks[0, :length] = r.prompt
-            s.chunks.append((toks, 0, length, True))
+            toks[0, :n_pre] = r.prompt[:n_pre]
+            s.chunks.append((toks, 0, n_pre, True))
         else:
             pos = start0
-            while pos < length:
+            while pos < n_pre:
                 w = min(self._chunk, self._s_max - pos)
-                nv = min(w, length - pos)
+                nv = min(w, n_pre - pos)
                 toks = onp.zeros((1, w), "i4")
                 toks[0, :nv] = r.prompt[pos:pos + nv]
                 s.chunks.append((toks, pos, nv, False))
                 pos += nv
         self._slots[slot] = s
         self._n_active += 1
+        if not s.chunks:
+            # a block-diffusion prompt shorter than a block
+            telemetry.counter("serving.generate.prefills")
+            self._enter_denoise(s)
         return True
 
     def _register_prefix(self, s: _PagedSlot):
@@ -2376,6 +2435,9 @@ class GenerationEngine:
         self._chunks_this_iter += 1
         if not s.chunks:
             telemetry.counter("serving.generate.prefills")
+            if self._blockdiff is not None:
+                self._enter_denoise(s)
+                return 1
             self._register_prefix(s)
             with tracing.phase("serve.prefill.sync"):
                 self._first_token(best, s, onp.asarray(logits))
@@ -2671,7 +2733,136 @@ class GenerationEngine:
         engine is where the synchronous loop is between two ticks."""
         tick, self._ahead = self._ahead, None
         if tick is not None:
-            self._finish_tick(tick)
+            self._finish_ahead(tick)
+
+    # -- block-diffusion steps (docs/SERVING.md "Block-diffusion steps") --
+    def _enter_denoise(self, s: _PagedSlot):
+        """A block-diffusion slot whose whole prompt blocks are cached
+        opens its first block: the prompt's last ``p mod B`` tokens, the
+        mask at the block's other positions. Its first denoising step
+        hands the block to the device (``_Block.fresh``)."""
+        bd = self._blockdiff
+        blk, p = bd["block_length"], int(s.prompt.size)
+        tail = p % blk
+        s.blk = _Block(p - tail, tail,
+                       block_schedule(blk - tail, bd["denoise_steps"]),
+                       min(p + s.left, self._s_cap))
+        s.state = "decode"
+
+    def _denoise_tick(self):
+        """One denoising step of every decoding slot's block in ONE
+        fixed-shape program (the model's ``denoise_step_paged``); a slot
+        whose last step this was yields its block at the tick's commit,
+        and the block's K/V are committed in the slot's next step, on the
+        device. The blocks live on the device: the host follows each
+        slot's static schedule (``_Block``), so it knows which slots a
+        step completes and reads tokens only then.
+
+        Dispatched ahead where ``_ahead_ok`` holds, as the plain tick is
+        (docs/SERVING.md "The tick in flight"): a step needs nothing of
+        the step before it on the host, so this one is queued before the
+        one in flight is synced and committed. A slot whose last block is
+        dispatched is served no further step; an end the host cannot
+        predict (eos, a deadline) finds its slot in the next step, whose
+        output for it ``_finish_denoise`` drops."""
+        ahead = self._ahead_ok()
+        if not ahead:
+            self._drain_ahead()
+        prev, self._ahead = self._ahead, None
+        b, blk = self.max_slots, self._blockdiff["block_length"]
+        full = block_schedule(blk, self._blockdiff["denoise_steps"])
+        take = onp.zeros((b,), "i4")
+        fresh = onp.zeros((b,), "i4")
+        f_blk = onp.zeros((b, blk), "i4")
+        f_fill = onp.zeros((b, blk), "?")
+        f_start = onp.zeros((b,), "i4")
+        f_table = onp.zeros((b, self._p_max), "i4")
+        rows = []
+        any_trace = False
+        for i in self._decode_idxs():
+            s = self._slots[i]
+            g = s.blk
+            if g.last:
+                continue
+            take[i] = g.steps[g.step]
+            if g.fresh:
+                fresh[i], g.fresh = 1, False
+                f_blk[i, :g.tail] = s.prompt[g.start:g.start + g.tail]
+                f_fill[i, :g.tail] = True
+                f_start[i] = g.start
+                f_table[i] = s.row
+            g.step += 1
+            # ``(slot, request, where its new tokens start in the block;
+            # -1 where the block is not done at this step)``
+            if g.step == len(g.steps):
+                rows.append((i, s, g.tail))
+                g.last = g.start + blk >= g.end
+                g.start, g.tail, g.step, g.steps = g.start + blk, 0, 0, full
+            else:
+                rows.append((i, s, -1))
+            if s.stream._trace is not None:
+                any_trace = True
+        tick = None
+        if rows:
+            with tracing.phase("serve.decode.dispatch"):
+                tt0 = time.perf_counter() if any_trace else 0.0
+                t0 = telemetry.clock()
+                with tracing.phase("serve.call.denoise"):
+                    out, self._cache = self.model.denoise_step_paged(
+                        take, fresh, f_blk, f_fill, f_start, f_table,
+                        self._cache)
+                telemetry.counter("serving.generate.dispatches")
+                telemetry.counter("serving.generate.denoise_steps",
+                                  len(rows))
+                tick = _Tick(rows, t0, tt0)
+                tick.pick = out
+                if ahead:
+                    self._ahead = tick
+                    if prev is not None:
+                        telemetry.counter("serving.generate.ticks_ahead")
+        if prev is not None:
+            self._finish_denoise(prev)
+        if tick is None:
+            return
+        if not ahead:
+            self._finish_denoise(tick)
+        elif not any(self._slots[i] is s for i, s, _ in rows):
+            self._drain_ahead()
+
+    def _finish_denoise(self, tick: _Tick):
+        """Commit a denoising step: for each slot whose block it completed
+        (and whose request is still the one it served), sync the blocks
+        and emit the block's new tokens, cut at the request's budget and
+        after the first eos: one host sync at most."""
+        live = [(i, s, at) for i, s, at in tick.rows
+                if self._slots[i] is s]
+        if len(live) < len(tick.rows):
+            telemetry.counter("serving.generate.stale_row_ticks",
+                              len(tick.rows) - len(live))
+        done = [(i, s, at) for i, s, at in live if at >= 0]
+        outs = {}
+        if done:
+            with tracing.phase("serve.decode.sync"):
+                toks = onp.asarray(tick.pick)
+            telemetry.counter("serving.generate.host_syncs")
+            for i, s, at in done:
+                out = [int(t) for t in toks[i, at:at + s.left]]
+                if s.eos_id is not None and s.eos_id in out:
+                    out = out[:out.index(s.eos_id) + 1]
+                if s.n_ctx == s.prompt.size:
+                    telemetry.hist_since("serving.generate.ttft",
+                                         s.t_submit)
+                outs[i] = out
+            telemetry.counter("serving.generate.blocks_committed", len(outs))
+        telemetry.hist_since("serving.generate.decode", tick.t0)
+        if not outs:
+            return
+
+        def span(i, s, out):
+            if s.stream._trace is not None:
+                s.stream._trace.add("block", tick.tt0, slot=i,
+                                    tokens=len(out))
+        self._commit_outputs(list(outs), outs, span)
 
     def _decode_tick_multi(self, idxs):
         """One MULTI-TICK decode tick: ``decode_ticks`` fused decode
@@ -2787,6 +2978,8 @@ class GenerationEngine:
                    for s in self._slots):
                 if self.speculative:
                     self._spec_tick()
+                elif self._blockdiff is not None:
+                    self._denoise_tick()
                 else:
                     self._decode_tick()
             return

@@ -645,3 +645,62 @@ def test_nemotronh_tick_has_the_operations_ssd_share_reads(
               f"bf16[{s['E_held']},{s['D']},{s['F']}]")
     assert not [n for n in names
                 if n.startswith("copy ") and n.endswith(leaves)]
+
+
+# -- the sdar family at its published widths: the denoising step of the
+# -- cell's 32 slots, whose held-page reads sdar_attend_roofline times
+def test_sdar_denoise_reads_held_pages_and_copies_no_pool(
+        one_chip, no_compile_cache, monkeypatch):
+    """``SDARModel``'s denoising step at the published widths and the
+    cell's 32 slots, compiled for the described v5e with the kernels as
+    the chip runs them: two held-page reads a layer (the committed
+    block's rows and the denoised block's), three expert products a
+    layer, and no copy of a pool."""
+    import json
+    import os
+    from chipbench import trace_reduce
+    from chipbench.families.sdar import program
+    from mxnet_tpu.gluon.model_zoo.sdar import SDARModel
+    from mxnet_tpu.ops import moe
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "chipbench", "configs",
+                           "sdar-30b-a3b-chat.json")) as f:
+        cfg = json.load(f)
+    model, serve = cfg["model"], cfg["serve"]
+    net = SDARModel(**{k: model[k] for k in program._KEYS},
+                    max_length=serve["max_length"])
+    monkeypatch.setattr(att, "_use_pallas", lambda: True)
+
+    def on_chip(p):
+        return jax.ShapeDtypeStruct(p.shape, jnp.dtype(p.dtype),
+                                    sharding=one_chip)
+
+    layers = [{k: on_chip(p) for k, p in net._params[li].items()}
+              for li in range(model["num_hidden_layers"])]
+    top = {k: on_chip(p) for k, p in net._params[None].items()}
+    slots, ps, s_max = serve["max_slots"], serve["page_size"], \
+        serve["max_length"]
+    cache = jax.tree.map(on_chip, jax.eval_shape(
+        lambda: net.init_paged_cache(slots, slots * s_max // ps + 1, ps,
+                                     s_max)))
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    blk = model["block_length"]
+    fill = jax.ShapeDtypeStruct((slots, blk), jnp.bool_, sharding=one_chip)
+    # the cache donated, as the program is jitted
+    text = jax.jit(net._denoise_body, donate_argnums=(8,)).lower(
+        layers, top, i32(slots), i32(slots), i32(slots, blk), fill,
+        i32(slots), i32(slots, s_max // ps), cache).compile().as_text()
+    names = [trace_reduce.short(line.strip().removeprefix("ROOT "))[0]
+             for line in text.splitlines() if " = " in line]
+    ran = [n for n in names if n.startswith(("fusion ", "custom-call ",
+                                             "copy "))]
+    n_layers = model["num_hidden_layers"]
+    assert sum(n.startswith(f"custom-call {att.PAGED_ROWS_KERNEL} ")
+               for n in ran) == 2 * n_layers
+    assert sum(n.startswith(f"custom-call {moe.KERNEL_NAME} ")
+               for n in ran) == 3 * n_layers
+    pool_rows = str(slots * s_max // ps + 1)
+    assert not [n for n in ran if n.startswith("copy ") and pool_rows in n]

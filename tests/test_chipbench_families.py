@@ -12,9 +12,9 @@ chipbench/tests`` on this tree; here they are left out of the cases taken
 over and superseded by the two cases marked ``SUPERSEDES`` below, which
 say the same for every family by whole words. The ``benchmark`` PR that
 edits the two in place deletes the two marked here (``PERF.md`` section 7).
-The ``dots3``, ``phi4flash``, ``xing4`` and ``nemotronh`` families are
-held to the same interface, and their configurations to the catalog's
-published keys.
+The ``dots3``, ``phi4flash``, ``xing4``, ``nemotronh`` and ``sdar``
+families are held to the same interface, and their configurations to the
+catalog's published keys.
 """
 import importlib.util
 import json
@@ -75,6 +75,9 @@ NAMES = {
     "nemotronh": ("NemotronHModel", "model.nemotronh.trace",
                   "families.nemotronh", "hybrid_override_pattern",
                   "mamba_num_heads", "ssd_chunk_scan_call"),
+    "sdar": ("SDARModel", "model.sdar.trace", "families.sdar",
+             "block_length", "denoise_steps", "mask_token_id",
+             "remasking_strategy", "paged_rows_call"),
 }
 
 
@@ -288,8 +291,11 @@ def test_phi4flash_cell_reads_its_own_programs_and_kernel():
     cell = PHI + ".serve.longgen"
     mine = {m["name"] for m in bench["per_layer"]
             if m.get("workloads") == [cell]}
-    assert mine == {"model.phi4flash_decode_device_ms", "model.ssm_share",
-                    "model.pool_attend_share"}
+    assert mine == {"model.phi4flash_decode_device_ms", "model.ssm_share"}
+    # the held-pages reader's share: first read in this cell
+    attend = [m for m in bench["per_layer"]
+              if m["name"] == "model.pool_attend_share"]
+    assert attend[0]["workloads"][0] == cell
     # the chunk programs' device time and the scan kernel's roofline are
     # not metrics of the cell: its traced 5 s hold ticks alone (PERF.md
     # section 7 has the two files a benchmark PR would add); the
@@ -581,3 +587,166 @@ def test_nemotronh_cell_is_its_traffic_and_reads_its_own_metrics():
         assert not rx.search("jit_nemotronh_paged_fresh(7)")
     limits = harness.load_json("limits", NEMO_CELL + ".json")
     assert 0 < limits["limits"]["served_logit_gap"] < 1
+
+
+# -- the sdar configuration: the catalog's row, one pipeline stage of eight
+SDAR = "sdar-30b-a3b-chat"
+SDAR_CELL = SDAR + ".serve.blockdiff-chat"
+
+
+@pytest.fixture(scope="module")
+def sdar_config():
+    return harness.load_json("configs", SDAR + ".json")
+
+
+def test_sdar_configuration_is_the_catalog_entry_but_for_reduced(
+        sdar_config):
+    """Every width as published; only the depth is cut (layers 0-5 of 48,
+    one stage of eight), and it stands in ``reduced`` with the published
+    value."""
+    assert sdar_config["reduced"] == ["num_hidden_layers"]
+    pub = sdar_config["published"]
+    assert pub == {"num_hidden_layers": 48}
+    assert sdar_config["num_hidden_layers"] * 8 == 48
+    assert "8 stages of 6 layers" in sdar_config["deployment"]
+    bench = harness.load_benchmark()
+    listed = next(c for c in bench["configs"] if c["name"] == SDAR)
+    assert listed["reduced"] == sdar_config["reduced"]
+    if not os.path.isfile(CATALOG):
+        pytest.skip("no catalog beside the model-configs guide here")
+    with open(CATALOG) as f:
+        rows = [json.loads(line) for line in f]
+    entry = next(r for r in rows if r["name"] == "SDAR-30B-A3B-Chat")
+    assert sdar_config["source"] == entry["source_url"] == listed["source"]
+    model = sdar_config["model"]
+    for key, value in entry["config"].items():
+        if key in sdar_config["reduced"]:
+            assert pub[key] == value, key
+        else:
+            assert sdar_config[key] == value, key
+            assert model[key] == value, key
+
+
+def test_sdar_configuration_states_its_keys_twice_alike(sdar_config):
+    model = sdar_config["model"]
+    extra = {"initializer_range", "block_length", "denoise_steps",
+             "remasking_strategy", "mask_token_id"}
+    for key, value in model.items():
+        if key not in extra:
+            assert sdar_config[key] == value, key
+    assert extra <= set(model)
+    assert (model["block_length"], model["denoise_steps"]) == (4, 2)
+    serve = sdar_config["serve"]
+    assert serve == {"paged": True, "page_size": 64, "max_slots": 32,
+                     "max_length": 3072, "prefill_chunk": 512,
+                     "prefix_cache": False, "compute_dtype": "bfloat16"}
+    mix = harness.load_json("traffic", "closed32-blockdiff-chat.json")
+    assert serve["max_length"] == mix["prompt_tokens"]["max"] \
+        + mix["new_tokens"]["max"]
+    for key in ("qk_norm", "mask", "routing", "block_length",
+                "denoise_steps", "mask_token_id", "prefill_alignment",
+                "commit", "initializer_range"):
+        assert len(sdar_config["assumed"][key]) > 20, key
+
+
+def test_sdar_parameter_count_is_the_stage_the_configuration_states(
+        sdar_config):
+    """A layer 623,120,640 (q 8,388,608, k and v 1,048,576 each, o
+    8,388,608, the q/k norms 256, the block's norms 4,096, the router
+    262,144, 128 experts of 4,718,592); six of them with the embedding,
+    the head and the final norm: 4,361,055,744 parameters, 8.72 GB in
+    bfloat16."""
+    weights = harness.family(sdar_config, "weights")
+    s = weights.sizes(sdar_config["model"])
+    layer = sum(int(np.prod(sh)) for _, sh, _ in weights.layer_leaves(s, 0))
+    assert layer == 623_120_640
+    n = weights.parameter_count(s)
+    assert n == 4_361_055_744
+    assert n - 6 * layer == 622_329_856 + 2048
+    wide = 6 * 2048 * 128                   # the float32 routers
+    assert (2 * n + 2 * wide) / (2 * n) < 1.001
+
+
+def test_sdar_cell_is_its_traffic_and_reads_its_own_metrics():
+    bench = harness.load_benchmark()
+    cells = {c["name"]: c for c in bench["workloads"]}
+    cell = cells[SDAR_CELL]
+    assert (cell["config"], cell["traffic"], cell["chips"]) == (
+        SDAR, "closed32-blockdiff-chat", 1)
+    mix = harness.load_json("traffic", "closed32-blockdiff-chat.json")
+    assert (mix["kind"], mix["callers"], mix["distinct_sizes"],
+            mix["check_requests"], mix["trace_seconds"], mix["eos"]) == (
+        "closed_loop", 32, 48, 6, 5, None)
+    assert mix["prompt_tokens"] == {"dist": "lognormal", "median": 768,
+                                    "sigma": 0.7, "min": 128, "max": 2048}
+    assert mix["new_tokens"] == {"dist": "lognormal", "median": 512,
+                                 "sigma": 0.35, "min": 256, "max": 1024}
+    others = [harness.load_json("traffic", f).get("pairing_seed")
+              for f in os.listdir(os.path.join(BENCH, "traffic"))
+              if f != "closed32-blockdiff-chat.json"]
+    assert mix["pairing_seed"] not in others
+    judged = {m["name"] for m in bench["end_to_end"]
+              if harness.applies(m, SDAR_CELL)}
+    assert judged == {"serve_tokens_per_s", "ttft_mean_ms", "setup_s"}
+    mine = {m["name"] for m in bench["per_layer"]
+            if m.get("workloads") == [SDAR_CELL]}
+    assert mine == {"model.sdar_denoise_device_ms",
+                    "model.sdar_chunk_device_ms", "sdar_attend_roofline",
+                    "sched.denoise_call_ms"}
+    shared = {m["name"] for m in bench["per_layer"]
+              if SDAR_CELL in m.get("workloads", ()) and m["name"]
+              not in mine}
+    assert shared == {"model.moe_share", "model.pool_copy_share",
+                      "model.pool_attend_share",
+                      "sched.itl_p95_ms.prefill-heavy",
+                      "sched.itl_p50_ms.prefill-heavy",
+                      "sched.call_ms_per_iter", "device.idle_in_call.serve",
+                      "device.idle_unattributed.serve"}
+    from mxnet_tpu.ops import attention
+    spec = harness.load_json("layer_metrics", "sdar_attend_roofline.json")
+    assert attention.PAGED_ROWS_KERNEL in spec["args"]["pattern"]
+    costs = harness.family({"family": "sdar"}, "costs")
+    assert callable(getattr(costs, spec["args"]["cost"]))
+    for prog, names in (("denoise", ("denoise",)),
+                        ("chunk", ("chunk", "fresh"))):
+        spec = harness.load_json(
+            "layer_metrics", f"model.sdar_{prog}_device_ms.json")
+        rx = re.compile(spec["args"]["module"])
+        for name in names:
+            assert rx.search(f"jit_sdar_paged_{name}(6)")
+        assert not rx.search("jit_xing4_paged_chunk(7)")
+    spec = harness.load_json("layer_metrics", "sched.denoise_call_ms.json")
+    harness.by_name("reducers", spec["reducer"])
+    assert re.search(spec["args"]["span"],
+                     "GenerationEngine.worker/7: serve.call.denoise")
+    limits = harness.load_json("limits", SDAR_CELL + ".json")
+    assert 0 < limits["limits"]["served_logit_gap"] < 1
+
+
+def test_sdar_costs_count_the_algorithm():
+    """``steps + 1`` row forwards a generated token and the head on its
+    ``steps`` denoised rows; a floor for the held-pages reader that never
+    goes below 0."""
+    costs = harness.family({"family": "sdar"}, "costs")
+    s = harness.family({"family": "sdar"}, "weights").sizes(
+        harness.load_json("configs", SDAR + ".json")["model"])
+    row = 2 * 6 * (2048 * 128 * 72 + 2048 * 128 + 3 * 2048 * 768 * 8)
+    head = 2 * 151936 * 2048
+    assert costs.token_forward_flops(s, 0, False) == 3 * row
+    assert costs.token_forward_flops(s, 10, True) == \
+        3 * (row + 6 * 4 * 32 * 128 * 10) + 2 * head
+    assert costs.prompt_forward_flops(s, 9) == 8 * row \
+        + 6 * 4 * 32 * 128 * (4 * 4 + 4 * 8)
+    facts = {"sizes": s, "max_slots": 32, "prompts_done": [],
+             "decode_contexts": [1000] * 4}
+    assert costs.paged_rows_call(facts) == (0.0, 0.0)
+    facts["decode_contexts"] = [1000] * 4000
+    flops, nbytes = costs.paged_rows_call(facts)
+    keys = 1000 * (3000 - 128)
+    assert nbytes == 6 * 2048 * keys and flops == 6 * 4 * 32 * 128 * 4 * keys
+    # the reads taken off count at the longest context, a request ended
+    # for each begun
+    facts["decode_contexts"] = [1000] * 3999 + [2000]
+    facts["prompts_done"] = [700] * 10
+    _, nbytes = costs.paged_rows_call(facts)
+    assert nbytes == 6 * 2048 * (4001000 * 3 / 4 - (128 + 10) * 2000)
